@@ -8,7 +8,7 @@ setup(
     version="0.1.0",
     author="xgboost_ray_tpu authors",
     description="TPU-native distributed gradient-boosted-tree training with "
-    "the xgboost_ray API: JAX/XLA/Pallas tpu_hist learner over a device mesh.",
+    "the xgboost_ray API: JAX/XLA tpu_hist learner over a device mesh.",
     long_description="A standalone re-design of ray-project/xgboost_ray for "
     "TPU: mesh workers instead of Ray actors, psum histogram allreduce "
     "instead of Rabit, and an HBM-resident quantile-binned matrix instead "
@@ -24,5 +24,5 @@ setup(
         "sklearn": ["scikit-learn"],
         "parquet": ["pyarrow"],
     },
-    python_requires=">=3.9",
+    python_requires=">=3.11",
 )

@@ -713,7 +713,6 @@ def test_serve_breaker_degraded_and_http_status_mapping(serve_model):
 
 _LAUNCH_ENV = {
     "JAX_PLATFORMS": "cpu",
-    "RXGB_FORCE_CPU_MESH": "1",
     "RXGB_RESTART_BACKOFF_BASE_S": "0",
 }
 

@@ -13,6 +13,8 @@ world size. Tests that exercise the quantized wire itself therefore pass
 identity test pins the DEFAULT contract.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -20,13 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from xgboost_ray_tpu.compat import shard_map_compat as shard_map
 from xgboost_ray_tpu.engine import TpuEngine
 from xgboost_ray_tpu.ops.histogram import (
     AllreduceBytes,
     quantized_hist_allreduce,
 )
 from xgboost_ray_tpu.params import parse_params
+
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _one_hot_fixture():

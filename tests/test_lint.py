@@ -233,12 +233,12 @@ def test_sync001_true_positive_float_and_item_in_traced():
 
 def test_sync001_true_positive_shard_map_closure():
     findings = lint("""
-        from xgboost_ray_tpu.compat import shard_map_compat
+        import jax
         def build(mesh, specs):
             def fn(x):
                 return bool(x.any())
-            return shard_map_compat(fn, mesh=mesh, in_specs=specs,
-                                    out_specs=specs)
+            return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                                 out_specs=specs)
     """)
     assert codes(findings) == ["SYNC001"]
 

@@ -102,7 +102,6 @@ def test_real_process_kill_surfaces_and_resume_matches(tmp_path):
             env={
                 "JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-                "RXGB_FORCE_CPU_MESH": "1",
                 "MH_KILL_ROUND": str(kill_round),
             },
             timeout_s=600.0,

@@ -22,7 +22,6 @@ from tools.rxgblint import catalog
 from tools.rxgbverify import checks, walker
 from tools.rxgbverify.matrix import trace_matrix
 from xgboost_ray_tpu import progreg
-from xgboost_ray_tpu.compat import shard_map_compat as shard_map
 from xgboost_ray_tpu.constants import AXIS_ACTORS
 from xgboost_ray_tpu.engine import TpuEngine
 from xgboost_ray_tpu.ops.histogram import quantized_hist_allreduce
@@ -55,10 +54,11 @@ def _mesh(n=4):
 
 def _sharded(body, n=4, n_in=1):
     specs = tuple(P(AXIS_ACTORS) for _ in range(n_in))
-    return shard_map(
+    return jax.shard_map(
         body, mesh=_mesh(n),
         in_specs=specs if n_in > 1 else specs[0],
         out_specs=P(AXIS_ACTORS),
+        check_vma=False,
     )
 
 
@@ -177,9 +177,10 @@ def test_schedule_identity_dtype_drift_is_flagged():
 
 def test_axis_name_true_positive():
     mesh = Mesh(np.array(jax.devices()[:4]), ("workers",))
-    body = shard_map(
+    body = jax.shard_map(
         lambda x: jax.lax.psum(x, "workers"), mesh=mesh,
         in_specs=P("workers"), out_specs=P("workers"),
+        check_vma=False,
     )
     t = _trace(body, (F32V,))
     findings = checks.check_axis_names([t], MESH_AXES)
@@ -196,7 +197,7 @@ def test_no_f64_true_positive():
     def body(x):
         return x.astype(jnp.float64).sum()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         t = _trace(body, (F32V,))
     assert t.ok, t.error
     findings = checks.check_no_f64([t])

@@ -5,7 +5,7 @@ README rule catalog:
 
 * **Traced-code detection** (DET001 time.*, SYNC001) is lexical: a function
   is "traced" when it is passed to a jax tracing entry point
-  (``jit``/``shard_map``/``shard_map_compat``/``vmap``/``pmap``/``scan``/
+  (``jit``/``shard_map``/``vmap``/``pmap``/``scan``/
   ``cond``/...) directly, by name within the same module, or via a ``jit``
   decorator — plus everything lexically nested inside such a function.
   Closures returned from one function and traced in another (the engine's
@@ -29,7 +29,7 @@ from tools.rxgblint.findings import Finding
 # jax tracing entry points: a function passed into one of these executes
 # under trace, where host-side effects are hazards
 TRACER_CALLS = frozenset({
-    "jit", "shard_map", "shard_map_compat", "vmap", "pmap", "scan",
+    "jit", "shard_map", "vmap", "pmap", "scan",
     "while_loop", "fori_loop", "cond", "switch", "checkpoint", "remat",
     "grad", "value_and_grad", "custom_jvp", "custom_vjp",
 })

@@ -12,7 +12,7 @@ import sys
 
 
 def _force_cpu_mesh() -> None:
-    """Hermetic virtual CPU mesh (same trick as tests/conftest.py): must run
+    """Virtual 8-device CPU mesh (same as tests/conftest.py): must run
     BEFORE the first jax import. If jax is already imported (in-process test
     invocation under conftest) the environment is trusted as-is."""
     if "jax" in sys.modules:
@@ -23,14 +23,6 @@ def _force_cpu_mesh() -> None:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge as _xb
-
-    for name in list(_xb._backend_factories):
-        if name != "cpu":
-            _xb._backend_factories.pop(name, None)
 
 
 def _program_entry(t) -> dict:

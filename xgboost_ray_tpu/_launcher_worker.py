@@ -1,9 +1,8 @@
 """Per-process bootstrap spawned by ``launcher.launch_distributed``.
 
-Order matters: the hermeticity trick (drop non-CPU PJRT factories when
-``RXGB_FORCE_CPU_MESH`` is set — same as tests/conftest.py) must run before
-ANY jax-touching import, including the unpickle of the worker fn's module;
-then the process joins the ``jax.distributed`` world and runs the fn.
+The process joins the ``jax.distributed`` world BEFORE any backend is
+initialized, then runs the fn. Which backend that is comes from the
+environment the launcher passed (``JAX_PLATFORMS``/``XLA_FLAGS``).
 
 Usage (internal): python -m xgboost_ray_tpu._launcher_worker <payload> <result>
 """
@@ -15,15 +14,6 @@ import sys
 
 def main() -> int:
     payload_path, result_path = sys.argv[1], sys.argv[2]
-
-    if os.environ.get("RXGB_FORCE_CPU_MESH"):
-        import jax
-        from jax._src import xla_bridge as _xb
-
-        jax.config.update("jax_platforms", "cpu")
-        for _name in list(_xb._backend_factories):
-            if _name not in ("cpu",):
-                _xb._backend_factories.pop(_name, None)
 
     with open(payload_path, "rb") as f:
         payload = pickle.load(f)

@@ -17,38 +17,6 @@ from xgboost_ray_tpu.callback import TrainingCallback
 LEGACY_CALLBACK = False  # new-style TrainingCallback is always available
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions; replication checking off by
-    default.
-
-    The replication-static check was renamed ``check_rep`` ->
-    ``check_vma`` when shard_map graduated from jax.experimental to the
-    top level. On jax versions with the OLD checker (<= 0.4.x), ANY
-    program carrying a ``lax.scan`` through shard_map trips a false
-    positive ("Scan carry ... mismatched replication types") even when
-    the program is replication-correct — measured here: enabling the
-    check fails 15/17 of the booster-predict/gblinear/SHAP tests on jax
-    0.4.37 while the identical programs run correctly with it off. The
-    out_specs still pin the sharding contract. Pass ``check=True`` from a
-    call site known to be clean on the deployed jax to opt back into the
-    trace-time guard.
-    """
-    import inspect
-
-    import jax
-
-    try:  # jax >= 0.6 exposes shard_map at top level (check_vma kwarg)
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = check
-    elif "check_rep" in params:
-        kw["check_rep"] = check
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 CallbackEnv = namedtuple(
     "CallbackEnv",
     [

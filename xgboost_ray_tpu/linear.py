@@ -38,8 +38,6 @@ from xgboost_ray_tpu.ops.metrics import compute_metric, parse_metric_name
 from xgboost_ray_tpu.ops.objectives import get_objective
 from xgboost_ray_tpu.params import TrainParams
 
-from xgboost_ray_tpu.compat import shard_map_compat as shard_map
-
 
 class RayLinearBooster:
     """A trained linear model: ``margin = x @ weights + bias + m0``.
@@ -503,11 +501,12 @@ class LinearEngine:
             (w, g), _ = jax.lax.scan(step, (w, g), jnp.arange(n_feat))
             return w, b
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(P(AXIS_ACTORS), P(AXIS_ACTORS), P(AXIS_ACTORS), P(AXIS_ACTORS),
                       P(AXIS_ACTORS), P(), P()),
             out_specs=(P(), P()),
+            check_vma=False,
         )
         return progreg.register_jit(
             "linear.update",

@@ -41,8 +41,6 @@ def _spmd_margin_fn(devices, k, max_depth, npt, ntree_limit, has_tw,
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from xgboost_ray_tpu.compat import shard_map_compat as shard_map
-
     key = (
         tuple(getattr(d, "id", i) for i, d in enumerate(devices)),
         k, max_depth, npt, int(ntree_limit), has_tw, tuple(cat_features),
@@ -62,10 +60,11 @@ def _spmd_margin_fn(devices, k, max_depth, npt, ntree_limit, has_tw,
         )
 
     mapped = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(), P(), P(AXIS_ACTORS), P(AXIS_ACTORS)),
             out_specs=P(AXIS_ACTORS),
+            check_vma=False,
         )
     )
     if len(_SPMD_MARGIN_FNS) > 16:  # bound retained programs; evict oldest

@@ -18,8 +18,10 @@ permutation* of the heap — node ``(tree t, level k, slot p)`` lives at
 
 and corresponds to per-tree heap index ``2**k - 1 + p`` — so the walk
 below performs the *same* elementwise routing arithmetic on the *same*
-float values as ``predict.py``'s ``_walk_one_tree`` and stays **bitwise
-identical** to it (pinned by ``tests/test_serve_pool.py``). Only the
+float values as ``predict.py``'s ``_walk_one_tree``: every row reaches the
+same leaves (leaf outputs are integer-identical), and the summed margins
+agree to the serve layer's cross-program parity bound (pinned by
+``tests/test_serve_pool.py``). Only the
 six fields the raw-x walk reads are materialized (feature, split_bin,
 threshold, default_left, is_leaf, value); the SHAP kernels need
 ``base_weight``/``cover`` path statistics that do not level-map, so
@@ -150,7 +152,9 @@ def predict_margin_na(
     cat_features: tuple = (),
 ) -> jnp.ndarray:
     """Node-array twin of ``predict.predict_margin``: same leaf matrix,
-    same accumulation tail, so the [N, K] margins are bitwise identical."""
+    same accumulation expression. The compiler may still order the tree sum
+    differently in the two programs, so the [N, K] margins agree to
+    ``serve.predictor.PARITY_ULPS``, not bitwise."""
     t = _num_trees(na, max_depth)
     cat_mask = _cat_mask_const(cat_features, x.shape[1])
     leaf, _ = _walk_levels(na, x, max_depth, cat_mask)  # [T, N]

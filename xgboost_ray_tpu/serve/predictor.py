@@ -7,9 +7,18 @@ space finite: every batch is padded up to a power-of-two **bucket** (rounded
 to a mesh multiple), and the compiled program for a given
 ``(model signature, bucket, output kind, mesh)`` key is built exactly once
 and cached process-wide. The tree walk is row-independent, so the padding
-rows change nothing about the real rows' outputs — served results are
-bit-identical to the batch ``predict()`` path (pinned by
-``tests/test_serve.py``).
+rows change nothing about the real rows' routing.
+
+Parity contract (pinned by ``tests/test_serve.py`` and
+``tests/test_serve_pool.py``): the SAME compiled program on the same input
+returns the same bits — every request served by one (model, bucket, kind,
+layout, mesh) program is reproducible, and replicas sharing that program
+agree bitwise. Two DIFFERENT programs over one forest — another bucket,
+the node-array layout, another device count, the batch ``predict()`` path —
+route every row to the same leaves (``leaf`` outputs are integers and agree
+exactly) but may sum the trees in another order: the compiler picks the
+reduction order per shape and sharding, on any backend. Their float outputs
+agree to within :data:`PARITY_ULPS` float32 ulps (see :func:`parity_atol`).
 
 Programs are keyed by the booster's *structural* signature
 (``RayXGBoostBooster.signature()``), not its identity: hot-swapping to a
@@ -36,15 +45,29 @@ from xgboost_ray_tpu.ops import node_array as node_array_ops
 from xgboost_ray_tpu.ops import predict as predict_ops
 from xgboost_ray_tpu.ops.grow import Tree
 
-#: output kinds this layer can serve, mapped to the batch-path flag they
-#: must stay bit-identical to
+#: output kinds this layer can serve (each has a batch-path ``predict()``
+#: flag it is checked against, see the parity contract above)
 KINDS = ("value", "margin", "leaf", "contribs")
 
 #: forest layouts the predictor can walk: the padded heap (per-tree
 #: depth-first walk, the batch path's layout) and the FIL-style breadth-
 #: first node-array (level-synchronous gathers; see ops/node_array.py).
-#: Both serve bitwise-identical outputs; node_array targets lower p99.
+#: Both route rows identically; node_array targets lower p99.
 LAYOUTS = ("heap", "node_array")
+
+#: cross-program float parity bound, in float32 ulps at the magnitude of
+#: the largest output (at least 1.0). Reordering a T-term float32 sum moves
+#: it by at most ~T ulps of the summed magnitudes; 4 covers the forests of a
+#: dozen trees the tests serve (largest seen: 2). Computing in a narrower
+#: type than float32 would miss it by orders of magnitude.
+PARITY_ULPS = 4
+
+
+def parity_atol(ref) -> float:
+    """Absolute tolerance of the cross-program parity contract for outputs
+    compared against ``ref``."""
+    scale = max(1.0, float(np.max(np.abs(ref))))
+    return PARITY_ULPS * float(np.spacing(np.float32(scale)))
 
 _lock = threading.Lock()
 _COMPILE_COUNT = 0
@@ -174,7 +197,7 @@ class CompiledPredictor:
         # contribs needs base_weight/cover path statistics the node array
         # does not carry — it routes to the (shared) heap program, so a
         # node-array predictor's contribs hit the same cache entry a heap
-        # predictor's do and stay trivially bitwise-identical
+        # predictor's do: same program, same bits
         return self.layout == "node_array" and kind != "contribs"
 
     def _program(self, kind: str):
@@ -205,13 +228,12 @@ class CompiledPredictor:
             if n_dev > 1:
                 from jax.sharding import PartitionSpec as P
 
-                from xgboost_ray_tpu.compat import shard_map_compat as shard_map
-
                 return jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=self._mesh,
                         in_specs=(P(), P(), P(AXIS_ACTORS), P(AXIS_ACTORS)),
                         out_specs=P(AXIS_ACTORS),
+                        check_vma=False,
                     )
                 )
             return jax.jit(body)
@@ -262,13 +284,12 @@ class CompiledPredictor:
             if n_dev > 1:
                 from jax.sharding import PartitionSpec as P
 
-                from xgboost_ray_tpu.compat import shard_map_compat as shard_map
-
                 return jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=self._mesh,
                         in_specs=(P(), P(), P(AXIS_ACTORS), P(AXIS_ACTORS)),
                         out_specs=P(AXIS_ACTORS),
+                        check_vma=False,
                     )
                 )
             return jax.jit(body)
@@ -380,7 +401,7 @@ class CompiledPredictor:
             return out[:, 0] if b.num_outputs == 1 else out
         if kind == "value":
             # the batch path transforms eagerly on host (outside the jitted
-            # walk) — do exactly the same so values stay bit-identical
+            # walk) — do exactly the same, so equal margins give equal values
             return b._margin_to_prediction(out, output_margin=False)
         if kind == "leaf":
             return out

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from xgboost_ray_tpu import obs, progreg
-from xgboost_ray_tpu.compat import shard_map_compat as shard_map
 from xgboost_ray_tpu.constants import AXIS_ACTORS, SHARD_COLUMN_FILLS
 from xgboost_ray_tpu.ops import binning
 from xgboost_ray_tpu.stream.reader import ShardStream
@@ -326,7 +325,7 @@ def merged_cuts(
             miss = jax.lax.psum(missw[0], AXIS_ACTORS)
             return cuts, miss > 0
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn,
             mesh=engine.mesh,
             in_specs=(
@@ -334,6 +333,7 @@ def merged_cuts(
                 P(AXIS_ACTORS), P(AXIS_ACTORS),
             ),
             out_specs=(P(), P()),
+            check_vma=False,
         )
         jit_fn = progreg.register_jit(
             "engine.sketch_cuts",

@@ -8,6 +8,7 @@ process (workers are mesh slots), so these become thin wrappers over
 semantics (stop events, callback queues) and the FT tests carry over.
 """
 
+import os
 import queue
 import threading
 from typing import Any, Callable, List, Optional
@@ -79,7 +80,6 @@ def restart_backoff_s(
     cannot crash-loop storm. Env-tunable: ``RXGB_RESTART_BACKOFF_BASE_S``
     (default 0.5; 0 disables), ``RXGB_RESTART_BACKOFF_MAX_S`` (default 30),
     ``RXGB_RESTART_BACKOFF_JITTER`` (fraction, default 0.1)."""
-    import os
     import random
 
     if base is None:
@@ -95,3 +95,46 @@ def restart_backoff_s(
         # rxgblint: disable-next-line=DET001 - restart-schedule jitter only; never touches model state
         delay *= 1.0 + random.random() * jitter
     return delay
+
+
+#: fixed in-checkout default of the persistent compile cache (git-ignored).
+#: The directory is part of the cache key, so it is never a temp dir, a pid
+#: or a time.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives for this process:
+    ``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it, otherwise
+    :data:`CHECKOUT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or CHECKOUT_CACHE_DIR
+
+
+def place_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at :func:`compile_cache_dir`
+    and return that path — the one helper the entry-point scripts
+    (``chip_smoke.py``, ``bench.py``, ``tools/chip_sweep.py``) call. JAX
+    reads ``JAX_COMPILATION_CACHE_DIR`` itself, so when it is set nothing is
+    configured in code; otherwise the default is installed through
+    ``jax.config``. Call before the first compile."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_record() -> dict:
+    """Where this process runs, as JAX reports it — the three fields every
+    benchmark and smoke result line carries. Initializes the backend."""
+    import jax
+
+    dev0 = jax.devices()[0]
+    return {
+        "platform": dev0.platform,
+        "device_kind": dev0.device_kind,
+        "device_count": len(jax.devices()),
+    }
