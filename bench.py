@@ -1940,103 +1940,6 @@ def r4_paired_recheck(detail):
     return out
 
 
-def run_phase_breakdown():
-    """Per-phase round-cost breakdown (sample / hist / split / partition /
-    margin / allreduce) for the full, subsample=0.5, and GOSS configs —
-    consumed from the RUNTIME trace.
-
-    Each arm trains a short run with fenced phase profiling enabled
-    (``RXGB_TRACE_PHASES=1``); the engine itself emits the per-phase spans
-    at its true per-shard shapes (compile vs execute separated via
-    ``block_until_ready``, sibling-subtraction fan-outs, the engine's real
-    sampling budget and split params), and the table below is read back
-    from ``additional_results["obs"]["phase_profile"]``. This replaced the
-    bench's former standalone duplicate timers: the numbers now come from
-    the same instrumentation any traced production run produces."""
-    import jax
-
-    from xgboost_ray_tpu import RayDMatrix, RayParams, train
-
-    n_rows = int(os.environ.get("BENCH_PHASE_ROWS", 25_000))
-    n_feat = int(os.environ.get("BENCH_FEATURES", 28))
-    depth = int(os.environ.get("BENCH_DEPTH", 6))
-    actors = int(
-        os.environ.get("BENCH_PHASE_ACTORS", max(1, len(jax.devices())))
-    )
-    rounds = 2
-    x, y = make_higgs_like(n_rows, n_feat, seed=3)
-    arms = {
-        "full": {},
-        "subsample": {"subsample": 0.5},
-        "goss": {"sampling_method": "gradient_based", "top_rate": 0.1,
-                 "other_rate": 0.1},
-    }
-    section = {}
-    saved = os.environ.get("RXGB_TRACE_PHASES")
-    os.environ["RXGB_TRACE_PHASES"] = "1"
-    try:
-        for name, extra in arms.items():
-            params = {
-                "objective": "binary:logistic", "max_depth": depth,
-                "eta": 0.1, "max_bin": 256, "tree_method": "tpu_hist",
-            }
-            params.update(extra)
-            res = {}
-            train(
-                params, RayDMatrix(x, y), num_boost_round=rounds,
-                additional_results=res,
-                ray_params=RayParams(num_actors=actors,
-                                     checkpoint_frequency=0),
-            )
-            prof = (res.get("obs") or {}).get("phase_profile")
-            if not prof:
-                print(
-                    f"[bench] phase breakdown: no phase profile in the "
-                    f"trace for arm {name!r}; skipping",
-                    file=sys.stderr,
-                )
-                continue
-            phases = prof["phases"]
-            section[name] = {
-                "rows_per_level": prof["sample_rows"],
-                "sample_ms": phases["sample"]["execute_ms"],
-                "hist_ms": phases["hist"]["execute_ms"],
-                "split_ms": phases["split"]["execute_ms"],
-                "partition_ms": phases["partition"]["execute_ms"],
-                "margin_ms": phases["margin"]["execute_ms"],
-                "allreduce_ms": phases["allreduce"]["execute_ms"],
-                "allreduce_bytes_per_round": phases["allreduce"][
-                    "bytes_per_round"
-                ],
-                "compile_ms": round(
-                    sum(p.get("compile_ms", 0.0) for p in phases.values()), 3
-                ),
-                "total_ms": prof["total_execute_ms"],
-                "rows_per_shard": prof["rows_per_shard"],
-            }
-    finally:
-        if saved is None:
-            os.environ.pop("RXGB_TRACE_PHASES", None)
-        else:
-            os.environ["RXGB_TRACE_PHASES"] = saved
-    if section.get("full", {}).get("total_ms"):
-        for arm in ("subsample", "goss"):
-            if section.get(arm):
-                section[f"{arm}_total_vs_full"] = round(
-                    section[arm]["total_ms"] / section["full"]["total_ms"], 3
-                )
-    section["config"] = {
-        "rows": n_rows, "features": n_feat, "depth": depth,
-        "max_bin": 256, "actors": actors,
-        "source": "runtime trace (engine.profile_phases spans)",
-        "note": "fenced standalone phase programs at the engine's real "
-                "shard shapes; phase-share approximation — the compiled "
-                "round fuses phases",
-    }
-    print(f"[bench] phase breakdown: {section}", file=sys.stderr)
-    return section
-
-
 def _timeline_recovery_s(timeline):
     """Failure→recovery seconds reconstructed from a run's trace timeline
     (``obs.recovery_time_s``), or None when the run produced no timeline
@@ -2912,17 +2815,8 @@ def run_measurement(cpu_counts: bool = False):
             hpo_section["regression_tripwire"] = htrip
         detail["hpo"] = hpo_section
 
-    # per-phase round-cost breakdown (sample/hist/split/partition/margin),
-    # consumed from the runtime trace — shows WHERE sampling saves. Default
-    # on for the CPU mesh; opt-in on TPU via BENCH_PHASE_BREAKDOWN=1.
-    phase_env = os.environ.get("BENCH_PHASE_BREAKDOWN")
-    if phase_env == "1" or (phase_env is None and not on_tpu):
-        detail["phase_breakdown"] = run_phase_breakdown()
-
     # the protocol run's own obs snapshot: per-round span stats, ring-buffer
-    # truncation accounting, wire bytes, and (when the breakdown above ran)
-    # the per-phase means — recorded so future tripwires can query phases
-    # straight out of BENCH_*.json without re-instrumenting
+    # truncation accounting, wire bytes
     obs_res = additional_results.get("obs") or {}
     if obs_res:
         round_durs = [
@@ -2944,14 +2838,6 @@ def run_measurement(cpu_counts: bool = False):
             )
         if ar_bytes is not None:
             obs_section["allreduce_bytes_per_round"] = int(ar_bytes)
-        full_phases = (detail.get("phase_breakdown") or {}).get("full")
-        if full_phases:
-            obs_section["phase_ms"] = {
-                k: full_phases[k]
-                for k in ("sample_ms", "hist_ms", "split_ms", "partition_ms",
-                          "margin_ms", "allreduce_ms")
-                if k in full_phases
-            }
         detail["obs"] = obs_section
         print(f"[bench] obs snapshot: {obs_section}", file=sys.stderr)
 

@@ -1,17 +1,20 @@
 """Traced-training smoke: the obs plane's queryable run timeline.
 
 Trains 5 rounds with tracing on (the default) plus per-rank JSONL
-streaming (``RXGB_TRACE_DIR``) and fenced phase profiling
-(``RXGB_TRACE_PHASES=1``), then:
+streaming (``RXGB_TRACE_DIR``) and a profiler trace of the round loop
+(``RXGB_PROFILE_DIR``), then:
 
 * validates BOTH the in-memory timeline (``additional_results["obs"]``)
   and the streamed JSONL file against the shared trace schema
   (``xgboost_ray_tpu.validate_trace_records`` — the same checker the
-  tests use, so the CI example and the suite cannot drift apart), and
-* prints the per-phase table (sample / hist / split / partition / margin /
-  allreduce, compile vs execute separated) that traced production runs
-  emit — the per-round/per-collective breakdown the XGBoost GPU paper
-  attributes its wins with, now available outside the benchmark harness.
+  tests use, so the CI example and the suite cannot drift apart),
+* prints where one ``train()`` call spent its host time, by span name
+  (``data.load`` / ``engine.init`` / ``dispatch.enqueue`` / ``dispatch.wait``
+  / ``compile.*`` / ``driver.checkpoint`` / ``driver.callbacks``), and
+* prints the device seconds per named scope (``tree/level3/hist`` ...) that
+  ``python -m xgboost_ray_tpu.obs.device <dir>`` reads from the profiler
+  trace. A CPU trace has no device plane, so the table is empty here; on a
+  TPU it is the in-program phase breakdown.
 
 Run directly: python examples/trace_run.py
 """
@@ -23,6 +26,7 @@ import tempfile
 import numpy as np
 
 from xgboost_ray_tpu import RayDMatrix, RayParams, train, validate_trace_records
+from xgboost_ray_tpu.obs import TRACE_NAMES, device
 
 
 def main():
@@ -31,9 +35,10 @@ def main():
     x = rng.randn(4096, 12).astype(np.float32)
     y = (x[:, 0] + 0.5 * x[:, 1] > 0).astype(np.float32)
 
-    with tempfile.TemporaryDirectory() as trace_dir:
+    with tempfile.TemporaryDirectory() as trace_dir, \
+            tempfile.TemporaryDirectory() as profile_dir:
         os.environ["RXGB_TRACE_DIR"] = trace_dir
-        os.environ["RXGB_TRACE_PHASES"] = "1"
+        os.environ["RXGB_PROFILE_DIR"] = profile_dir
         try:
             res = {}
             bst = train(
@@ -46,13 +51,14 @@ def main():
             )
         finally:
             os.environ.pop("RXGB_TRACE_DIR", None)
-            os.environ.pop("RXGB_TRACE_PHASES", None)
+            os.environ.pop("RXGB_PROFILE_DIR", None)
 
         assert bst.num_boosted_rounds() == rounds
         obs = res["obs"]
 
         # schema validation: in-memory timeline AND the streamed JSONL
-        problems = validate_trace_records(obs["timeline"])
+        problems = validate_trace_records(obs["timeline"],
+                                          known_names=TRACE_NAMES)
         assert not problems, problems
         stream_path = os.path.join(trace_dir, "trace-rank0.jsonl")
         with open(stream_path) as f:
@@ -62,6 +68,8 @@ def main():
         print(f"trace schema OK: {len(obs['timeline'])} buffered records, "
               f"{len(streamed)} streamed lines, "
               f"{obs['dropped_spans']} dropped")
+        # what `python -m xgboost_ray_tpu.obs.device <dir>` prints
+        scopes = device.scope_times(profile_dir)
 
     # the queryable views: one span per round, lifecycle events
     assert [r["round"] for r in obs["rounds"]] == list(range(rounds))
@@ -73,17 +81,29 @@ def main():
     print(f"events: {events}")
     assert any(name == "checkpoint.commit" for name, _ in events)
 
-    # the per-phase table from the fenced profile
-    prof = obs["phase_profile"]
-    print(f"\nphase profile ({prof['rows_per_shard']} rows/shard, "
-          f"world {prof['config']['world']}):")
-    print(f"{'phase':<10} {'compile_ms':>11} {'execute_ms':>11}")
-    for name in ("sample", "hist", "split", "partition", "margin",
-                 "allreduce"):
-        p = prof["phases"][name]
-        print(f"{name:<10} {p['compile_ms']:>11.3f} {p['execute_ms']:>11.3f}")
-    print(f"total execute: {prof['total_execute_ms']:.3f} ms/round "
-          f"(phase-share approximation)")
+    # one train() call by layer: seconds by span name (a parent holds its
+    # children's seconds too: dispatch > dispatch.enqueue > compile.*)
+    by_name = {}
+    for rec in obs["timeline"]:
+        if rec["kind"] == "span":
+            n, sec = by_name.get(rec["name"], (0, 0.0))
+            by_name[rec["name"]] = (n + 1, sec + rec["dur_s"])
+    for name in ("attempt", "data.load", "engine.init", "data.h2d",
+                 "data.sketch_bin", "dispatch", "dispatch.enqueue",
+                 "dispatch.wait", "round", "compile.backend",
+                 "driver.checkpoint", "driver.callbacks"):
+        assert name in by_name, name
+    print(f"\n{'span':<20} {'count':>6} {'seconds':>10}")
+    for name, (n, sec) in sorted(by_name.items(), key=lambda kv: -kv[1][1]):
+        print(f"{name:<20} {n:>6} {sec:>10.4f}")
+    first, second = [r for r in obs["timeline"] if r["name"] == "dispatch"][:2]
+    assert first["attrs"]["first"] and not second["attrs"]["first"]
+
+    print(f"\ndevice seconds by named scope ({len(scopes)} scope paths):")
+    for name, sec in sorted(scopes.items(), key=lambda kv: -kv[1]):
+        print(f"{sec:12.6f}  {name}")
+    if not scopes:
+        print("  (none: this backend's trace has no device plane)")
     print("\ntraced run OK")
 
 

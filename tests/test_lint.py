@@ -799,8 +799,10 @@ def test_broken_pipe_does_not_mask_findings(tmp_path):
 def test_validate_trace_records_known_names():
     from xgboost_ray_tpu.obs import TRACE_NAMES, validate_trace_records
 
-    rec = {"kind": "event", "name": "recovered", "ts": 1.0, "seq": 1}
-    bad = {"kind": "event", "name": "not.catalogued", "ts": 2.0, "seq": 2}
+    rec = {"kind": "event", "name": "recovered", "ts": 1.0, "t0_s": 1.0,
+           "seq": 1}
+    bad = {"kind": "event", "name": "not.catalogued", "ts": 2.0, "t0_s": 2.0,
+           "seq": 2}
     assert validate_trace_records([rec, bad]) == []  # default: schema only
     problems = validate_trace_records([rec, bad], known_names=TRACE_NAMES)
     assert len(problems) == 1 and "not.catalogued" in problems[0]

@@ -143,13 +143,16 @@ def test_use_tracer_scopes_current_thread():
 
 def test_validate_trace_records_flags_malformed():
     bad = [
-        {"kind": "span", "name": "a", "ts": 0.0, "seq": 1, "dur_s": 0.1,
-         "parent": None, "extra": 1},                     # unknown key
-        {"kind": "event", "name": "b", "ts": 0.0, "seq": 1},  # dup seq
-        {"kind": "event", "name": "c", "ts": 0.0, "seq": 2, "dur_s": 0.5},
-        {"kind": "nope", "name": "d", "ts": 0.0, "seq": 3},   # bad kind
+        {"kind": "span", "name": "a", "ts": 0.0, "t0_s": 0.0, "seq": 1,
+         "dur_s": 0.1, "parent": None, "extra": 1},       # unknown key
+        {"kind": "event", "name": "b", "ts": 0.0, "t0_s": 0.0,
+         "seq": 1},                                       # dup seq
+        {"kind": "event", "name": "c", "ts": 0.0, "t0_s": 0.0, "seq": 2,
+         "dur_s": 0.5},
+        {"kind": "nope", "name": "d", "ts": 0.0, "t0_s": 0.0,
+         "seq": 3},                                       # bad kind
         {"kind": "span", "name": "", "ts": "x", "seq": 4, "dur_s": -1,
-         "parent": "p"},
+         "parent": "p"},                                  # no t0_s either
     ]
     problems = validate_trace_records(bad)
     text = "\n".join(problems)
@@ -157,7 +160,7 @@ def test_validate_trace_records_flags_malformed():
     assert "duplicate seq" in text
     assert "event carries dur_s" in text
     assert "bad kind 'nope'" in text
-    assert "bad name" in text and "bad ts" in text
+    assert "bad name" in text and "bad ts" in text and "bad t0_s" in text
     assert "bad dur_s" in text and "bad parent" in text
 
 
@@ -492,3 +495,276 @@ def test_chaos_shrink_grow_sequence_reconstructible_from_timeline(monkeypatch):
     assert ttr == pytest.approx(
         res["robustness"]["time_to_recover_s"], abs=0.05
     )
+
+
+# ---------------------------------------------------------------------------
+# one timeline from inside train(): the monotonic clock, spans at the layer
+# boundaries, compiles under their cause, named scopes on the device phases
+# (structural checks only: nothing here asserts a duration)
+# ---------------------------------------------------------------------------
+
+
+def _traced(rounds=4, per_round=False, **ray):
+    """One small traced train(); ``per_round`` forces the per-round loop
+    (a TrainingCallback switches the fused scan off)."""
+
+    class Noop:
+        def after_iteration(self, model, epoch, evals_log):
+            return False
+
+    x, y = _data()
+    dtrain = RayDMatrix(x, y)
+    res = {}
+    train(_PARAMS, dtrain, rounds, evals=[(dtrain, "train")],
+          additional_results=res, callbacks=[Noop()] if per_round else None,
+          ray_params=RayParams(num_actors=2, checkpoint_frequency=2, **ray))
+    return res["obs"]["timeline"]
+
+
+def _spans(timeline, name):
+    return [r for r in timeline if r["kind"] == "span" and r["name"] == name]
+
+
+def _below(timeline, seq):
+    """Every record under span ``seq`` (children, grandchildren, ...)."""
+    parent = {r["seq"]: r.get("parent") for r in timeline}
+    out = []
+    for r in timeline:
+        p = r.get("parent")
+        while p is not None and p != seq:
+            p = parent.get(p)
+        if p == seq:
+            out.append(r)
+    return out
+
+
+@pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
+def test_every_record_is_on_the_monotonic_clock_inside_its_parent(per_round):
+    timeline = _traced(per_round=per_round)
+    assert validate_trace_records(timeline, known_names=obs.TRACE_NAMES) == []
+    assert all(isinstance(r["t0_s"], float) for r in timeline)
+    by_seq = {r["seq"]: r for r in timeline}
+    nested = [r for r in timeline if r.get("parent") is not None]
+    assert len(nested) > 10
+    slack = 0.05  # compile spans are dated back from jax's own stopwatch
+    for r in nested:
+        p = by_seq[r["parent"]]
+        assert p["t0_s"] - slack <= r["t0_s"], (r, p)
+        assert (r["t0_s"] + r["dur_s"]
+                <= p["t0_s"] + p["dur_s"] + slack), (r, p)
+    # events share the clock: a commit lies inside the save that made it
+    (attempt,) = _spans(timeline, "attempt")
+    for name in ("data.load", "engine.init", "dispatch", "driver.callbacks",
+                 "driver.checkpoint"):
+        assert any(r["parent"] == attempt["seq"]
+                   for r in _spans(timeline, name)), name
+    init = _spans(timeline, "engine.init")[0]
+    under_init = {r["name"] for r in _below(timeline, init["seq"])}
+    assert {"data.h2d", "data.sketch_bin", "compile.backend"} <= under_init
+    assert all(r["attrs"]["bytes"] > 0 for r in _spans(timeline, "data.h2d"))
+
+
+def test_fused_run_has_one_dispatch_per_chunk():
+    timeline = _traced(rounds=4)
+    dispatches = _spans(timeline, "dispatch")
+    assert [d["attrs"]["rounds"] for d in dispatches] == [2, 2]
+    assert all(d["attrs"]["program"] == "scan" for d in dispatches)
+    seen_rounds = []
+    for d in dispatches:
+        kids = [r for r in timeline if r.get("parent") == d["seq"]]
+        names = [r["name"] for r in kids if not r["name"].startswith("compile.")]
+        assert sorted(names) == ["dispatch.enqueue", "dispatch.wait",
+                                 "round", "round"]
+        rounds = [r for r in kids if r["name"] == "round"]
+        assert all(r["attrs"]["fused_chunk"] == 2 for r in rounds)
+        seen_rounds += [r["round"] for r in rounds]
+    assert seen_rounds == [0, 1, 2, 3]
+    # every round record names a real dispatch as its parent
+    ids = {d["seq"] for d in dispatches}
+    assert all(r["parent"] in ids for r in _spans(timeline, "round"))
+
+
+@pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
+def test_first_dispatch_compiles_and_the_next_does_not(per_round):
+    timeline = _traced(per_round=per_round)
+    dispatches = _spans(timeline, "dispatch")
+    assert len(dispatches) == (4 if per_round else 2)
+    assert [d["attrs"]["first"] for d in dispatches] == (
+        [True] + [False] * (len(dispatches) - 1))
+    assert {d["attrs"]["program"] for d in dispatches} == {
+        "step" if per_round else "scan"}
+    first = _below(timeline, dispatches[0]["seq"])
+    enqueue = next(r for r in first if r["name"] == "dispatch.enqueue")
+    compiled = {r["name"] for r in first if r["parent"] == enqueue["seq"]}
+    assert {"compile.trace", "compile.lower", "compile.backend"} <= compiled
+    for d in dispatches[1:]:
+        assert not [r for r in _below(timeline, d["seq"])
+                    if r["name"].startswith("compile.")], d
+
+
+@pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
+def test_every_save_is_one_checkpoint_span_around_its_commit(per_round):
+    timeline = _traced(rounds=5, per_round=per_round)
+    saves = _spans(timeline, "driver.checkpoint")
+    commits = [r for r in timeline if r["name"] == "checkpoint.commit"]
+    assert len(saves) == len(commits) == 3  # rounds 2, 4 and the last
+    for save, commit in zip(saves, commits):
+        assert save["round"] == commit["round"]
+        assert (save["t0_s"] <= commit["t0_s"]
+                <= save["t0_s"] + save["dur_s"])
+    hooks = [r["attrs"].get("hook") for r in _spans(timeline,
+                                                    "driver.callbacks")]
+    if per_round:
+        assert hooks.count("after_round") == hooks.count("after_iteration") == 5
+    else:
+        assert hooks == [None] * 3  # one fan-out a chunk
+
+
+def test_compiles_land_under_the_span_that_caused_them():
+    import jax
+    import jax.numpy as jnp
+
+    obs.watch_compiles()
+    obs.watch_compiles()  # once per process, however often it is asked
+    t = Tracer(enabled=True, trace_dir="")
+    ones = jnp.ones(7)
+    before = obs.get_registry().counter("rxgb_compiles_total").value
+    with use_tracer(t):
+        with t.span("dispatch.enqueue"):
+            jax.jit(lambda v: jnp.sin(v) * 3 + 1)(ones)
+    recs = t.records()
+    outer = next(r for r in recs if r["name"] == "dispatch.enqueue")
+    kids = [r["name"] for r in recs if r.get("parent") == outer["seq"]]
+    # one of each: a function traced inside another's trace is not a span
+    assert kids == ["compile.trace", "compile.lower", "compile.backend"]
+    assert obs.get_registry().counter("rxgb_compiles_total").value == before + 1
+    assert validate_trace_records(recs, known_names=obs.TRACE_NAMES) == []
+
+
+def _engine(params, n_evals=0, num_actors=2):
+    from xgboost_ray_tpu.engine import TpuEngine
+    from xgboost_ray_tpu.params import parse_params
+
+    x, y = _data(128)
+    shards = [{"data": x, "label": y}]
+    evals = [(shards, "train")] + [
+        ([{"data": x[:64], "label": y[:64]}], f"v{i}") for i in range(n_evals)]
+    return TpuEngine(shards, parse_params(params), num_actors, evals=evals)
+
+
+_SCOPE_CASES = {
+    "default": (dict(_PARAMS, max_depth=3), 0, set()),
+    "eval_set": (dict(_PARAMS, max_depth=2), 1, {"eval_walk"}),
+    "subsample": (dict(_PARAMS, max_depth=2, subsample=0.5), 0, {"sample"}),
+    "int8_gh": (dict(_PARAMS, max_depth=2, gh_precision="int8"), 0,
+                {"quantize_gh"}),
+    "lossguide": (dict(_PARAMS, max_depth=3, grow_policy="lossguide",
+                       max_leaves=4), 0, set()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCOPE_CASES))
+def test_lowered_round_programs_name_their_phases(case):
+    """The scope vocabulary is in the HLO's metadata, so a device trace of
+    any later program names its phases the same way."""
+    import re
+
+    params, n_evals, extra = _SCOPE_CASES[case]
+    eng = _engine(params, n_evals)
+    eng.build_programs()
+    want = {"objective", "tree", "hist", "allreduce", "split", "partition",
+            "margin", "metrics"} | extra
+    if case != "lossguide":
+        want |= {f"level{d}" for d in range(params["max_depth"])}
+    assert {w.rstrip("0123456789") for w in want} <= obs.DEVICE_SCOPES
+    texts = {
+        "step": eng._step_fn.lower(
+            *eng._step_example_args(False)).as_text(debug_info=True),
+        "step_many": eng._scan_fn.lower(
+            *eng._scan_example_args()).as_text(debug_info=True),
+    }
+    for prog, text in texts.items():
+        have = set(re.findall(r"[\w]+", " ".join(
+            re.findall(r'loc\("([^"]+)"', text))))
+        assert want <= have, (prog, sorted(want - have))
+        # phases nest: a level's histogram is tree/level{d}/hist
+        if case != "lossguide":
+            assert "tree/level1/hist/" in text and "tree/level1/split/" in text
+
+
+def test_the_binning_program_names_sketch_and_bin():
+    from xgboost_ray_tpu import progreg
+    import jax
+
+    with progreg.capture():
+        progreg.clear()
+        _engine(_PARAMS)
+        (rec,) = [r for r in progreg.records()
+                  if r.name == "engine.sketch_cuts"]
+    progreg.clear()
+    text = jax.jit(rec.fn).lower(*rec.abstract_args).as_text(debug_info=True)
+    assert 'loc("sketch/' in text and 'loc("bin/' in text
+
+
+def test_scope_times_on_a_recorded_trace(tmp_path):
+    """``obs.device.scope_times`` over a hand-written XSpace: self times by
+    scope path, nested operations not counted twice, other lines and host
+    planes left out."""
+    from xgboost_ray_tpu.obs import device
+
+    def varint(v):
+        out = bytearray()
+        while True:
+            out.append((v & 0x7F) | (0x80 if v > 0x7F else 0))
+            v >>= 7
+            if not v:
+                return bytes(out)
+
+    def field(num, val):
+        if isinstance(val, int):
+            return varint(num << 3) + varint(val)
+        return varint(num << 3 | 2) + varint(len(val)) + bytes(val)
+
+    def entry(key, msg):
+        return field(1, key) + field(2, msg)
+
+    def event(md, offset_ps, dur_ps):
+        return field(1, md) + field(2, offset_ps) + field(3, dur_ps)
+
+    def op(md_id, name, tf_op):
+        stat = field(1, 7) + field(5, tf_op.encode())
+        return field(4, entry(md_id, field(1, md_id) + field(2, name.encode())
+                              + field(5, stat)))
+
+    body = "jit(run)/while/body/closed_call/"
+    ops = (op(1, "%while.1", "jit(run)/while")
+           + op(2, "%fusion.2", body + "tree/level0/hist/dot_general:")
+           + op(3, "%fusion.3", body + "tree/level0/partition/gather:")
+           # a cond branch repeats the scopes it sits in
+           + op(4, "%fusion.4", body + "tree/level1/hist/cond/branch_1_fun/"
+                "tree/level1/hist/add:")
+           + op(5, "%copy.5", body + "margin/jit(_where)/select_n:"))
+    stat_md = field(5, entry(7, field(1, 7) + field(2, b"tf_op")))
+    xla_ops = field(2, b"XLA Ops") + b"".join(field(4, e) for e in (
+        event(1, 0, 1000), event(2, 100, 300), event(3, 400, 200),
+        event(4, 600, 100), event(5, 1500, 500)))
+    modules = field(2, b"XLA Modules") + field(4, event(1, 0, 2000))
+    plane = (field(2, b"/device:TPU:0") + stat_md + ops
+             + field(3, xla_ops) + field(3, modules))
+    host = field(2, b"/host:CPU") + field(3, field(2, b"XLA Ops")
+                                          + field(4, event(1, 0, 9000)))
+    run_dir = tmp_path / "plugins" / "profile" / "run"
+    run_dir.mkdir(parents=True)
+    (run_dir / "host.xplane.pb").write_bytes(field(1, plane) + field(1, host))
+
+    got = device.scope_times(str(tmp_path))
+    ps = 1e-12
+    assert got == pytest.approx({
+        "(unscoped)": 400 * ps,            # the while, less its body
+        "tree/level0/hist": 300 * ps,
+        "tree/level0/partition": 200 * ps,
+        "tree/level1/hist": 100 * ps,
+        "margin": 500 * ps,
+    })
+    assert sum(got.values()) == pytest.approx(1500 * ps)  # the busy time
+    assert device.scope_times(str(tmp_path / "nothing_here")) == {}

@@ -641,8 +641,11 @@ def test_streamed_load_emits_catalogued_ingest_spans():
     assert len(by_name["data.sketch_chunk"]) == n_chunks
     assert len(by_name["data.bin_chunk"]) == n_chunks
     assert len(by_name["data.cuts_merge"]) == 1
-    # every uploaded part is fenced, with byte accounting
-    h2d = by_name["data.h2d"]
+    # every uploaded block is fenced, with byte accounting (the engine's own
+    # row uploads — valid, label, weight, margins — are data.h2d spans too,
+    # unfenced: they time the enqueue)
+    h2d = [r for r in by_name["data.h2d"] if r["attrs"].get("fenced", True)]
+    assert len(by_name["data.h2d"]) == len(h2d) + 4
     assert len(h2d) == eng._stream_stats["transfers"]
     assert sum(r["attrs"]["bytes"] for r in h2d) == eng._stream_stats["bytes"]
 

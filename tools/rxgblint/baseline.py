@@ -17,7 +17,7 @@ Schema (JSON)::
     {"entries": [
         {"rule": "OBS001",
          "path": "xgboost_ray_tpu/engine.py",
-         "scope": "TpuEngine.profile_phases.emit",
+         "scope": "TpuEngine.some_method.emit",
          "why": "one-line justification"}
     ]}
 """

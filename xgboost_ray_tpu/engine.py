@@ -512,16 +512,8 @@ class TpuEngine:
         )
         self._row_sharding = NamedSharding(self.mesh, P(AXIS_ACTORS))
 
-        from xgboost_ray_tpu.distributed import put_rows_global
-
         def put_rows(arr, dtype, fill=0):
-            # multi-host: arr holds this process's local rows and is assembled
-            # into the global sharded array without cross-host copies
-            arr = np.asarray(arr, dtype=dtype)
-            if arr.shape[0] < self._local_pad:
-                pad_width = [(0, self._local_pad - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
-                arr = np.pad(arr, pad_width, constant_values=fill)
-            return put_rows_global(arr, self._row_sharding)
+            return self._upload_rows(arr, dtype, fill, self._local_pad)
 
         self._put_rows = put_rows
         self.pad_to = pad_to
@@ -625,9 +617,15 @@ class TpuEngine:
                     init_booster
                 )
         else:
-            self.bins, self.cuts, self._feat_has_missing = self._sketch_and_bin(
-                x_dev, self.valid, self.weight_dev
-            )
+            # times trace / lower / compile (its compile.* children) and the
+            # enqueue: nothing here waits for the program (``fenced: false``).
+            # The first round program's lowering reads the cuts and so waits
+            # for it; a profiler trace has its device seconds under the
+            # ``sketch`` and ``bin`` scopes
+            with obs.get_tracer().span("data.sketch_bin", fenced=False):
+                self.bins, self.cuts, self._feat_has_missing = (
+                    self._sketch_and_bin(x_dev, self.valid, self.weight_dev)
+                )
 
         # ---- feature-axis sharding (feature_parallel > 1) ----------------
         # Sketch/binning ran at full F (one-off, row-parallel); the binned
@@ -821,6 +819,22 @@ class TpuEngine:
         )
 
     # ------------------------------------------------------------------
+    def _upload_rows(self, arr, dtype, fill, local_pad: int):
+        """Pad this process's local rows to ``local_pad`` and place them in
+        the global row-sharded layout (multi-host: assembled without
+        cross-host copies). The ``data.h2d`` span times the host's part —
+        convert, pad, enqueue; the copy itself runs on (``fenced: false``)
+        and is waited for by the first program that reads the rows."""
+        from xgboost_ray_tpu.distributed import put_rows_global
+
+        with obs.get_tracer().span("data.h2d", fenced=False) as span_attrs:
+            arr = np.asarray(arr, dtype=dtype)
+            if arr.shape[0] < local_pad:
+                pad_width = [(0, local_pad - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
+                arr = np.pad(arr, pad_width, constant_values=fill)
+            span_attrs["bytes"] = int(arr.nbytes)
+            return put_rows_global(arr, self._row_sharding)
+
     def _global_row_layout(self, local_n: int):
         """(global_n, local_pad, pad_to) for the row-sharded device layout.
 
@@ -873,7 +887,7 @@ class TpuEngine:
         max_bin = self.params.max_bin
         cat_features = self._cat_features
 
-        def fn(x, v, w):
+        def sketch(x, v, w):
             mn, mx = binning.feature_min_max(x, v)
             mn = jax.lax.pmin(mn, AXIS_ACTORS)
             mx = jax.lax.pmax(mx, AXIS_ACTORS)
@@ -888,6 +902,9 @@ class TpuEngine:
                 cat_mask = cat_mask_const(cat_features, x.shape[1])
                 code_cuts = jnp.arange(max_bin - 1, dtype=cuts.dtype) + 0.5
                 cuts = jnp.where(cat_mask[:, None], code_cuts[None, :], cuts)
+            return cuts
+
+        def bin_rows(x, v, cuts):
             bins = binning.bin_matrix(x, cuts, max_bin)
             # global per-feature "has any missing value" mask (padding rows
             # are excluded — they bin to the missing bucket by construction):
@@ -898,6 +915,12 @@ class TpuEngine:
             )
             has_missing = jax.lax.psum(miss_cnt, AXIS_ACTORS) > 0
             return bins, cuts, has_missing
+
+        def fn(x, v, w):
+            with jax.named_scope("sketch"):
+                cuts = sketch(x, v, w)
+            with jax.named_scope("bin"):
+                return bin_rows(x, v, cuts)
 
         mapped = jax.shard_map(
             fn,
@@ -1136,14 +1159,8 @@ class TpuEngine:
         )
         es.local_rows = local_rows
 
-        from xgboost_ray_tpu.distributed import put_rows_global
-
         def put_rows(arr, dtype, fill=0):
-            arr = np.asarray(arr, dtype=dtype)
-            if arr.shape[0] < local_pad:
-                pad_width = [(0, local_pad - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
-                arr = np.pad(arr, pad_width, constant_values=fill)
-            return put_rows_global(arr, self._row_sharding)
+            return self._upload_rows(arr, dtype, fill, local_pad)
 
         x_dev = put_rows(x, np.float32, fill=np.nan)
         es.bins = self._bin_with_cuts(x_dev)
@@ -1298,22 +1315,31 @@ class TpuEngine:
                     block=cfg.hist_quant_block,
                 )
 
-            w_eff = weight * valid.astype(jnp.float32)
-            if gh_in is not None:
-                g, h = gh_in
-            elif is_ranking:
-                g, h = obj.grad_hess_ranked(margins, label, w_eff, group_rows)
-            elif is_survival:
-                g, h = obj.grad_hess_bounds(margins, bounds[0], bounds[1], w_eff)
-            else:
-                g, h = obj.grad_hess(margins, label, w_eff)
+            # the jax.named_scope names below are obs.DEVICE_SCOPES: HLO
+            # metadata a profiler trace names the device's operations by
+            # (obs/device.py reads them back); nothing at run time
+            with jax.named_scope("objective"):
+                w_eff = weight * valid.astype(jnp.float32)
+                if gh_in is not None:
+                    g, h = gh_in
+                elif is_ranking:
+                    g, h = obj.grad_hess_ranked(
+                        margins, label, w_eff, group_rows
+                    )
+                elif is_survival:
+                    g, h = obj.grad_hess_bounds(
+                        margins, bounds[0], bounds[1], w_eff
+                    )
+                else:
+                    g, h = obj.grad_hess(margins, label, w_eff)
             new_margins = margins
             new_eval_margins = list(eval_margins)
             trees = []
             for k in range(k_out):
                 for t in range(t_par):
                     key = jax.random.fold_in(rng, k * t_par + t)
-                    ghk = jnp.stack([g[:, k], h[:, k]], axis=1)
+                    with jax.named_scope("objective"):
+                        ghk = jnp.stack([g[:, k], h[:, k]], axis=1)
                     ghk_scale = None
                     if cfg.gh_precision != "float32":
                         # quantize g/h AT THE SOURCE (per-tree pmax-shared
@@ -1327,11 +1353,12 @@ class TpuEngine:
                             jax.random.fold_in(key, SALT_SR),
                             jax.lax.axis_index(AXIS_ACTORS),
                         )
-                        ghk, ghk_scale = quantize_gh(
-                            ghk, cfg.gh_precision, srkey,
-                            axis_name=AXIS_ACTORS, counter=counter,
-                            max_rows=gh_max_rows,
-                        )
+                        with jax.named_scope("quantize_gh"):
+                            ghk, ghk_scale = quantize_gh(
+                                ghk, cfg.gh_precision, srkey,
+                                axis_name=AXIS_ACTORS, counter=counter,
+                                max_rows=gh_max_rows,
+                            )
                     bins_t = bins
                     if samp_spec is not None:
                         # compact the round's rows to the fixed M-row budget
@@ -1351,11 +1378,12 @@ class TpuEngine:
                             jax.random.fold_in(key, salt),
                             jax.lax.axis_index(AXIS_ACTORS),
                         )
-                        rows_sel, ghk = sampling.sample_rows(
-                            ghk, valid, skey, samp_spec, scale=ghk_scale,
-                            lane_budget=lane_budget,
-                        )
-                        bins_t = bins[rows_sel]
+                        with jax.named_scope("sample"):
+                            rows_sel, ghk = sampling.sample_rows(
+                                ghk, valid, skey, samp_spec, scale=ghk_scale,
+                                lane_budget=lane_budget,
+                            )
+                            bins_t = bins[rows_sel]
                     fmask = None
                     if params.colsample_bytree < 1.0:
                         fkey = jax.random.fold_in(key, SALT_BYTREE)
@@ -1374,46 +1402,53 @@ class TpuEngine:
                         params.colsample_bylevel < 1.0
                         or params.colsample_bynode < 1.0
                     )
-                    tree, row_value = build_tree(
-                        bins_t,
-                        ghk,
-                        cuts_grow,
-                        cfg_t,
-                        depth_limit=depth_limit,
-                        feature_mask=fmask,
-                        level_rng=key if need_level_rng else None,
-                        colsample_bylevel=params.colsample_bylevel,
-                        colsample_bynode=params.colsample_bynode,
-                        allreduce=tree_psum,
-                        feature_log_weights=self._log_fw,
-                        feat_has_missing=fhm_grow,
-                        hist_allreduce=hist_ar,
-                        ar_counter=counter,
-                        fshard=fshard,
-                        # GOSS compaction dequantizes its small [M, 2]
-                        # buffer (amplification is real-valued); the grower
-                        # then takes the f32 path over quantized-grid values
-                        gh_scale=(
-                            ghk_scale
-                            if ghk_scale is not None
-                            and jnp.issubdtype(ghk.dtype, jnp.integer)
-                            else None
-                        ),
-                    )
-                    trees.append(tree)
-                    if samp_spec is not None:
-                        # the compacted build only knows the sampled rows'
-                        # leaf values; ALL rows need their margin update (the
-                        # next round's gradients cover every row), so walk
-                        # the finished tree over the full binned matrix —
-                        # the same once-per-tree device walk eval sets use.
-                        row_value = walk(tree, bins)
-                    new_margins = new_margins.at[:, k].add(row_value / t_par)
-                    for e in range(n_evals_dev):
-                        upd = walk(tree, eval_bins[e])
-                        new_eval_margins[e] = (
-                            new_eval_margins[e].at[:, k].add(upd / t_par)
+                    with jax.named_scope("tree"):
+                        tree, row_value = build_tree(
+                            bins_t,
+                            ghk,
+                            cuts_grow,
+                            cfg_t,
+                            depth_limit=depth_limit,
+                            feature_mask=fmask,
+                            level_rng=key if need_level_rng else None,
+                            colsample_bylevel=params.colsample_bylevel,
+                            colsample_bynode=params.colsample_bynode,
+                            allreduce=tree_psum,
+                            feature_log_weights=self._log_fw,
+                            feat_has_missing=fhm_grow,
+                            hist_allreduce=hist_ar,
+                            ar_counter=counter,
+                            fshard=fshard,
+                            # GOSS compaction dequantizes its small [M, 2]
+                            # buffer (amplification is real-valued); the
+                            # grower then takes the f32 path over
+                            # quantized-grid values
+                            gh_scale=(
+                                ghk_scale
+                                if ghk_scale is not None
+                                and jnp.issubdtype(ghk.dtype, jnp.integer)
+                                else None
+                            ),
                         )
+                    trees.append(tree)
+                    with jax.named_scope("margin"):
+                        if samp_spec is not None:
+                            # the compacted build only knows the sampled
+                            # rows' leaf values; ALL rows need their margin
+                            # update (the next round's gradients cover every
+                            # row), so walk the finished tree over the full
+                            # binned matrix — the same once-per-tree device
+                            # walk eval sets use.
+                            row_value = walk(tree, bins)
+                        new_margins = new_margins.at[:, k].add(
+                            row_value / t_par
+                        )
+                    with jax.named_scope("eval_walk"):
+                        for e in range(n_evals_dev):
+                            upd = walk(tree, eval_bins[e])
+                            new_eval_margins[e] = (
+                                new_eval_margins[e].at[:, k].add(upd / t_par)
+                            )
             forest = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
             # total per-chip wire bytes of the round: actors-axis traffic
             # (histogram merges + exact reductions) plus, on a 2D mesh, the
@@ -1425,39 +1460,40 @@ class TpuEngine:
         def metric_contribs(new_margins, new_eval_margins, label, w_eff,
                             train_group_rows, eval_data, bounds=None):
             """Post-update psum'd (num, den) pairs per eval set x metric."""
-            contribs = []
-            ei = 0
-            for es in self.evals:
-                if es.is_train:
-                    m, lab, w = new_margins, label, w_eff
-                    gr, bnd = train_group_rows, bounds
-                else:
-                    ed = eval_data[ei]
-                    m, lab, w = (
-                        new_eval_margins[ei],
-                        ed.label,
-                        ed.weight * ed.valid.astype(jnp.float32),
-                    )
-                    gr, bnd = ed.group_rows, ed.bounds
-                    ei += 1
-                set_contribs = []
-                for name in dev_metrics:
-                    set_contribs.append(
-                        device_metric_contrib(
-                            name, m, lab, w, gr, psum,
-                            huber_slope=params.huber_slope,
-                            quantile_alpha=tuple(
-                                params.quantile_alpha
-                                if isinstance(params.quantile_alpha, (list, tuple))
-                                else [params.quantile_alpha]
-                            ),
-                            bounds=bnd,
-                            aft_distribution=params.aft_loss_distribution,
-                            aft_sigma=params.aft_loss_distribution_scale,
+            with jax.named_scope("metrics"):
+                contribs = []
+                ei = 0
+                for es in self.evals:
+                    if es.is_train:
+                        m, lab, w = new_margins, label, w_eff
+                        gr, bnd = train_group_rows, bounds
+                    else:
+                        ed = eval_data[ei]
+                        m, lab, w = (
+                            new_eval_margins[ei],
+                            ed.label,
+                            ed.weight * ed.valid.astype(jnp.float32),
                         )
-                    )
-                contribs.append(tuple(set_contribs))
-            return tuple(contribs)
+                        gr, bnd = ed.group_rows, ed.bounds
+                        ei += 1
+                    set_contribs = []
+                    for name in dev_metrics:
+                        set_contribs.append(
+                            device_metric_contrib(
+                                name, m, lab, w, gr, psum,
+                                huber_slope=params.huber_slope,
+                                quantile_alpha=tuple(
+                                    params.quantile_alpha
+                                    if isinstance(params.quantile_alpha, (list, tuple))
+                                    else [params.quantile_alpha]
+                                ),
+                                bounds=bnd,
+                                aft_distribution=params.aft_loss_distribution,
+                                aft_sigma=params.aft_loss_distribution_scale,
+                            )
+                        )
+                    contribs.append(tuple(set_contribs))
+                return tuple(contribs)
 
         return tree_round, metric_contribs
 
@@ -1749,11 +1785,38 @@ class TpuEngine:
     def can_batch_rounds(self) -> bool:
         return not self._host_metrics and not self.dart
 
+    def _adopt_eval_margins(self, new_eval_margins) -> None:
+        """The dispatch's updated margins of the non-train eval sets."""
+        for es, margins in zip(
+            (es for es in self.evals if not es.is_train), new_eval_margins
+        ):
+            es.margins = margins
+
+    def _read_metric_scalars(self, contribs, empty_shape) -> np.ndarray:
+        """Every (num, den) scalar of the dispatch in ONE stacked transfer
+        (rows: eval set x metric x (num, den)) instead of a blocking host
+        read per scalar; this read is the sync ``dispatch.wait`` ends at."""
+        flat = [
+            c
+            for si in range(len(self.evals))
+            for mi in range(len(self._device_metrics))
+            for c in contribs[si][mi]
+        ]
+        return np.asarray(jnp.stack(flat)) if flat else np.zeros(empty_shape)
+
+    @staticmethod
+    def _metric_value(name: str, num, den) -> float:
+        val = float(num) / max(float(den), 1e-12)
+        base, _ = parse_metric_name(name)
+        return float(np.sqrt(val)) if base in ("rmse", "rmsle") else val
+
     def _emit_round_spans(self, ts, t0, round0: int, n_rounds: int = 1) -> None:
-        """Record per-round spans on the current tracer, fenced by the same
-        host-side sync the step paths already perform (no extra device round
-        trips). Fused-scan chunks amortize the chunk duration evenly and mark
-        each span with ``fused_chunk`` so consumers know the granularity."""
+        """Record one ``round`` span per boosting round of the open
+        ``dispatch`` span (their parent), fenced by the same host-side sync
+        the step paths already perform (no extra device round trips). What
+        happened is the dispatch; a fused-scan chunk's rounds are its
+        duration split evenly, each marked ``fused_chunk`` so consumers
+        (``after_round`` streaming, ``obs["rounds"]``) know the granularity."""
         tracer = obs.get_tracer()
         if not tracer.enabled:
             return
@@ -1763,7 +1826,8 @@ class TpuEngine:
             attrs = dict(attrs, fused_chunk=n_rounds)
         for r in range(n_rounds):
             tracer.add_span(
-                "round", ts + r * dur, dur, round=round0 + r, attrs=attrs
+                "round", ts + r * dur, t0 + r * dur, dur, round=round0 + r,
+                attrs=attrs,
             )
 
     def step_many(self, iteration0: int, n_rounds: int) -> List[Dict[str, Dict[str, float]]]:
@@ -1780,67 +1844,68 @@ class TpuEngine:
             )
         if not self.can_batch_rounds():
             raise RuntimeError("host-side metrics require per-round stepping")
-        span_ts, span_t0 = time.time(), time.perf_counter()
-        if self._scan_fn is None:
-            self._scan_fn = self._make_scan_step()
-        iterations = jnp.arange(
-            self.iteration_offset + iteration0,
-            self.iteration_offset + iteration0 + n_rounds,
-        )
-        eval_data = self._eval_arrs()
-        group_rows = self._default_group_rows()
-        bounds = self._default_bounds()
-        # the scan program compiles once per distinct chunk length; the
-        # strict guard arms only for chunk lengths already dispatched
         prog = ("scan", n_rounds)
-        with strict_transfer_guard(active=prog in self._warm_programs):
-            new_margins, new_eval_margins, forests, contribs, ar_bytes = self._scan_fn(
-                self.bins,
-                self.valid,
-                self.label_dev,
-                self.weight_dev,
-                self.margins,
-                group_rows,
-                iterations,
-                bounds,
-                eval_data,
-            )
-        self._warm_programs.add(prog)
-        # keep the device scalar; materialized lazily by the accessor so the
-        # steady-state step path adds NO host reads (transfer-count contract)
-        self._ar_bytes_dev = ar_bytes[0]
-        self.margins = new_margins
-        ei = 0
-        for es in self.evals:
-            if not es.is_train:
-                es.margins = new_eval_margins[ei]
-                ei += 1
-        # defer forest transfer: keep the whole stacked chunk on device
-        # (order-safe alongside per-round step()s) and materialize it in ONE
-        # batched read per Tree field at the next checkpoint/get_booster
-        # instead of an eager 9-field read per chunk
-        self._trees_dev.append((forests, n_rounds))
+        tracer = obs.get_tracer()
+        with tracer.span(
+            "dispatch", program="scan", rounds=n_rounds,
+            first=prog not in self._warm_programs,
+        ):
+            span_ts, span_t0 = time.time(), time.perf_counter()
+            with tracer.span("dispatch.enqueue"):
+                if self._scan_fn is None:
+                    self._scan_fn = self._make_scan_step()
+                # placed from the host: jnp.arange(a, b) compiles an add for
+                # the first a > 0, i.e. inside the second chunk's dispatch
+                iterations = jax.device_put(
+                    np.arange(
+                        self.iteration_offset + iteration0,
+                        self.iteration_offset + iteration0 + n_rounds,
+                        dtype=np.int32,
+                    ),
+                    NamedSharding(self.mesh, P()),
+                )
+                eval_data = self._eval_arrs()
+                group_rows = self._default_group_rows()
+                bounds = self._default_bounds()
+                # the scan program compiles once per distinct chunk length; the
+                # strict guard arms only for chunk lengths already dispatched
+                with strict_transfer_guard(active=prog in self._warm_programs):
+                    new_margins, new_eval_margins, forests, contribs, ar_bytes = self._scan_fn(
+                        self.bins,
+                        self.valid,
+                        self.label_dev,
+                        self.weight_dev,
+                        self.margins,
+                        group_rows,
+                        iterations,
+                        bounds,
+                        eval_data,
+                    )
+            self._warm_programs.add(prog)
+            # keep the device scalar; materialized lazily by the accessor so the
+            # steady-state step path adds NO host reads (transfer-count contract)
+            self._ar_bytes_dev = ar_bytes[0]
+            self.margins = new_margins
+            self._adopt_eval_margins(new_eval_margins)
+            # defer forest transfer: keep the whole stacked chunk on device
+            # (order-safe alongside per-round step()s) and materialize it in ONE
+            # batched read per Tree field at the next checkpoint/get_booster
+            # instead of an eager 9-field read per chunk
+            self._trees_dev.append((forests, n_rounds))
 
-        # metrics: one stacked transfer for ALL (num, den) scalars of the
-        # whole chunk instead of a device read per (eval, metric, row)
-        flat_scalars = [
-            c
-            for si in range(len(self.evals))
-            for mi in range(len(self._device_metrics))
-            for c in contribs[si][mi]
-        ]
-        if flat_scalars:
-            flat_vals = np.asarray(jnp.stack(flat_scalars))
-        else:
-            flat_vals = np.zeros((0, n_rounds))
-            # with no eval sets, the metric read above is skipped and (with
-            # forest transfer deferred) nothing else syncs — block so that
-            # returning means "chunk computed", keeping round_times_s and
-            # the overhead ablation honest
-            new_margins.block_until_ready()
-        self._emit_round_spans(
-            span_ts, span_t0, self.iteration_offset + iteration0, n_rounds
-        )
+            # metrics: one stacked transfer for ALL (num, den) scalars of the
+            # whole chunk instead of a device read per (eval, metric, row)
+            with tracer.span("dispatch.wait"):
+                flat_vals = self._read_metric_scalars(contribs, (0, n_rounds))
+                if not flat_vals.size:
+                    # with no eval sets, the metric read above is skipped and (with
+                    # forest transfer deferred) nothing else syncs — block so that
+                    # returning means "chunk computed", keeping round_times_s and
+                    # the overhead ablation honest
+                    new_margins.block_until_ready()
+            self._emit_round_spans(
+                span_ts, span_t0, self.iteration_offset + iteration0, n_rounds
+            )
         results: List[Dict[str, Dict[str, float]]] = []
         for r in range(n_rounds):
             round_res: Dict[str, Dict[str, float]] = {}
@@ -1848,12 +1913,10 @@ class TpuEngine:
             for si, es in enumerate(self.evals):
                 row: Dict[str, float] = {}
                 for mi, name in enumerate(self._device_metrics):
-                    num = float(flat_vals[fi][r])
-                    den = float(flat_vals[fi + 1][r])
+                    row[name] = self._metric_value(
+                        name, flat_vals[fi][r], flat_vals[fi + 1][r]
+                    )
                     fi += 2
-                    val = num / max(den, 1e-12)
-                    base, _ = parse_metric_name(name)
-                    row[name] = float(np.sqrt(val)) if base in ("rmse", "rmsle") else val
                 round_res[es.name] = row
             results.append(round_res)
         return results
@@ -1868,96 +1931,90 @@ class TpuEngine:
             if gh_custom is not None:
                 raise ValueError("custom objectives are not supported with dart")
             return self.step_dart(iteration)
-        span_ts, span_t0 = time.time(), time.perf_counter()
         custom = gh_custom is not None
-        if custom:
-            if self._step_fn_custom is None:
-                self._step_fn_custom = self._make_step(custom=True)
-            fn = self._step_fn_custom
-        else:
-            if self._step_fn is None:
-                self._step_fn = self._make_step(custom=False)
-            fn = self._step_fn
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.params.seed), self.iteration_offset + iteration
-        )
-        eval_data = self._eval_arrs()
-        group_rows = self._default_group_rows()
-        if custom:
-            # g/h hold THIS process's rows (the driver computes the custom
-            # objective from get_margins_local + process-local labels — the
-            # reference's per-actor local computation, ``main.py:745-752``);
-            # _put_rows assembles them into the global sharded layout.
-            g, h = gh_custom
-            gh_in = (
-                self._put_rows(
-                    np.asarray(g, np.float32).reshape(self._local_rows, -1),
-                    np.float32,
-                ),
-                self._put_rows(
-                    np.asarray(h, np.float32).reshape(self._local_rows, -1),
-                    np.float32,
-                ),
-            )
-        else:
-            gh_in = jnp.zeros((), jnp.float32)
-        bounds = self._default_bounds()
         prog = "step_custom" if custom else "step"
-        with strict_transfer_guard(active=prog in self._warm_programs):
-            new_margins, new_eval_margins, forest, contribs, ar_bytes = fn(
-                self.bins,
-                self.valid,
-                self.label_dev,
-                self.weight_dev,
-                self.margins,
-                group_rows,
-                gh_in,
-                rng,
-                bounds,
-                eval_data,
-            )
-        self._warm_programs.add(prog)
-        self._ar_bytes_dev = ar_bytes
-        self.margins = new_margins
-        ei = 0
-        for es in self.evals:
-            if not es.is_train:
-                es.margins = new_eval_margins[ei]
-                ei += 1
-        self._trees_dev.append((forest, None))
-
-        # metrics: one stacked transfer for all (num, den) scalars instead of
-        # a blocking host read per scalar
-        flat_scalars = [
-            c
-            for si in range(len(self.evals))
-            for mi in range(len(self._device_metrics))
-            for c in contribs[si][mi]
-        ]
-        flat_vals = (
-            np.asarray(jnp.stack(flat_scalars)) if flat_scalars else np.zeros(0)
-        )
-        results: Dict[str, Dict[str, float]] = {}
-        fi = 0
-        for si, es in enumerate(self.evals):
-            row: Dict[str, float] = {}
-            for mi, name in enumerate(self._device_metrics):
-                num, den = float(flat_vals[fi]), float(flat_vals[fi + 1])
-                fi += 2
-                val = num / max(den, 1e-12)
-                base, _ = parse_metric_name(name)
-                row[name] = float(np.sqrt(val)) if base in ("rmse", "rmsle") else val
-            if self._host_metrics:
-                margin = self.get_margins_local(es)
-                for name in self._host_metrics:
-                    row[name] = self.combine_host_scalar(
-                        self._host_metric_value(name, margin, es), es,
-                        metric=name,
+        tracer = obs.get_tracer()
+        with tracer.span(
+            "dispatch", program=prog, rounds=1,
+            first=prog not in self._warm_programs,
+        ):
+            span_ts, span_t0 = time.time(), time.perf_counter()
+            with tracer.span("dispatch.enqueue"):
+                if custom:
+                    if self._step_fn_custom is None:
+                        self._step_fn_custom = self._make_step(custom=True)
+                    fn = self._step_fn_custom
+                else:
+                    if self._step_fn is None:
+                        self._step_fn = self._make_step(custom=False)
+                    fn = self._step_fn
+                rng = jax.random.fold_in(
+                    jax.random.PRNGKey(self.params.seed), self.iteration_offset + iteration
+                )
+                eval_data = self._eval_arrs()
+                group_rows = self._default_group_rows()
+                if custom:
+                    # g/h hold THIS process's rows (the driver computes the custom
+                    # objective from get_margins_local + process-local labels — the
+                    # reference's per-actor local computation, ``main.py:745-752``);
+                    # _put_rows assembles them into the global sharded layout.
+                    g, h = gh_custom
+                    gh_in = (
+                        self._put_rows(
+                            np.asarray(g, np.float32).reshape(self._local_rows, -1),
+                            np.float32,
+                        ),
+                        self._put_rows(
+                            np.asarray(h, np.float32).reshape(self._local_rows, -1),
+                            np.float32,
+                        ),
                     )
-            results[es.name] = row
-        self._emit_round_spans(
-            span_ts, span_t0, self.iteration_offset + iteration
-        )
+                else:
+                    gh_in = jnp.zeros((), jnp.float32)
+                bounds = self._default_bounds()
+                with strict_transfer_guard(active=prog in self._warm_programs):
+                    new_margins, new_eval_margins, forest, contribs, ar_bytes = fn(
+                        self.bins,
+                        self.valid,
+                        self.label_dev,
+                        self.weight_dev,
+                        self.margins,
+                        group_rows,
+                        gh_in,
+                        rng,
+                        bounds,
+                        eval_data,
+                    )
+            self._warm_programs.add(prog)
+            self._ar_bytes_dev = ar_bytes
+            self.margins = new_margins
+            self._adopt_eval_margins(new_eval_margins)
+            self._trees_dev.append((forest, None))
+
+            # metrics: one stacked transfer for all (num, den) scalars instead of
+            # a blocking host read per scalar
+            with tracer.span("dispatch.wait"):
+                flat_vals = self._read_metric_scalars(contribs, 0)
+            results: Dict[str, Dict[str, float]] = {}
+            fi = 0
+            for si, es in enumerate(self.evals):
+                row: Dict[str, float] = {}
+                for mi, name in enumerate(self._device_metrics):
+                    row[name] = self._metric_value(
+                        name, flat_vals[fi], flat_vals[fi + 1]
+                    )
+                    fi += 2
+                if self._host_metrics:
+                    margin = self.get_margins_local(es)
+                    for name in self._host_metrics:
+                        row[name] = self.combine_host_scalar(
+                            self._host_metric_value(name, margin, es), es,
+                            metric=name,
+                        )
+                results[es.name] = row
+            self._emit_round_spans(
+                span_ts, span_t0, self.iteration_offset + iteration
+            )
         return results
 
     def _host_metric_value(self, name: str, margin: np.ndarray, es) -> float:
@@ -2511,72 +2568,61 @@ class TpuEngine:
         map through ``lane_ids()`` for original candidate identity)."""
         if not self._vk:
             raise RuntimeError("enable_lanes() first")
-        span_ts, span_t0 = time.time(), time.perf_counter()
-        k = self._vk
-        fn = self._vk_fns.get(k)
-        if fn is None:
-            fn = self._vk_fns[k] = self._make_vmapped_step(k)
-        eval_data = self._eval_arrs()
-        group_rows = self._default_group_rows()
-        bounds = self._default_bounds()
-        rngs = self._vk_rngs(iteration)
-        prog = ("vmapped", k)
-        with strict_transfer_guard(active=prog in self._warm_programs):
-            new_margins, new_eval_margins, forests, contribs, ar_bytes = fn(
-                self.bins,
-                self.valid,
-                self.label_dev,
-                self.weight_dev,
-                self.margins,
-                group_rows,
-                self._vk_lane_arrays,
-                rngs,
-                bounds,
-                eval_data,
-            )
-        self._warm_programs.add(prog)
-        self._ar_bytes_dev = ar_bytes[0]
-        self.margins = new_margins
-        ei = 0
-        for es in self.evals:
-            if not es.is_train:
-                es.margins = new_eval_margins[ei]
-                ei += 1
-        # defer the [K, T, heap] forest transfer like the scalar path
-        self._vk_trees_dev.append(forests)
-
-        # metrics: one stacked [2*n_metrics*n_evals, K] transfer
-        flat_scalars = [
-            c
-            for si in range(len(self.evals))
-            for mi in range(len(self._device_metrics))
-            for c in contribs[si][mi]
-        ]
-        flat_vals = (
-            np.asarray(jnp.stack(flat_scalars))
-            if flat_scalars else np.zeros((0, k))
-        )
-        results: List[Dict[str, Dict[str, float]]] = []
-        for j in range(k):
-            lane_res: Dict[str, Dict[str, float]] = {}
-            fi = 0
-            for si, es in enumerate(self.evals):
-                row: Dict[str, float] = {}
-                for mi, name in enumerate(self._device_metrics):
-                    num = float(flat_vals[fi][j])
-                    den = float(flat_vals[fi + 1][j])
-                    fi += 2
-                    val = num / max(den, 1e-12)
-                    base, _ = parse_metric_name(name)
-                    row[name] = (
-                        float(np.sqrt(val)) if base in ("rmse", "rmsle")
-                        else val
+        prog = ("vmapped", self._vk)
+        tracer = obs.get_tracer()
+        with tracer.span(
+            "dispatch", program="vmapped", rounds=1,
+            first=prog not in self._warm_programs,
+        ):
+            span_ts, span_t0 = time.time(), time.perf_counter()
+            with tracer.span("dispatch.enqueue"):
+                k = self._vk
+                fn = self._vk_fns.get(k)
+                if fn is None:
+                    fn = self._vk_fns[k] = self._make_vmapped_step(k)
+                eval_data = self._eval_arrs()
+                group_rows = self._default_group_rows()
+                bounds = self._default_bounds()
+                rngs = self._vk_rngs(iteration)
+                with strict_transfer_guard(active=prog in self._warm_programs):
+                    new_margins, new_eval_margins, forests, contribs, ar_bytes = fn(
+                        self.bins,
+                        self.valid,
+                        self.label_dev,
+                        self.weight_dev,
+                        self.margins,
+                        group_rows,
+                        self._vk_lane_arrays,
+                        rngs,
+                        bounds,
+                        eval_data,
                     )
-                lane_res[es.name] = row
-            results.append(lane_res)
-        self._emit_round_spans(
-            span_ts, span_t0, self.iteration_offset + iteration
-        )
+            self._warm_programs.add(prog)
+            self._ar_bytes_dev = ar_bytes[0]
+            self.margins = new_margins
+            self._adopt_eval_margins(new_eval_margins)
+            # defer the [K, T, heap] forest transfer like the scalar path
+            self._vk_trees_dev.append(forests)
+
+            # metrics: one stacked [2*n_metrics*n_evals, K] transfer
+            with tracer.span("dispatch.wait"):
+                flat_vals = self._read_metric_scalars(contribs, (0, k))
+            results: List[Dict[str, Dict[str, float]]] = []
+            for j in range(k):
+                lane_res: Dict[str, Dict[str, float]] = {}
+                fi = 0
+                for si, es in enumerate(self.evals):
+                    row: Dict[str, float] = {}
+                    for mi, name in enumerate(self._device_metrics):
+                        row[name] = self._metric_value(
+                            name, flat_vals[fi][j], flat_vals[fi + 1][j]
+                        )
+                        fi += 2
+                    lane_res[es.name] = row
+                results.append(lane_res)
+            self._emit_round_spans(
+                span_ts, span_t0, self.iteration_offset + iteration
+            )
         return results
 
     def lane_ids(self) -> List[int]:
@@ -3012,332 +3058,104 @@ class TpuEngine:
 
     def step_dart(self, iteration: int) -> Dict[str, Dict[str, float]]:
         params = self.params
-        span_ts, span_t0 = time.time(), time.perf_counter()
-        if self.dart_t + self.n_outputs > self._dart_t_cap:
-            # the in-program dynamic_update_slice CLAMPS an out-of-range
-            # slot, which would silently overwrite the newest trees —
-            # unreachable under the driver's round arithmetic (capacity
-            # covers init + total_rounds, resets keep the invariant), so
-            # tripping it means a bookkeeping bug, not a user error
-            raise RuntimeError(
-                f"dart forest capacity exhausted: slot {self.dart_t} + "
-                f"{self.n_outputs} trees > t_cap {self._dart_t_cap}"
-            )
-        if self._dart_fn is None:
-            self._dart_fn = self._make_dart_step()
-        lr = params.learning_rate
-        drop = self._dart_sample_drops(iteration)
-        k_dropped = int(drop.sum())
-        if k_dropped:
-            if params.normalize_type == "forest":
-                new_w, drop_scale = 1.0 / (1.0 + lr), 1.0 / (1.0 + lr)
-            else:  # "tree"
-                new_w = 1.0 / (k_dropped + lr)
-                drop_scale = k_dropped / (k_dropped + lr)
-        else:
-            new_w, drop_scale = 1.0, 1.0
-        w_eff = self.dart_weights.copy()
-        w_eff[drop] = 0.0
-        w_post = self.dart_weights.copy()
-        w_post[drop] *= drop_scale
-
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(params.seed), self.iteration_offset + iteration
-        )
-        eval_data = self._eval_arrs()
-        group_rows = self._default_group_rows()
-        bounds = self._default_bounds()
-        # the per-round drop weights / tree index are legitimate host
-        # inputs of the dart program: place them explicitly (replicated)
-        # BEFORE entering the strict guard, which rejects the implicit
-        # upload-and-reshard the bare jnp conversions would trigger
-        repl = NamedSharding(self.mesh, P())
-        w_eff_dev = jax.device_put(np.asarray(w_eff), repl)
-        w_post_dev = jax.device_put(np.asarray(w_post), repl)
-        new_w_dev = jax.device_put(np.float32(new_w), repl)
-        dart_t_dev = jax.device_put(np.int32(self.dart_t), repl)
-        with strict_transfer_guard(active="dart" in self._warm_programs):
-            m_full, new_eval_margins, forest, round_forest, contribs, ar_bytes = self._dart_fn(
-                self.bins,
-                self.valid,
-                self.label_dev,
-                self.weight_dev,
-                self._margins_static_dev,
-                group_rows,
-                bounds,
-                self.dart_forest_dev,
-                w_eff_dev,
-                w_post_dev,
-                new_w_dev,
-                dart_t_dev,
-                rng,
-                eval_data,
-            )
-        self._warm_programs.add("dart")
-        self.margins = m_full
-        self._ar_bytes_dev = ar_bytes
-        self.dart_forest_dev = forest
-        ei = 0
-        for es in self.evals:
-            if not es.is_train:
-                es.margins = new_eval_margins[ei]
-                ei += 1
-        self._trees_dev.append((round_forest, None))
-        w_new_vec = w_post
-        w_new_vec[self.dart_t : self.dart_t + self.n_outputs] = new_w
-        self.dart_weights = w_new_vec
-        self.dart_t += self.n_outputs
-
-        results: Dict[str, Dict[str, float]] = {}
-        for si, es in enumerate(self.evals):
-            row: Dict[str, float] = {}
-            for mi, name in enumerate(self._device_metrics):
-                num, den = contribs[si][mi]
-                num, den = float(num), float(den)
-                val = num / max(den, 1e-12)
-                base, _ = parse_metric_name(name)
-                row[name] = float(np.sqrt(val)) if base in ("rmse", "rmsle") else val
-            if self._host_metrics:
-                margin = self.get_margins_local(es)
-                for name in self._host_metrics:
-                    row[name] = self.combine_host_scalar(
-                        self._host_metric_value(name, margin, es), es,
-                        metric=name,
+        tracer = obs.get_tracer()
+        with tracer.span(
+            "dispatch", program="dart", rounds=1,
+            first="dart" not in self._warm_programs,
+        ):
+            span_ts, span_t0 = time.time(), time.perf_counter()
+            with tracer.span("dispatch.enqueue"):
+                if self.dart_t + self.n_outputs > self._dart_t_cap:
+                    # the in-program dynamic_update_slice CLAMPS an out-of-range
+                    # slot, which would silently overwrite the newest trees —
+                    # unreachable under the driver's round arithmetic (capacity
+                    # covers init + total_rounds, resets keep the invariant), so
+                    # tripping it means a bookkeeping bug, not a user error
+                    raise RuntimeError(
+                        f"dart forest capacity exhausted: slot {self.dart_t} + "
+                        f"{self.n_outputs} trees > t_cap {self._dart_t_cap}"
                     )
-            results[es.name] = row
-        self._emit_round_spans(
-            span_ts, span_t0, self.iteration_offset + iteration
-        )
+                if self._dart_fn is None:
+                    self._dart_fn = self._make_dart_step()
+                lr = params.learning_rate
+                drop = self._dart_sample_drops(iteration)
+                k_dropped = int(drop.sum())
+                if k_dropped:
+                    if params.normalize_type == "forest":
+                        new_w, drop_scale = 1.0 / (1.0 + lr), 1.0 / (1.0 + lr)
+                    else:  # "tree"
+                        new_w = 1.0 / (k_dropped + lr)
+                        drop_scale = k_dropped / (k_dropped + lr)
+                else:
+                    new_w, drop_scale = 1.0, 1.0
+                w_eff = self.dart_weights.copy()
+                w_eff[drop] = 0.0
+                w_post = self.dart_weights.copy()
+                w_post[drop] *= drop_scale
+
+                rng = jax.random.fold_in(
+                    jax.random.PRNGKey(params.seed), self.iteration_offset + iteration
+                )
+                eval_data = self._eval_arrs()
+                group_rows = self._default_group_rows()
+                bounds = self._default_bounds()
+                # the per-round drop weights / tree index are legitimate host
+                # inputs of the dart program: place them explicitly (replicated)
+                # BEFORE entering the strict guard, which rejects the implicit
+                # upload-and-reshard the bare jnp conversions would trigger
+                repl = NamedSharding(self.mesh, P())
+                w_eff_dev = jax.device_put(np.asarray(w_eff), repl)
+                w_post_dev = jax.device_put(np.asarray(w_post), repl)
+                new_w_dev = jax.device_put(np.float32(new_w), repl)
+                dart_t_dev = jax.device_put(np.int32(self.dart_t), repl)
+                with strict_transfer_guard(active="dart" in self._warm_programs):
+                    m_full, new_eval_margins, forest, round_forest, contribs, ar_bytes = self._dart_fn(
+                        self.bins,
+                        self.valid,
+                        self.label_dev,
+                        self.weight_dev,
+                        self._margins_static_dev,
+                        group_rows,
+                        bounds,
+                        self.dart_forest_dev,
+                        w_eff_dev,
+                        w_post_dev,
+                        new_w_dev,
+                        dart_t_dev,
+                        rng,
+                        eval_data,
+                    )
+            self._warm_programs.add("dart")
+            self.margins = m_full
+            self._ar_bytes_dev = ar_bytes
+            self.dart_forest_dev = forest
+            self._adopt_eval_margins(new_eval_margins)
+            self._trees_dev.append((round_forest, None))
+            w_new_vec = w_post
+            w_new_vec[self.dart_t : self.dart_t + self.n_outputs] = new_w
+            self.dart_weights = w_new_vec
+            self.dart_t += self.n_outputs
+
+            with tracer.span("dispatch.wait"):
+                results: Dict[str, Dict[str, float]] = {}
+                for si, es in enumerate(self.evals):
+                    row: Dict[str, float] = {}
+                    for mi, name in enumerate(self._device_metrics):
+                        row[name] = self._metric_value(
+                            name, *contribs[si][mi]
+                        )
+                    if self._host_metrics:
+                        margin = self.get_margins_local(es)
+                        for name in self._host_metrics:
+                            row[name] = self.combine_host_scalar(
+                                self._host_metric_value(name, margin, es), es,
+                                metric=name,
+                            )
+                    results[es.name] = row
+            self._emit_round_spans(
+                span_ts, span_t0, self.iteration_offset + iteration
+            )
         return results
-
-    # ------------------------------------------------------------------
-    # Fenced per-phase profiling (the obs plane's runtime replacement for
-    # bench.py's former standalone phase timers).
-    # ------------------------------------------------------------------
-
-    def profile_phases(self, tracer=None, iters: int = 3) -> Dict[str, Any]:
-        """Micro-time each round phase (``sample`` / ``hist`` / ``split`` /
-        ``partition`` / ``margin`` / ``allreduce``) standalone at THIS
-        engine's true per-shard shapes, emitting one span per phase on the
-        current tracer with compile-vs-execute separated via
-        ``jax.block_until_ready`` and rows/bytes attributes attached.
-
-        The compiled round step fuses these phases (XLA may overlap them),
-        so this is a phase-share approximation, not an in-program trace —
-        but it runs against the engine's real shard block size, sampling
-        budget, resolved hist impl and split params, so the breakdown
-        reflects the program that actually trains. Returns the
-        ``phase_profile`` dict that ``train()`` surfaces under
-        ``additional_results["obs"]`` when ``RXGB_TRACE_PHASES=1``."""
-        import functools
-
-        from xgboost_ray_tpu.ops.grow import empty_tree, route_right_binned
-        from xgboost_ray_tpu.ops.split import find_splits
-
-        tracer = tracer if tracer is not None else obs.get_tracer()
-        n_local = self.pad_to // self.n_devices  # one shard's row block
-        # per-chip feature tile width (== F on the 1D mesh)
-        n_feat = (
-            self._f_padded // self.feature_parallel
-            if self.feature_parallel > 1
-            else self.n_features
-        )
-        depth = self.cfg.max_depth
-        max_bin = self.params.max_bin
-        nbt = max_bin + 1
-        provider = self.cfg.hist_provider()
-        impl = provider.name
-        spec = sampling.spec_from_params(self.params)
-        m = n_local if spec is None else sampling.row_budget(n_local, spec)
-
-        rng = np.random.RandomState(0)
-        bins = jnp.asarray(
-            rng.randint(0, max_bin, size=(n_local, n_feat)), jnp.uint8
-        )
-        gh = jnp.asarray(
-            np.stack(
-                [rng.standard_normal(n_local),
-                 np.abs(rng.standard_normal(n_local))],
-                axis=1,
-            ),
-            jnp.float32,
-        )
-        valid = jnp.ones((n_local,), bool)
-        key = jax.random.PRNGKey(0)
-        gh_scale = None
-        if self.cfg.gh_precision != "float32":
-            # profile the int path the real round runs: quantized gh buffer
-            # feeding the builders (no mesh here, so no pmax — the scales
-            # only affect values, not shapes/dtypes)
-            gh, gh_scale = jax.jit(
-                lambda g, k, _m=self.cfg.gh_precision, _r=int(self.pad_to):
-                quantize_gh(g, _m, k, max_rows=_r)
-            )(gh, key)
-
-        def fenced(fn, *args):
-            """(compile_s, execute_s): the first call carries compile; the
-            steady mean over ``iters`` further calls is execute — every
-            timing fenced by block_until_ready."""
-            t0 = time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            first = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(*args)
-            jax.block_until_ready(out)
-            execute = (time.perf_counter() - t0) / iters
-            return max(first - execute, 0.0), execute
-
-        phases: Dict[str, Dict[str, Any]] = {}
-
-        def emit(name, compile_s, execute_s, rows, **extra):
-            attrs = {"compile_s": round(compile_s, 6), "rows": int(rows)}
-            attrs.update(extra)
-            tracer.add_span(name, time.time(), execute_s, attrs=attrs)
-            phases[name] = {
-                "compile_ms": round(1e3 * compile_s, 3),
-                "execute_ms": round(1e3 * execute_s, 3),
-                "rows": int(rows),
-                **extra,
-            }
-
-        # -- sample: budget selection + row gather (absent for full rows)
-        if spec is None:
-            emit("sample", 0.0, 0.0, n_local)
-            bins_m, gh_m = bins, gh
-        else:
-            sample_fn = jax.jit(
-                lambda g, v, k, _s=spec, _sc=gh_scale: sampling.sample_rows(
-                    g, v, k, _s, scale=_sc
-                )
-            )
-            gather_fn = jax.jit(lambda r: bins[r])
-            rows_sel, gh_m = sample_fn(gh, valid, key)
-            c1, e1 = fenced(sample_fn, gh, valid, key)
-            c2, e2 = fenced(gather_fn, rows_sel)
-            bins_m = gather_fn(rows_sel)
-            emit("sample", c1 + c2, e1 + e2, m)
-
-        # -- hist + partition, per level (sibling subtraction halves the
-        # built fan-out beyond the root, exactly as the real builds do)
-        hist_c = hist_e = part_c = part_e = 0.0
-        split_c = split_e = 0.0
-        for d in range(depth):
-            n_nodes = 1 << d
-            build_nodes = max(1, n_nodes // 2) if d > 0 else 1
-            pos = jnp.asarray(
-                rng.randint(0, build_nodes, size=(m,)), jnp.int32
-            )
-            hist_fn = jax.jit(
-                functools.partial(
-                    provider.build,
-                    n_nodes=build_nodes,
-                    n_bins_total=nbt,
-                )
-            )
-            c, e = fenced(hist_fn, bins_m, gh_m, pos)
-            hist_c, hist_e = hist_c + c, hist_e + e
-
-            hist = jnp.asarray(
-                rng.standard_normal((n_nodes, n_feat, nbt, 2)), jnp.float32
-            )
-            node_gh = hist[:, 0, :, :].sum(axis=1)
-            split_fn = jax.jit(
-                lambda h, ng, _p=self.cfg.split: find_splits(h, ng, _p)
-            )
-            c, e = fenced(split_fn, hist, node_gh)
-            split_c, split_e = split_c + c, split_e + e
-
-            pos_lvl = jnp.asarray(
-                rng.randint(0, n_nodes, size=(m,)), jnp.int32
-            )
-            sbin = jnp.asarray(
-                rng.randint(0, max_bin - 1, size=(n_nodes,)), jnp.int32
-            )
-
-            def part_fn(b, p, sb):
-                bv = b[:, 0].astype(jnp.int32)
-                go_right = route_right_binned(
-                    bv, sb[p], jnp.zeros_like(sb, bool)[p], None, max_bin
-                )
-                return p * 2 + go_right.astype(jnp.int32)
-
-            c, e = fenced(jax.jit(part_fn), bins_m, pos_lvl, sbin)
-            part_c, part_e = part_c + c, part_e + e
-        emit("hist", hist_c, hist_e, m, impl=impl)
-        emit("split", split_c, split_e, m)
-        emit("partition", part_c, part_e, m)
-
-        # -- margin: the once-per-tree full-row walk sampled builds pay
-        # (full-row builds fuse the margin update into the build itself)
-        if spec is None:
-            emit("margin", 0.0, 0.0, n_local, fused_into_build=True)
-        else:
-            tree = empty_tree((1 << (depth + 1)) - 1)
-            tree = tree._replace(
-                feature=jnp.asarray(
-                    rng.randint(0, n_feat, tree.feature.shape), jnp.int32
-                ),
-                split_bin=jnp.asarray(
-                    rng.randint(0, max_bin - 1, tree.split_bin.shape),
-                    jnp.int32,
-                ),
-            )
-            walk_fn = jax.jit(
-                lambda t, b: predict_tree_binned(t, b, depth, max_bin)
-            )
-            c, e = fenced(walk_fn, tree, bins)
-            emit("margin", c, e, n_local)
-
-        # -- allreduce: one psum of the deepest built level's histogram over
-        # the real mesh, with the whole round's ring-model payload attached
-        # (measured from the trained program when a round has run)
-        last_level = depth - 1
-        last_nodes = (
-            max(1, (1 << last_level) // 2) if last_level > 0 else 1
-        )
-        arr = jnp.zeros((last_nodes, n_feat, nbt, 2), jnp.float32)
-        ar_fn = jax.jit(
-            jax.shard_map(
-                lambda a: jax.lax.psum(a, AXIS_ACTORS),
-                mesh=self.mesh,
-                in_specs=(P(),),
-                out_specs=P(),
-                check_vma=False,
-            )
-        )
-        c, e = fenced(ar_fn, arr)
-        measured = self.hist_allreduce_bytes_per_round()
-        if measured is None:
-            counter = AllreduceBytes(self.n_devices)
-            for d in range(depth):
-                bn = max(1, (1 << d) // 2) if d > 0 else 1
-                counter.add_allreduce(
-                    np.zeros((bn, n_feat, nbt, 2), np.float32)
-                )
-            measured = counter.total
-        emit("allreduce", c, e, m, bytes_per_round=int(measured))
-
-        total_ms = round(sum(p["execute_ms"] for p in phases.values()), 3)
-        return {
-            "rows_per_shard": int(n_local),
-            "sample_rows": int(m),
-            "phases": phases,
-            "total_execute_ms": total_ms,
-            "config": {
-                "features": int(n_feat),
-                "depth": int(depth),
-                "max_bin": int(max_bin),
-                "impl": impl,
-                "world": int(self.n_devices),
-                "note": (
-                    "standalone jitted phases fenced with block_until_ready; "
-                    "compile-vs-execute separated; phase-share approximation "
-                    "— the compiled round fuses phases"
-                ),
-            },
-        }
 
 
 def shard_layout_fingerprint(shards) -> tuple:

@@ -521,32 +521,7 @@ def _stop_profile_if_running():
         pass
 
 
-def _maybe_profile_phases(engine, state) -> None:
-    """End-of-training fenced phase profiling (``RXGB_TRACE_PHASES=1``):
-    emits sample/hist/split/partition/margin/allreduce spans at the
-    engine's true shard shapes and stashes the table for
-    ``additional_results["obs"]["phase_profile"]``. Runs after the round
-    loop so the standalone phase programs never pollute steady-round
-    timings."""
-    if not obs.phase_profiling_enabled():
-        return
-    tracer = obs.get_tracer()
-    if not tracer.enabled:
-        return
-    profiler = getattr(engine, "profile_phases", None)
-    if profiler is None:
-        return  # gblinear's LinearEngine has no tree phases
-    import jax
-
-    try:
-        state.additional_results["_obs_phase_profile"] = profiler(tracer)
-    except jax.errors.JaxRuntimeError:
-        raise  # a device error is the run's failure, not a diagnostic's
-    except Exception as exc:  # noqa: BLE001 - diagnostics never fail training
-        logger.warning("[RayXGBoost] phase profiling failed: %s", exc)
-
-
-def _assemble_obs(tracer, state) -> Dict:
+def _assemble_obs(tracer) -> Dict:
     """The ``additional_results["obs"]`` payload: full timeline plus the
     derived per-round and event views and the ring-buffer accounting
     (dropped records are surfaced, never silent)."""
@@ -557,17 +532,13 @@ def _assemble_obs(tracer, state) -> Dict:
             row = {"round": rec.get("round"), "dur_s": rec["dur_s"]}
             row.update(rec.get("attrs") or {})
             rounds.append(row)
-    out = {
+    return {
         "timeline": records,
         "rounds": rounds,
         "events": [r for r in records if r.get("kind") == "event"],
         "dropped_spans": tracer.dropped,
         "capacity": tracer.capacity,
     }
-    profile = state.additional_results.pop("_obs_phase_profile", None)
-    if profile is not None:
-        out["phase_profile"] = profile
-    return out
 
 
 class _FauxDMatrix:
@@ -698,6 +669,8 @@ def _train(
 
     state = _training_state
     num_actors = ray_params.num_actors
+    tracer = obs.get_tracer()
+    obs.watch_compiles()  # every compile of the process, on the timeline
 
     # 1) create (or re-create) missing actors (mirror main.py:1129-1149)
     newly_created = 0
@@ -738,17 +711,18 @@ def _train(
 
     # 3) data loading on every alive actor (mirror _PrepareActorTask)
     load_errors = []
-    for actor in state.actors:
-        if actor is None:
-            continue
-        try:
-            actor.load_data(dtrain)
-            for deval, _ in evals:
-                actor.load_data(deval)
-        except (RayActorError, RayTaskError):
-            raise
-        except Exception as exc:  # noqa: BLE001 - surfaced as task error
-            load_errors.append((actor.rank, exc))
+    with tracer.span("data.load", matrices=1 + len(evals)):
+        for actor in state.actors:
+            if actor is None:
+                continue
+            try:
+                actor.load_data(dtrain)
+                for deval, _ in evals:
+                    actor.load_data(deval)
+            except (RayActorError, RayTaskError):
+                raise
+            except Exception as exc:  # noqa: BLE001 - surfaced as task error
+                load_errors.append((actor.rank, exc))
     if load_errors:
         err = RayTaskError(f"Data loading failed on ranks {load_errors}")
         err.ranks = [rank for rank, _ in load_errors]
@@ -833,34 +807,37 @@ def _train(
                     "[RayXGBoost] cached engine for world %s unusable (%s); "
                     "rebuilding.", key, exc,
                 )
-        if parsed.booster == "gblinear":
-            from xgboost_ray_tpu.linear import LinearEngine
+        with tracer.span(
+            "engine.init", booster=parsed.booster, world=len(world_actors)
+        ):
+            if parsed.booster == "gblinear":
+                from xgboost_ray_tpu.linear import LinearEngine
 
-            eng = LinearEngine(
-                train_shards,
-                parsed,
-                num_actors=len(world_actors),
-                evals=evals_in,
-                devices=trial_devices,
-                init_booster=world_init,
-                feature_names=dtrain.resolved_feature_names,
-                feature_types=dtrain.resolved_feature_types,
-            )
-        else:
-            eng = TpuEngine(
-                train_shards,
-                parsed,
-                num_actors=len(world_actors),
-                evals=evals_in,
-                devices=trial_devices,
-                init_booster=world_init,
-                feature_names=dtrain.resolved_feature_names,
-                total_rounds=boost_rounds_left,
-                feature_weights=dtrain.feature_weights,
-                feature_types=dtrain.resolved_feature_types,
-                categories=train_cats,
-                stream_donor=donor,
-            )
+                eng = LinearEngine(
+                    train_shards,
+                    parsed,
+                    num_actors=len(world_actors),
+                    evals=evals_in,
+                    devices=trial_devices,
+                    init_booster=world_init,
+                    feature_names=dtrain.resolved_feature_names,
+                    feature_types=dtrain.resolved_feature_types,
+                )
+            else:
+                eng = TpuEngine(
+                    train_shards,
+                    parsed,
+                    num_actors=len(world_actors),
+                    evals=evals_in,
+                    devices=trial_devices,
+                    init_booster=world_init,
+                    feature_names=dtrain.resolved_feature_names,
+                    total_rounds=boost_rounds_left,
+                    feature_weights=dtrain.feature_weights,
+                    feature_types=dtrain.resolved_feature_types,
+                    categories=train_cats,
+                    stream_donor=donor,
+                )
         eng._world_key = key
         eng._shard_fingerprint = fp
         return eng
@@ -940,6 +917,22 @@ def _train(
         for actor in state.actors:
             if actor is not None:
                 actor._distributed_callbacks.after_round(actor, record)
+
+    def _checkpoint_and_drain(iteration, save):
+        """Drain the callback queue; on a checkpoint boundary (``save``)
+        first read the booster back, serialise it and queue it. The
+        ``driver.checkpoint`` span is the stall a save costs the loop; the
+        ``checkpoint.commit`` event lands inside it."""
+        if not save:
+            _handle_queue(state.queue, state.checkpoint, callback_returns)
+            return
+        with tracer.span("driver.checkpoint", round=iteration):
+            state.queue.put(
+                (0, _Checkpoint(
+                    iteration, _serialize_booster(engine.get_booster())
+                ))
+            )
+            _handle_queue(state.queue, state.checkpoint, callback_returns)
 
     def _schedule_replacements(force=False):
         if ENV.ELASTIC_RESTART_DISABLED:
@@ -1354,35 +1347,34 @@ def _train(
             round_times.extend([chunk_wall / n] * n)
             state.rounds_this_attempt += n
             _mark_recovered(state)
-            for ri, round_metrics in enumerate(chunk_results):
-                for set_name, metrics in round_metrics.items():
-                    for metric_name, value in metrics.items():
-                        evals_result.setdefault(set_name, {}).setdefault(
-                            metric_name, []
-                        ).append(value)
-                # same per-round interval semantics as the per-round path
-                i = completed + ri
-                _fire_after_round(i, round_metrics, round_times[-1])
-                if verbose_eval and (
-                    verbose_eval is True or (i % max(int(verbose_eval), 1) == 0)
-                ):
-                    flat = "\t".join(
-                        f"{sn}-{mn}:{ms[mn]:.5f}"
-                        for sn, ms in round_metrics.items()
-                        for mn in ms
-                    )
-                    print(f"[{i}]\t{flat}")
+            with tracer.span("driver.callbacks", rounds=n):
+                for ri, round_metrics in enumerate(chunk_results):
+                    for set_name, metrics in round_metrics.items():
+                        for metric_name, value in metrics.items():
+                            evals_result.setdefault(set_name, {}).setdefault(
+                                metric_name, []
+                            ).append(value)
+                    # same per-round interval semantics as the per-round path
+                    i = completed + ri
+                    _fire_after_round(i, round_metrics, round_times[-1])
+                    if verbose_eval and (
+                        verbose_eval is True
+                        or (i % max(int(verbose_eval), 1) == 0)
+                    ):
+                        flat = "\t".join(
+                            f"{sn}-{mn}:{ms[mn]:.5f}"
+                            for sn, ms in round_metrics.items()
+                            for mn in ms
+                        )
+                        print(f"[{i}]\t{flat}")
             completed += n
-            if checkpoint_frequency and (
-                completed % checkpoint_frequency == 0
-                or completed == boost_rounds_left
-            ):
-                booster = engine.get_booster()
-                iteration = attempt_offset0 + completed - 1
-                state.queue.put(
-                    (0, _Checkpoint(iteration, _serialize_booster(booster)))
-                )
-            _handle_queue(state.queue, state.checkpoint, callback_returns)
+            _checkpoint_and_drain(
+                attempt_offset0 + completed - 1,
+                save=checkpoint_frequency and (
+                    completed % checkpoint_frequency == 0
+                    or completed == boost_rounds_left
+                ),
+            )
             if ray_params.elastic_training and not ENV.ELASTIC_RESTART_DISABLED:
                 _schedule_replacements()
                 if elastic_mod._update_scheduled_actor_states(
@@ -1397,7 +1389,6 @@ def _train(
                 )
                 last_status = time.time()
 
-        _maybe_profile_phases(engine, state)
         booster = engine.get_booster()
         for actor in [a for a in state.actors if a is not None]:
             actor._distributed_callbacks.after_train(
@@ -1422,9 +1413,17 @@ def _train(
             raise RayXGBoostTrainingStopped("Training was aborted.")
 
         try:
-            for model_cb in callbacks:
-                if hasattr(model_cb, "before_iteration"):
-                    model_cb.before_iteration(proxy, i, evals_result)
+            # driver.callbacks spans: user and framework code between
+            # dispatches (``hook`` says which), so that a host gap in a
+            # device trace has a name
+            if callbacks:
+                with tracer.span(
+                    "driver.callbacks", round=attempt_offset0 + i,
+                    hook="before_iteration",
+                ):
+                    for model_cb in callbacks:
+                        if hasattr(model_cb, "before_iteration"):
+                            model_cb.before_iteration(proxy, i, evals_result)
 
             faults.fire(
                 "actor.train_round",
@@ -1439,13 +1438,16 @@ def _train(
                 # objective per actor on its shard, ``main.py:745-752``);
                 # label_np/weight_np hold exactly this process's rows.
                 # Single-host: all rows.
-                margins = engine.get_margins_local()
-                preds = margins[:, 0] if engine.n_outputs == 1 else margins
-                faux = _FauxDMatrix(
-                    engine.label_np, engine.weight_np, engine.group_ptr
-                )
-                g, h = obj(preds, faux)
-                gh_custom = (g, h)
+                with tracer.span(
+                    "driver.callbacks", round=attempt_offset0 + i, hook="obj"
+                ):
+                    margins = engine.get_margins_local()
+                    preds = margins[:, 0] if engine.n_outputs == 1 else margins
+                    faux = _FauxDMatrix(
+                        engine.label_np, engine.weight_np, engine.group_ptr
+                    )
+                    g, h = obj(preds, faux)
+                    gh_custom = (g, h)
 
             round_metrics = engine.step(i - engine_base, gh_custom=gh_custom)
             completed += 1
@@ -1455,55 +1457,55 @@ def _train(
             round_times.append(round_wall)
             chunk_times.append({"rounds": 1, "seconds": round(round_wall, 6)})
 
-            # custom metric (feval) computed per process on its local rows,
-            # then combined as a weighted mean across processes (the
-            # reference's per-worker metric averaging). Single-host: one
-            # call over all rows.
-            if feval is not None:
-                for es in engine.evals:
-                    margin = engine.get_margins_local(es)
-                    preds = margin[:, 0] if engine.n_outputs == 1 else margin
-                    faux = _FauxDMatrix(
-                        es.label_np if es.label_np is not None else engine.label_np,
-                        es.weight_np,
-                        es.group_ptr,
-                    )
-                    name, value = feval(preds, faux)
-                    round_metrics.setdefault(es.name, {})[name] = (
-                        engine.combine_host_scalar(value, es, metric=name)
-                    )
-
-            for set_name, metrics in round_metrics.items():
-                for metric_name, value in metrics.items():
-                    evals_result.setdefault(set_name, {}).setdefault(
-                        metric_name, []
-                    ).append(value)
-
-            _fire_after_round(i, round_metrics, round_times[-1])
-
-            if verbose_eval and (
-                verbose_eval is True or (i % max(int(verbose_eval), 1) == 0)
+            with tracer.span(
+                "driver.callbacks", round=attempt_offset0 + i,
+                hook="after_round",
             ):
-                flat = "\t".join(
-                    f"{sn}-{mn}:{v[-1]:.5f}"
-                    for sn, ms in evals_result.items()
-                    for mn, v in ms.items()
-                )
-                print(f"[{i}]\t{flat}")
+                # custom metric (feval) computed per process on its local rows,
+                # then combined as a weighted mean across processes (the
+                # reference's per-worker metric averaging). Single-host: one
+                # call over all rows.
+                if feval is not None:
+                    for es in engine.evals:
+                        margin = engine.get_margins_local(es)
+                        preds = margin[:, 0] if engine.n_outputs == 1 else margin
+                        faux = _FauxDMatrix(
+                            es.label_np if es.label_np is not None else engine.label_np,
+                            es.weight_np,
+                            es.group_ptr,
+                        )
+                        name, value = feval(preds, faux)
+                        round_metrics.setdefault(es.name, {})[name] = (
+                            engine.combine_host_scalar(value, es, metric=name)
+                        )
+
+                for set_name, metrics in round_metrics.items():
+                    for metric_name, value in metrics.items():
+                        evals_result.setdefault(set_name, {}).setdefault(
+                            metric_name, []
+                        ).append(value)
+
+                _fire_after_round(i, round_metrics, round_times[-1])
+
+                if verbose_eval and (
+                    verbose_eval is True or (i % max(int(verbose_eval), 1) == 0)
+                ):
+                    flat = "\t".join(
+                        f"{sn}-{mn}:{v[-1]:.5f}"
+                        for sn, ms in evals_result.items()
+                        for mn, v in ms.items()
+                    )
+                    print(f"[{i}]\t{flat}")
 
             # driver-side checkpointing (mirror of the rank-0 checkpoint
             # callback, main.py:612-626): every k rounds + after the last
             is_last = i == boost_rounds_left - 1
-            if checkpoint_frequency and (
-                (i + 1) % checkpoint_frequency == 0 or is_last
-            ):
-                booster = engine.get_booster()
-                iteration = attempt_offset0 + i
-                state.queue.put(
-                    (0, _Checkpoint(iteration, _serialize_booster(booster)))
-                )
-
-            _handle_queue(state.queue, state.checkpoint, callback_returns)
+            _checkpoint_and_drain(
+                attempt_offset0 + i,
+                save=checkpoint_frequency and (
+                    (i + 1) % checkpoint_frequency == 0 or is_last
+                ),
+            )
 
             # elastic: reintegrate failed ranks at the round boundary —
             # in place (zero replay) for reshardable engines, via the
@@ -1517,25 +1519,29 @@ def _train(
                     _grow_at_boundary()
 
             stop = False
-            for model_cb in callbacks:
-                if hasattr(model_cb, "after_iteration"):
-                    stop = model_cb.after_iteration(proxy, i, evals_result) or stop
+            with tracer.span(
+                "driver.callbacks", round=attempt_offset0 + i,
+                hook="after_iteration",
+            ):
+                for model_cb in callbacks:
+                    if hasattr(model_cb, "after_iteration"):
+                        stop = model_cb.after_iteration(proxy, i, evals_result) or stop
 
-            if es_metric is not None:
-                try:
-                    cur = evals_result[evals[-1][1]][es_metric][-1]
-                except KeyError:
-                    cur = None
-                if cur is not None:
-                    better = (
-                        es_best is None
-                        or (es_maximize and cur > es_best)
-                        or (not es_maximize and cur < es_best)
-                    )
-                    if better:
-                        es_best, es_best_iter = cur, i
-                    elif i - es_best_iter >= early_stopping_rounds:
-                        stop = True
+                if es_metric is not None:
+                    try:
+                        cur = evals_result[evals[-1][1]][es_metric][-1]
+                    except KeyError:
+                        cur = None
+                    if cur is not None:
+                        better = (
+                            es_best is None
+                            or (es_maximize and cur > es_best)
+                            or (not es_maximize and cur < es_best)
+                        )
+                        if better:
+                            es_best, es_best_iter = cur, i
+                        elif i - es_best_iter >= early_stopping_rounds:
+                            stop = True
 
             if time.time() - last_status > ENV.STATUS_FREQUENCY_S:
                 logger.info(
@@ -1557,7 +1563,6 @@ def _train(
             i = engine_base + engine.num_round_trees
             completed = i
 
-    _maybe_profile_phases(engine, state)
     booster = engine.get_booster()
     if es_metric is not None and es_best_iter >= 0:
         # es_best_iter is attempt-local; xgboost reports the *global* boosting
@@ -1693,12 +1698,14 @@ def train(
     ``ray_params``.
 
     Observability: every run is traced by a fresh run-scoped
-    :class:`obs.Tracer` — per-round spans from the engine, lifecycle
-    events (attempts, failures, world shrink/grow, checkpoint commits,
-    backoff) from the driver — and the timeline is returned under
+    :class:`obs.Tracer` — spans at every layer boundary (``data.load``,
+    ``engine.init``, one ``dispatch`` per compiled dispatch with its
+    enqueue / wait halves, ``round`` records and ``compile.*`` children,
+    ``driver.checkpoint`` / ``driver.callbacks`` between dispatches) and
+    lifecycle events (failures, world shrink/grow, checkpoint commits,
+    backoff) — and the timeline is returned under
     ``additional_results["obs"]``. ``RXGB_TRACE=0`` disables tracing,
-    ``RXGB_TRACE_DIR`` streams per-rank JSONL, ``RXGB_TRACE_PHASES=1``
-    adds an end-of-run fenced per-phase profile.
+    ``RXGB_TRACE_DIR`` streams per-rank JSONL.
     """
     tracer = obs.Tracer()
     with obs.use_tracer(tracer):
@@ -1825,9 +1832,10 @@ def _train_impl(
     xgb_model = _coerce_model(kwargs.get("xgb_model"))
 
     # eager central loading on the driver (mirror main.py:1555-1556)
-    dtrain.load_data(ray_params.num_actors)
-    for deval, _ in evals:
-        deval.load_data(ray_params.num_actors)
+    with obs.get_tracer().span("data.load", matrices=1 + len(evals)):
+        dtrain.load_data(ray_params.num_actors)
+        for deval, _ in evals:
+            deval.load_data(ray_params.num_actors)
 
     state = _TrainingState(
         actors=[None] * ray_params.num_actors,
@@ -1923,36 +1931,39 @@ def _train_impl(
                 break
 
         attempt_no += 1
-        attempt_ts, attempt_t0 = time.time(), time.perf_counter()
-
-        def _close_attempt(outcome):
-            run_tracer.add_span(
-                "attempt", attempt_ts, time.perf_counter() - attempt_t0,
-                attrs={"attempt": attempt_no, "outcome": outcome,
-                       "rounds_left": boost_rounds_left},
-            )
-
         try:
-            booster, final_evals_result, stats = _train(
-                params,
-                dtrain,
-                boost_rounds_left,
-                evals=evals,
-                ray_params=ray_params,
-                obj=obj,
-                feval=feval,
-                callbacks=kwargs_callbacks,
-                early_stopping_rounds=early_stopping_rounds,
-                maximize=maximize,
-                verbose_eval=verbose_eval,
-                _training_state=state,
-            )
+            # on the thread's span stack while it runs, so every span of the
+            # attempt names it as parent; the outcome is set as it ends
+            with run_tracer.span(
+                "attempt", attempt=attempt_no, rounds_left=boost_rounds_left,
+                outcome="error",
+            ) as attempt_attrs:
+                try:
+                    booster, final_evals_result, stats = _train(
+                        params,
+                        dtrain,
+                        boost_rounds_left,
+                        evals=evals,
+                        ray_params=ray_params,
+                        obj=obj,
+                        feval=feval,
+                        callbacks=kwargs_callbacks,
+                        early_stopping_rounds=early_stopping_rounds,
+                        maximize=maximize,
+                        verbose_eval=verbose_eval,
+                        _training_state=state,
+                    )
+                    attempt_attrs["outcome"] = "ok"
+                except RayXGBoostActorAvailable:
+                    attempt_attrs["outcome"] = "elastic_restart"
+                    raise
+                except (RayActorError, RayTaskError):
+                    attempt_attrs["outcome"] = "failed"
+                    raise
             total_training_time += stats["training_time_s"]
-            _close_attempt("ok")
             break
         except RayXGBoostActorAvailable as exc:
             _stop_profile_if_running()
-            _close_attempt("elastic_restart")
             # elastic reintegration: free restart (mirror main.py:1661-1673)
             logger.info(f"[RayXGBoost] {exc} Restarting from checkpoint with "
                         f"reintegrated workers.")
@@ -1971,7 +1982,6 @@ def _train_impl(
             continue
         except (RayActorError, RayTaskError) as exc:
             _stop_profile_if_running()
-            _close_attempt("failed")
             if state.training_started_at:
                 total_training_time += time.time() - state.training_started_at
                 state.training_started_at = 0.0
@@ -2054,9 +2064,9 @@ def _train_impl(
     state.additional_results["training_time_s"] = total_training_time
     state.additional_results["total_time_s"] = total_time
     if _run_tracer is not None and _run_tracer.enabled:
-        # the queryable run timeline: per-round spans, lifecycle events,
-        # ring-buffer truncation accounting, optional phase profile
-        state.additional_results["obs"] = _assemble_obs(_run_tracer, state)
+        # the queryable run timeline: spans at every layer boundary,
+        # lifecycle events, ring-buffer truncation accounting
+        state.additional_results["obs"] = _assemble_obs(_run_tracer)
     if additional_results is not None:
         additional_results.update(state.additional_results)
 

@@ -12,13 +12,28 @@ lock-guarded metrics island). This package is the one plane they now share:
   ring buffer (dropped-record accounting, never silent), JSONL export and
   per-rank ``RXGB_TRACE_DIR`` streaming; ``validate_trace_records`` is the
   shared schema checker; ``recovery_time_s`` reconstructs
-  failure→recovery timing from the event timeline.
+  failure→recovery timing from the event timeline. Every record carries
+  ``t0_s`` (``time.perf_counter()`` at its start), the clock a harness
+  window and a profiler session can be dated on; spans also enter a
+  ``jax.profiler.TraceAnnotation`` once jax is imported. ``TRACE_NAMES``
+  is the catalogue of host span / event names, ``DEVICE_SCOPES`` the
+  ``jax.named_scope`` vocabulary of the compiled programs.
+* :mod:`xgboost_ray_tpu.obs.compiles` — ``watch_compiles()``: one
+  ``jax.monitoring`` listener per process that records every trace / lower
+  / backend compile / cache load as a ``compile.*`` span under the span
+  that caused it, and counts them in the registry.
+* :mod:`xgboost_ray_tpu.obs.device` — ``scope_times(trace_dir)``: device
+  seconds per ``DEVICE_SCOPES`` path from a profiler trace
+  (``python -m xgboost_ray_tpu.obs.device <dir>``); imports jax when called.
 
 ``train()`` scopes a fresh tracer per run and returns its timeline under
-``additional_results["obs"]``. Environment knobs: ``RXGB_TRACE`` (0
-disables), ``RXGB_TRACE_CAPACITY`` (ring size), ``RXGB_TRACE_DIR``
-(per-rank JSONL streaming), ``RXGB_TRACE_PHASES=1`` (fenced per-phase
-engine profiling at the end of training).
+``additional_results["obs"]``: one ``attempt`` span, under it ``data.load``,
+``engine.init`` (``data.h2d``, ``data.sketch_bin``), one ``dispatch``
+(``dispatch.enqueue``, ``dispatch.wait``, ``round`` records, ``compile.*``
+on a first call) per compiled dispatch, and ``driver.checkpoint`` /
+``driver.callbacks`` between dispatches. Environment knobs: ``RXGB_TRACE``
+(0 disables), ``RXGB_TRACE_CAPACITY`` (ring size), ``RXGB_TRACE_DIR``
+(per-rank JSONL streaming).
 
 Stdlib-only imports: safe to touch before jax comes up.
 """
@@ -31,7 +46,9 @@ from xgboost_ray_tpu.obs.metrics import (
     MetricsRegistry,
     get_registry,
 )
+from xgboost_ray_tpu.obs.compiles import watch_compiles
 from xgboost_ray_tpu.obs.trace import (
+    DEVICE_SCOPES,
     TRACE_NAMES,
     Tracer,
     get_tracer,
@@ -44,6 +61,7 @@ from xgboost_ray_tpu.obs.trace import (
 __all__ = [
     "BUCKET_BOUNDS_MS",
     "Counter",
+    "DEVICE_SCOPES",
     "Gauge",
     "LatencyHistogram",
     "MetricsRegistry",
@@ -55,12 +73,5 @@ __all__ = [
     "set_default_tracer",
     "use_tracer",
     "validate_trace_records",
+    "watch_compiles",
 ]
-
-
-def phase_profiling_enabled() -> bool:
-    """Whether end-of-training fenced phase profiling is requested
-    (``RXGB_TRACE_PHASES=1``)."""
-    import os
-
-    return os.environ.get("RXGB_TRACE_PHASES", "0") == "1"

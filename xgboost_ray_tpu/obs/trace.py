@@ -5,9 +5,16 @@ dict (the schema the whole repo shares — drivers, tests, CI and ``bench.py``
 all validate against :func:`validate_trace_records`):
 
 ``{"kind": "span" | "event", "name": str, "ts": float (epoch seconds),
+"t0_s": float (``time.perf_counter()`` at the record's start),
 "seq": int (monotonic per tracer), "dur_s": float (spans only),
 "parent": int | None (enclosing span's seq, spans only),
 "round": int (optional — global boosting round), "attrs": dict (optional)}``
+
+``t0_s`` is the process's monotonic clock — the one a harness times its
+window on and the one a ``jax.profiler`` session can be dated on (take
+``perf_counter()`` inside a ``TraceAnnotation`` marker: a span's ``t0_s``
+less the marker's is its place in the device trace). ``ts`` stays for JSONL
+streams of several ranks, whose monotonic clocks share no origin.
 
 Design points:
 
@@ -26,6 +33,11 @@ Design points:
   ``<dir>/trace-rank<k>.jsonl`` (k = the JAX process index when available)
   at emission time — a crash loses at most the last unflushed line, and
   multi-host runs produce one stream per rank.
+* **On the profiler's timeline.** When ``jax`` is already imported,
+  ``span()`` also enters ``jax.profiler.TraceAnnotation(name)``: a profiler
+  trace (``RXGB_PROFILE_DIR``, a harness's own session) then holds the
+  program's spans on its host plane beside the device's operations. With no
+  session running the annotation is a no-op.
 * **Import-light.** Stdlib only: the launcher worker (and ``faults.py``)
   touch this module before any jax import.
 
@@ -38,11 +50,13 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional
 
 __all__ = [
+    "DEVICE_SCOPES",
     "TRACE_NAMES",
     "Tracer",
     "get_tracer",
@@ -60,8 +74,23 @@ _DEFAULT_CAPACITY = 8192
 #: entry are each findings), and the optional ``known_names`` vocabulary
 #: for :func:`validate_trace_records`. Grouped by emitting layer.
 TRACE_NAMES = frozenset({
-    # engine round/phase spans (engine.py; phase spans via profile_phases)
-    "round", "sample", "hist", "split", "partition", "margin", "allreduce",
+    # one compiled dispatch (engine.py step / step_many / step_dart /
+    # step_vmapped): ``dispatch`` with its two halves as children —
+    # ``dispatch.enqueue`` (argument assembly, trace / lower / compile or
+    # cache load on a first call, launch: entry until the jitted call
+    # returns) and ``dispatch.wait`` (the host blocked on the metric
+    # readback) — and one ``round`` record per boosting round inside it
+    "dispatch", "dispatch.enqueue", "dispatch.wait", "round",
+    # every compile of the process (obs/compiles.py, one jax.monitoring
+    # listener), parented to whatever span is open on the compiling thread
+    "compile.trace", "compile.lower", "compile.backend",
+    "compile.cache_load",
+    # one train() call's set-up (main.py, engine.py __init__): matrices ->
+    # host shards, engine construction, and inside it the row uploads
+    # (data.h2d, below) and the sketch + bin program
+    "data.load", "engine.init", "data.sketch_bin",
+    # host time between dispatches (main.py round loops)
+    "driver.checkpoint", "driver.callbacks",
     # streamed ingestion (stream/ingest.py + stream/upload.py): one fenced
     # span per sketch/bin chunk and per H2D transfer, one per cuts merge —
     # a streamed load is reconstructible from the timeline alone
@@ -109,6 +138,19 @@ TRACE_NAMES = frozenset({
     # (asserted by tests/test_serve_pool.py).
     "serve.route", "serve.replica_up", "serve.replica_down", "serve.scale",
     "serve.shadow", "serve.rollback", "serve.promote",
+})
+
+#: The ``jax.named_scope`` vocabulary of the compiled programs (engine.py
+#: ``_round_closures`` / ``_sketch_and_bin``, ops/grow.py, ops/
+#: grow_lossguide.py): trace-time metadata of the HLO, so a profiler trace
+#: names the device's operations by phase whatever XLA numbers its fusions.
+#: A round nests ``tree`` > ``level{d}`` > {``hist``, ``allreduce``,
+#: ``split``, ``partition``}; ``level`` stands for ``level0``, ``level1`` ...
+#: :func:`xgboost_ray_tpu.obs.device.scope_times` reads them back.
+DEVICE_SCOPES = frozenset({
+    "objective", "quantize_gh", "sample", "tree", "level", "hist",
+    "allreduce", "split", "partition", "margin", "eval_walk", "metrics",
+    "sketch", "bin",
 })
 
 
@@ -215,37 +257,49 @@ class Tracer:
             seq = self._next_seq_locked()
         parent = stack[-1] if stack else None
         stack.append(seq)
+        # on the profiler's host plane too, once jax is up (no-op with no
+        # profiler session; this module itself never imports jax)
+        jax = sys.modules.get("jax")
+        note = (
+            jax.profiler.TraceAnnotation(name) if jax is not None
+            else contextlib.nullcontext()
+        )
         ts = time.time()
         t0 = time.perf_counter()
         try:
-            yield attrs
+            with note:
+                yield attrs
         finally:
             dur = time.perf_counter() - t0
             stack.pop()
-            self._finish_span(name, ts, dur, seq, parent, round, attrs)
+            self._finish_span(name, ts, t0, dur, seq, parent, round, attrs)
 
     def add_span(
         self,
         name: str,
         ts: float,
+        t0_s: float,
         dur_s: float,
         round: Optional[int] = None,
         attrs: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """Record an externally-timed span (no nesting bookkeeping)."""
+        """Record an externally-timed span: ``ts`` / ``t0_s`` are its start
+        on the epoch and the ``perf_counter`` clock. Its parent is the span
+        open on this thread, if any (no nesting bookkeeping of its own)."""
         if not self.enabled:
             return
         with self._lock:
             seq = self._next_seq_locked()
         stack = getattr(self._tls, "stack", None)
         parent = stack[-1] if stack else None
-        self._finish_span(name, ts, dur_s, seq, parent, round, attrs)
+        self._finish_span(name, ts, t0_s, dur_s, seq, parent, round, attrs)
 
-    def _finish_span(self, name, ts, dur_s, seq, parent, round, attrs):
+    def _finish_span(self, name, ts, t0_s, dur_s, seq, parent, round, attrs):
         rec: Dict[str, Any] = {
             "kind": "span",
             "name": name,
             "ts": ts,
+            "t0_s": float(t0_s),
             "seq": seq,
             "dur_s": float(dur_s),
             "parent": parent,
@@ -275,6 +329,7 @@ class Tracer:
             "kind": "event",
             "name": name,
             "ts": time.time(),
+            "t0_s": time.perf_counter(),
             "seq": seq,
         }
         if round is not None:
@@ -362,7 +417,9 @@ def use_tracer(tracer: Tracer):
 # schema validation + timeline queries (shared by tests, CI and bench.py)
 # ---------------------------------------------------------------------------
 
-_ALLOWED_KEYS = {"kind", "name", "ts", "seq", "dur_s", "parent", "round", "attrs"}
+_ALLOWED_KEYS = {
+    "kind", "name", "ts", "t0_s", "seq", "dur_s", "parent", "round", "attrs",
+}
 
 
 def validate_trace_records(
@@ -396,8 +453,9 @@ def validate_trace_records(
             problems.append(f"{where}: bad name {name!r}")
         elif name_vocab is not None and name not in name_vocab:
             problems.append(f"{where}: unknown name {name!r}")
-        if not isinstance(rec.get("ts"), (int, float)):
-            problems.append(f"{where}: bad ts {rec.get('ts')!r}")
+        for clock in ("ts", "t0_s"):
+            if not isinstance(rec.get(clock), (int, float)):
+                problems.append(f"{where}: bad {clock} {rec.get(clock)!r}")
         seq = rec.get("seq")
         if not isinstance(seq, int):
             problems.append(f"{where}: bad seq {seq!r}")

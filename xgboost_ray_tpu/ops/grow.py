@@ -266,6 +266,17 @@ def empty_tree(heap_size: int) -> Tree:
     )
 
 
+def _in_scope(name: str, fn):
+    """``fn`` with its operations under ``jax.named_scope(name)`` (one of
+    ``obs.DEVICE_SCOPES``: trace-time metadata, nothing at run time)."""
+
+    def scoped(x):
+        with jax.named_scope(name):
+            return fn(x)
+
+    return scoped
+
+
 def build_tree(
     bins: jnp.ndarray,  # [N, F] int bins (max_bin == missing bucket); may be
     #   a COMPACTED [M, F] row selection (ops/sampling.py) — every shape in
@@ -317,6 +328,8 @@ def build_tree(
     axis (``elect_across_feature_shards``), and the winning feature's bin
     column is owner-broadcast so row routing stays O(rows)."""
     hist_ar = hist_allreduce if hist_allreduce is not None else allreduce
+    allreduce = _in_scope("allreduce", allreduce)
+    hist_ar = _in_scope("allreduce", hist_ar)
     if cfg.grow_policy == "lossguide":
         if depth_limit is not None:
             # lossguide's frontier scan has no per-level structure to mask;
@@ -439,325 +452,330 @@ def build_tree(
 
     prev_hist = None
     for d in range(cfg.max_depth):
-        n_nodes = 1 << d
-        base = n_nodes - 1
+        with jax.named_scope(f"level{d}"):
+            n_nodes = 1 << d
+            base = n_nodes - 1
 
-        # Does THIS level's histogram cross the quantization size threshold?
-        # (Mirrors quantized_hist_allreduce's static decision on the built
-        # tensor; != "none" covers the row AND block wire modes.) Sub-
-        # threshold levels take the exact f32 psum, and then node totals
-        # also come from the histogram readout — bit-identical to
-        # hist_quant="none", so small problems are a provable no-op.
-        sib = cfg.sibling_subtract and d > 0
-        build_nodes = (n_nodes // 2) if sib else n_nodes
-        exact_totals = (
-            cfg.hist_quant != "none"
-            and build_nodes * num_features * nbt * 2 * 4
-            >= cfg.hist_quant_min_bytes
-        )
-
-        node_gh_exact = counts_live = None
-        if exact_totals:
-            # quantized histogram wire: node totals must stay full-precision
-            # (they become leaf weights -g/(h+lambda)), and the sibling-
-            # subtraction child choice needs exact live-row counts. ONE
-            # packed [n_nodes, 3] psum carries both — a single extra small
-            # collective per level regardless of mode. Under quantized gh
-            # the whole packed payload rides int32 (sums AND counts), so the
-            # side-psum is an exact integer reduction dequantized once
-            # (deq is the identity on the f32 path).
-            cdt = jnp.int32 if quant else jnp.float32
-            gh_live = jnp.where(done[:, None], gh_zero, gh)
-            packed = allreduce(
-                jnp.concatenate(
-                    [
-                        node_sums(gh_live, pos, n_nodes),
-                        jnp.zeros((n_nodes, 1), cdt)
-                        .at[pos, 0]
-                        .add((~done).astype(cdt)),
-                    ],
-                    axis=1,
-                )
-            )
-            node_gh_exact = deq(packed[:, :2])
-            counts_live = packed[:, 2]
-
-        def _build(gh_b, pos_b, order_b, counts_b, nn, rows_sel=None):
-            """One histogram build over nn node slots via the provider.
-
-            ``rows_sel`` is a compacted row-id view into the FULL bins/gh
-            (sentinel n for unused slots). Presorted providers consume it
-            directly as the row order — the padded-block gather is then the
-            only copy; gather-based providers materialize the selection
-            first (``ops.provider._gather_rows``).
-
-            The missing bucket is reconstructed by subtraction (node_total -
-            sum of regular bins), so with hist_precision="fast" the bf16
-            rounding residue of the regular bins lands there; for features
-            with NO missing values (known globally from the binned matrix)
-            the bucket is exactly zero, so it is zeroed to keep phantom
-            missing mass from steering the learned default direction.
-            """
-            return zero_phantom_missing(
-                provider.build(
-                    bins, gh_b, pos_b, nn, nbt,
-                    order=order_b, counts=counts_b, rows_sel=rows_sel,
-                ),
-                fhm_local,
+            # Does THIS level's histogram cross the quantization size threshold?
+            # (Mirrors quantized_hist_allreduce's static decision on the built
+            # tensor; != "none" covers the row AND block wire modes.) Sub-
+            # threshold levels take the exact f32 psum, and then node totals
+            # also come from the histogram readout — bit-identical to
+            # hist_quant="none", so small problems are a provable no-op.
+            sib = cfg.sibling_subtract and d > 0
+            build_nodes = (n_nodes // 2) if sib else n_nodes
+            exact_totals = (
+                cfg.hist_quant != "none"
+                and build_nodes * num_features * nbt * 2 * 4
+                >= cfg.hist_quant_min_bytes
             )
 
-        if cfg.sibling_subtract and d > 0 and prev_hist is not None:
-            # Sibling subtraction: per parent, build only the globally-smaller
-            # child's histogram (indexed by parent -> half the tensor and half
-            # the one-hot width) and derive the sibling as parent - child.
-            # The choice must be identical on every shard, so it is made from
-            # allreduced per-child row counts.
-            n_par = n_nodes // 2
-            child_counts = (
-                counts_live
-                if counts_live is not None
-                else allreduce(
-                    jnp.zeros((n_nodes,), jnp.float32).at[pos].add(
-                        (~done).astype(jnp.float32)
+            node_gh_exact = counts_live = None
+            if exact_totals:
+                # quantized histogram wire: node totals must stay full-precision
+                # (they become leaf weights -g/(h+lambda)), and the sibling-
+                # subtraction child choice needs exact live-row counts. ONE
+                # packed [n_nodes, 3] psum carries both — a single extra small
+                # collective per level regardless of mode. Under quantized gh
+                # the whole packed payload rides int32 (sums AND counts), so the
+                # side-psum is an exact integer reduction dequantized once
+                # (deq is the identity on the f32 path).
+                cdt = jnp.int32 if quant else jnp.float32
+                gh_live = jnp.where(done[:, None], gh_zero, gh)
+                packed = allreduce(
+                    jnp.concatenate(
+                        [
+                            node_sums(gh_live, pos, n_nodes),
+                            jnp.zeros((n_nodes, 1), cdt)
+                            .at[pos, 0]
+                            .add((~done).astype(cdt)),
+                        ],
+                        axis=1,
                     )
                 )
-            )
-            # [n_par] True when the right child is the (weakly) smaller one
-            small_is_right = child_counts[1::2] <= child_counts[0::2]
-            if track_order:
-                # compact the smaller child's rows into an [N // 2] buffer so
-                # every impl processes HALF the rows (vs just zeroing gh).
-                # The child choice is GLOBAL (allreduced counts), so on a
-                # skewed shard the chosen children's LOCAL rows can exceed
-                # N // 2 — lax.cond falls back to the gh-zeroed full-row
-                # build there (shard-local control flow; the psum sits
-                # outside and runs on every shard either way).
-                rows, par_of_slot, _valid_sel, counts_sel = (
-                    select_small_child_rows(order, counts, small_is_right)
-                )
+                node_gh_exact = deq(packed[:, :2])
+                counts_live = packed[:, 2]
 
-                def _compacted(_):
-                    # done rows only live under inactive parents (they always
-                    # route left below their leaf), so the active nodes this
-                    # histogram feeds never see them — no done-mask needed;
-                    # sentinel slots zero out via the layouts' appended row.
-                    return _build(gh, par_of_slot, None, counts_sel, n_par,
-                                  rows_sel=rows)
+            def _build(gh_b, pos_b, order_b, counts_b, nn, rows_sel=None):
+                """One histogram build over nn node slots via the provider.
 
-                def _zeroed(_):
+                ``rows_sel`` is a compacted row-id view into the FULL bins/gh
+                (sentinel n for unused slots). Presorted providers consume it
+                directly as the row order — the padded-block gather is then the
+                only copy; gather-based providers materialize the selection
+                first (``ops.provider._gather_rows``).
+
+                The missing bucket is reconstructed by subtraction (node_total -
+                sum of regular bins), so with hist_precision="fast" the bf16
+                rounding residue of the regular bins lands there; for features
+                with NO missing values (known globally from the binned matrix)
+                the bucket is exactly zero, so it is zeroed to keep phantom
+                missing mass from steering the learned default direction.
+                """
+                with jax.named_scope("hist"):
+                    return zero_phantom_missing(
+                        provider.build(
+                            bins, gh_b, pos_b, nn, nbt,
+                            order=order_b, counts=counts_b, rows_sel=rows_sel,
+                        ),
+                        fhm_local,
+                    )
+
+            if cfg.sibling_subtract and d > 0 and prev_hist is not None:
+                # Sibling subtraction: per parent, build only the globally-smaller
+                # child's histogram (indexed by parent -> half the tensor and half
+                # the one-hot width) and derive the sibling as parent - child.
+                # The choice must be identical on every shard, so it is made from
+                # allreduced per-child row counts.
+                n_par = n_nodes // 2
+                if counts_live is not None:
+                    child_counts = counts_live
+                else:
+                    with jax.named_scope("hist"):
+                        live_rows = jnp.zeros((n_nodes,), jnp.float32).at[
+                            pos
+                        ].add((~done).astype(jnp.float32))
+                    child_counts = allreduce(live_rows)
+                # [n_par] True when the right child is the (weakly) smaller one
+                small_is_right = child_counts[1::2] <= child_counts[0::2]
+                if track_order:
+                    # compact the smaller child's rows into an [N // 2] buffer so
+                    # every impl processes HALF the rows (vs just zeroing gh).
+                    # The child choice is GLOBAL (allreduced counts), so on a
+                    # skewed shard the chosen children's LOCAL rows can exceed
+                    # N // 2 — lax.cond falls back to the gh-zeroed full-row
+                    # build there (shard-local control flow; the psum sits
+                    # outside and runs on every shard either way).
+                    with jax.named_scope("hist"):
+                        rows, par_of_slot, _valid_sel, counts_sel = (
+                            select_small_child_rows(order, counts, small_is_right)
+                        )
+
+                    def _compacted(_):
+                        # done rows only live under inactive parents (they always
+                        # route left below their leaf), so the active nodes this
+                        # histogram feeds never see them — no done-mask needed;
+                        # sentinel slots zero out via the layouts' appended row.
+                        return _build(gh, par_of_slot, None, counts_sel, n_par,
+                                      rows_sel=rows)
+
+                    def _zeroed(_):
+                        parent_pos = pos >> 1
+                        is_right = (pos & 1).astype(bool)
+                        sel = (is_right == small_is_right[parent_pos]) & ~done
+                        gh_sel = gh * sel[:, None].astype(gh.dtype)
+                        counts_par = counts.reshape(-1, 2).sum(axis=1)
+                        return _build(gh_sel, parent_pos, order, counts_par, n_par)
+
+                    if cfg.shards_may_skew:
+                        fits = counts_sel.sum() <= rows.shape[0]
+                        hist_small = hist_ar(
+                            jax.lax.cond(fits, _compacted, _zeroed, None)
+                        )
+                    else:
+                        hist_small = hist_ar(_compacted(None))
+                else:
                     parent_pos = pos >> 1
                     is_right = (pos & 1).astype(bool)
                     sel = (is_right == small_is_right[parent_pos]) & ~done
                     gh_sel = gh * sel[:, None].astype(gh.dtype)
-                    counts_par = counts.reshape(-1, 2).sum(axis=1)
-                    return _build(gh_sel, parent_pos, order, counts_par, n_par)
-
-                if cfg.shards_may_skew:
-                    fits = counts_sel.sum() <= rows.shape[0]
                     hist_small = hist_ar(
-                        jax.lax.cond(fits, _compacted, _zeroed, None)
+                        _build(gh_sel, parent_pos, None, None, n_par)
+                    )
+                with jax.named_scope("hist"):
+                    hist_big = prev_hist - hist_small
+                    sir = small_is_right[:, None, None, None]
+                    left = jnp.where(sir, hist_big, hist_small)
+                    right = jnp.where(sir, hist_small, hist_big)
+                    hist = jnp.stack([left, right], axis=1).reshape(
+                        (n_nodes,) + hist_small.shape[1:]
+                    )
+            else:
+                hist = hist_ar(_build(gh, pos, order, counts, n_nodes))
+            prev_hist = hist
+            # [n_nodes, 2]: feature 0's buckets cover every row. Under
+            # hist_precision="fast" these totals carry the regular bins' bf16
+            # rounding (when feature 0 has no missing values its zeroed missing
+            # bucket no longer re-balances the sum) — accepted as part of the
+            # fast-precision accuracy/speed contract; use the default precision
+            # when exact node totals matter.
+            # under a quantized wire the histogram's feature-0 totals carry the
+            # quantization rounding, which would land straight in the leaf
+            # weights -g/(h+lambda); the packed exact psum above keeps node
+            # totals full-precision while only the split *search* sees
+            # quantized bin sums
+            if exact_totals:
+                node_gh = node_gh_exact
+            else:
+                node_gh = hist[:, 0, :, :].sum(axis=1)
+                if fshard is not None:
+                    # each shard's column-0 readout sums a DIFFERENT feature's
+                    # buckets (same value up to f32 rounding); leaf weights must
+                    # be identical on every chip, so global feature 0's owner —
+                    # the column the (R, 1) program reads — wins
+                    node_gh = fshard.bcast_from_shard0(node_gh)
+                # quantized gh + exact int32 wire: the readout sums are exact
+                # integer node totals — dequantize at the same boundary the
+                # packed side-psum uses, so both totals paths agree bitwise
+                node_gh = deq(node_gh)
+            with jax.named_scope("split"):
+                # the split search consumes real-valued bin sums: dequantize the
+                # merged histogram ONCE per level (identity on the f32 path);
+                # prev_hist stays in the quantized domain for sibling subtraction
+                hist_sv = deq(hist)
+
+                fmask = fmask_tree
+                if colsample_bylevel < 1.0 and level_rng is not None:
+                    k = jax.random.fold_in(jax.random.fold_in(level_rng, SALT_BYLEVEL), d)
+                    lmask = sample_feature_mask(
+                        k, num_features, colsample_bylevel, feature_log_weights
+                    )
+                    fmask = lmask if fmask is None else (fmask & lmask)
+                if colsample_bynode < 1.0 and level_rng is not None:
+                    k = jax.random.fold_in(jax.random.fold_in(level_rng, SALT_BYNODE), d)
+                    nmask = sample_feature_mask(
+                        k, num_features, colsample_bynode, feature_log_weights,
+                        batch=n_nodes,
+                    )
+                    fmask = nmask if fmask is None else (nmask & fmask[None, :])
+
+                if ic_on:
+                    # allowed = union of still-active groups + the path's features;
+                    # a node that has not split yet (root) may use any feature
+                    union_active = jnp.any(
+                        ic_active[:, :, None] & ic_membership[None, :, :], axis=1
+                    )  # [n_nodes, F]
+                    allowed = jnp.where(
+                        ic_has_used[:, None], union_active | ic_used, True
+                    )
+                    if fmask is None:
+                        fmask = allowed
+                    else:
+                        fmask = (fmask[None, :] if fmask.ndim == 1 else fmask) & allowed
+
+                sp = find_splits(hist_sv, node_gh, cfg.split, feature_mask=fmask,
+                                 cat_mask=cat_mask_local, monotone=mono_arr,
+                                 node_lower=lower, node_upper=upper)
+                if fshard is not None:
+                    # the per-shard winner covers only this chip's feature slice;
+                    # one tiny per-node record gather over the feature axis elects
+                    # the global split (first-max tie-break — bitwise the (R, 1)
+                    # argmax)
+                    sp = elect_across_feature_shards(
+                        sp, fshard.offset(num_features), cfg.max_bin, cfg.split,
+                        fshard.axis, counter=fshard.counter,
+                    )
+                valid_split = sp.valid & active
+                if depth_limit is not None:
+                    # per-lane depth ceiling: a lane whose limit is this level keeps
+                    # its active nodes but may not split them — they fall through to
+                    # is_new_leaf below with node values from the histogram readout
+                    # (vs the final-level exact psum, so a depth-masked lane matches
+                    # its sequential twin to f32 rounding, bitwise only when its
+                    # limit equals cfg.max_depth and this mask is never engaged)
+                    valid_split = valid_split & (d < depth_limit)
+                if mono_on:
+                    node_value = lr * bounded_weight(
+                        node_gh[:, 0], node_gh[:, 1], cfg.split, lower, upper
                     )
                 else:
-                    hist_small = hist_ar(_compacted(None))
-            else:
-                parent_pos = pos >> 1
-                is_right = (pos & 1).astype(bool)
-                sel = (is_right == small_is_right[parent_pos]) & ~done
-                gh_sel = gh * sel[:, None].astype(gh.dtype)
-                hist_small = hist_ar(
-                    _build(gh_sel, parent_pos, None, None, n_par)
+                    node_value = lr * leaf_weight(
+                        node_gh[:, 0], node_gh[:, 1], cfg.split
+                    )
+                is_new_leaf = active & ~valid_split
+
+                fsafe = jnp.clip(sp.feature, 0, f_global_max)
+                thr = cuts[fsafe, jnp.clip(sp.split_bin, 0, cfg.max_bin - 2)]
+                sl = slice(base, base + n_nodes)
+                tree = tree._replace(
+                    feature=tree.feature.at[sl].set(jnp.where(valid_split, sp.feature, -1)),
+                    split_bin=tree.split_bin.at[sl].set(jnp.where(valid_split, sp.split_bin, 0)),
+                    threshold=tree.threshold.at[sl].set(jnp.where(valid_split, thr, 0.0)),
+                    default_left=tree.default_left.at[sl].set(sp.default_left & valid_split),
+                    is_leaf=tree.is_leaf.at[sl].set(is_new_leaf),
+                    value=tree.value.at[sl].set(jnp.where(is_new_leaf, node_value, 0.0)),
+                    gain=tree.gain.at[sl].set(jnp.where(valid_split, sp.gain, 0.0)),
+                    cover=tree.cover.at[sl].set(jnp.where(active, node_gh[:, 1], 0.0)),
+                    base_weight=tree.base_weight.at[sl].set(
+                        jnp.where(active, node_value, 0.0)
+                    ),
                 )
-            hist_big = prev_hist - hist_small
-            sir = small_is_right[:, None, None, None]
-            left = jnp.where(sir, hist_big, hist_small)
-            right = jnp.where(sir, hist_small, hist_big)
-            hist = jnp.stack([left, right], axis=1).reshape(
-                (n_nodes,) + hist_small.shape[1:]
-            )
-        else:
-            hist = hist_ar(_build(gh, pos, order, counts, n_nodes))
-        prev_hist = hist
-        # [n_nodes, 2]: feature 0's buckets cover every row. Under
-        # hist_precision="fast" these totals carry the regular bins' bf16
-        # rounding (when feature 0 has no missing values its zeroed missing
-        # bucket no longer re-balances the sum) — accepted as part of the
-        # fast-precision accuracy/speed contract; use the default precision
-        # when exact node totals matter.
-        # under a quantized wire the histogram's feature-0 totals carry the
-        # quantization rounding, which would land straight in the leaf
-        # weights -g/(h+lambda); the packed exact psum above keeps node
-        # totals full-precision while only the split *search* sees
-        # quantized bin sums
-        if exact_totals:
-            node_gh = node_gh_exact
-        else:
-            node_gh = hist[:, 0, :, :].sum(axis=1)
-            if fshard is not None:
-                # each shard's column-0 readout sums a DIFFERENT feature's
-                # buckets (same value up to f32 rounding); leaf weights must
-                # be identical on every chip, so global feature 0's owner —
-                # the column the (R, 1) program reads — wins
-                node_gh = fshard.bcast_from_shard0(node_gh)
-            # quantized gh + exact int32 wire: the readout sums are exact
-            # integer node totals — dequantize at the same boundary the
-            # packed side-psum uses, so both totals paths agree bitwise
-            node_gh = deq(node_gh)
-        # the split search consumes real-valued bin sums: dequantize the
-        # merged histogram ONCE per level (identity on the f32 path);
-        # prev_hist stays in the quantized domain for sibling subtraction
-        hist_sv = deq(hist)
 
-        fmask = fmask_tree
-        if colsample_bylevel < 1.0 and level_rng is not None:
-            k = jax.random.fold_in(jax.random.fold_in(level_rng, SALT_BYLEVEL), d)
-            lmask = sample_feature_mask(
-                k, num_features, colsample_bylevel, feature_log_weights
-            )
-            fmask = lmask if fmask is None else (fmask & lmask)
-        if colsample_bynode < 1.0 and level_rng is not None:
-            k = jax.random.fold_in(jax.random.fold_in(level_rng, SALT_BYNODE), d)
-            nmask = sample_feature_mask(
-                k, num_features, colsample_bynode, feature_log_weights,
-                batch=n_nodes,
-            )
-            fmask = nmask if fmask is None else (nmask & fmask[None, :])
+            with jax.named_scope("partition"):
+                newly_leafed = is_new_leaf[pos] & ~done
+                row_value = jnp.where(newly_leafed, node_value[pos], row_value)
+                done = done | newly_leafed
 
-        if ic_on:
-            # allowed = union of still-active groups + the path's features;
-            # a node that has not split yet (root) may use any feature
-            union_active = jnp.any(
-                ic_active[:, :, None] & ic_membership[None, :, :], axis=1
-            )  # [n_nodes, F]
-            allowed = jnp.where(
-                ic_has_used[:, None], union_active | ic_used, True
-            )
-            if fmask is None:
-                fmask = allowed
-            else:
-                fmask = (fmask[None, :] if fmask.ndim == 1 else fmask) & allowed
-
-        sp = find_splits(hist_sv, node_gh, cfg.split, feature_mask=fmask,
-                         cat_mask=cat_mask_local, monotone=mono_arr,
-                         node_lower=lower, node_upper=upper)
-        if fshard is not None:
-            # the per-shard winner covers only this chip's feature slice;
-            # one tiny per-node record gather over the feature axis elects
-            # the global split (first-max tie-break — bitwise the (R, 1)
-            # argmax)
-            sp = elect_across_feature_shards(
-                sp, fshard.offset(num_features), cfg.max_bin, cfg.split,
-                fshard.axis, counter=fshard.counter,
-            )
-        valid_split = sp.valid & active
-        if depth_limit is not None:
-            # per-lane depth ceiling: a lane whose limit is this level keeps
-            # its active nodes but may not split them — they fall through to
-            # is_new_leaf below with node values from the histogram readout
-            # (vs the final-level exact psum, so a depth-masked lane matches
-            # its sequential twin to f32 rounding, bitwise only when its
-            # limit equals cfg.max_depth and this mask is never engaged)
-            valid_split = valid_split & (d < depth_limit)
-        if mono_on:
-            node_value = lr * bounded_weight(
-                node_gh[:, 0], node_gh[:, 1], cfg.split, lower, upper
-            )
-        else:
-            node_value = lr * leaf_weight(
-                node_gh[:, 0], node_gh[:, 1], cfg.split
-            )
-        is_new_leaf = active & ~valid_split
-
-        fsafe = jnp.clip(sp.feature, 0, f_global_max)
-        thr = cuts[fsafe, jnp.clip(sp.split_bin, 0, cfg.max_bin - 2)]
-        sl = slice(base, base + n_nodes)
-        tree = tree._replace(
-            feature=tree.feature.at[sl].set(jnp.where(valid_split, sp.feature, -1)),
-            split_bin=tree.split_bin.at[sl].set(jnp.where(valid_split, sp.split_bin, 0)),
-            threshold=tree.threshold.at[sl].set(jnp.where(valid_split, thr, 0.0)),
-            default_left=tree.default_left.at[sl].set(sp.default_left & valid_split),
-            is_leaf=tree.is_leaf.at[sl].set(is_new_leaf),
-            value=tree.value.at[sl].set(jnp.where(is_new_leaf, node_value, 0.0)),
-            gain=tree.gain.at[sl].set(jnp.where(valid_split, sp.gain, 0.0)),
-            cover=tree.cover.at[sl].set(jnp.where(active, node_gh[:, 1], 0.0)),
-            base_weight=tree.base_weight.at[sl].set(
-                jnp.where(active, node_value, 0.0)
-            ),
-        )
-
-        newly_leafed = is_new_leaf[pos] & ~done
-        row_value = jnp.where(newly_leafed, node_value[pos], row_value)
-        done = done | newly_leafed
-
-        f_of_row = fsafe[pos]
-        if fshard is None:
-            b = jnp.take_along_axis(
-                bins.astype(jnp.int32), f_of_row[:, None], axis=1
-            )[:, 0]
-        else:
-            # winning feature's bin column, owner-broadcast over the
-            # feature axis: one [N] collective — O(rows), not O(rows x F)
-            b = fshard.bin_column(bins, f_of_row)
-        go_right = route_right_binned(
-            b, sp.split_bin[pos], sp.default_left[pos],
-            None if cat_mask is None else cat_mask[f_of_row], missing_bin,
-        )
-        effective_right = jnp.where(done, False, go_right)
-        pos = pos * 2 + effective_right.astype(jnp.int32)
-        active = jnp.repeat(valid_split, 2)
-        if track_order:
-            order, counts = update_partition_order(order, counts, effective_right)
-
-        if mono_on:
-            # Recompute the CHOSEN split's child weights (same clamped
-            # formula find_splits scored with) to narrow the children's
-            # feasible interval at the midpoint — xgboost's monotone bound
-            # propagation. O(n_nodes * bins), negligible next to the build.
-            hist_f = jnp.take_along_axis(
-                hist_sv, fsafe[:, None, None, None], axis=1
-            )[:, 0]  # [n_nodes, nbt, 2]
-            gf, hf = hist_f[..., 0], hist_f[..., 1]
-            sbin_c = jnp.clip(sp.split_bin, 0, cfg.max_bin - 2)[:, None]
-            gl_c = jnp.take_along_axis(
-                jnp.cumsum(gf[:, : cfg.max_bin], axis=-1), sbin_c, axis=1
-            )[:, 0]
-            hl_c = jnp.take_along_axis(
-                jnp.cumsum(hf[:, : cfg.max_bin], axis=-1), sbin_c, axis=1
-            )[:, 0]
-            if cat_mask is not None:
-                is_cat = cat_mask[fsafe]
-                gl_c = jnp.where(
-                    is_cat, jnp.take_along_axis(gf, sbin_c, axis=1)[:, 0], gl_c
+                f_of_row = fsafe[pos]
+                if fshard is None:
+                    b = jnp.take_along_axis(
+                        bins.astype(jnp.int32), f_of_row[:, None], axis=1
+                    )[:, 0]
+                else:
+                    # winning feature's bin column, owner-broadcast over the
+                    # feature axis: one [N] collective — O(rows), not O(rows x F)
+                    b = fshard.bin_column(bins, f_of_row)
+                go_right = route_right_binned(
+                    b, sp.split_bin[pos], sp.default_left[pos],
+                    None if cat_mask is None else cat_mask[f_of_row], missing_bin,
                 )
-                hl_c = jnp.where(
-                    is_cat, jnp.take_along_axis(hf, sbin_c, axis=1)[:, 0], hl_c
-                )
-            gl_c = jnp.where(sp.default_left, gl_c + gf[:, cfg.max_bin], gl_c)
-            hl_c = jnp.where(sp.default_left, hl_c + hf[:, cfg.max_bin], hl_c)
-            wl = bounded_weight(gl_c, hl_c, cfg.split, lower, upper)
-            wr = bounded_weight(
-                node_gh[:, 0] - gl_c, node_gh[:, 1] - hl_c, cfg.split,
-                lower, upper,
-            )
-            mid = 0.5 * (wl + wr)
-            c = jnp.where(valid_split, mono_arr[fsafe], 0.0)
-            lower_l = jnp.where(c < 0, jnp.maximum(lower, mid), lower)
-            upper_l = jnp.where(c > 0, jnp.minimum(upper, mid), upper)
-            lower_r = jnp.where(c > 0, jnp.maximum(lower, mid), lower)
-            upper_r = jnp.where(c < 0, jnp.minimum(upper, mid), upper)
-            lower = jnp.stack([lower_l, lower_r], axis=1).reshape(-1)
-            upper = jnp.stack([upper_l, upper_r], axis=1).reshape(-1)
+                effective_right = jnp.where(done, False, go_right)
+                pos = pos * 2 + effective_right.astype(jnp.int32)
+                active = jnp.repeat(valid_split, 2)
+                if track_order:
+                    order, counts = update_partition_order(order, counts, effective_right)
 
-        if ic_on:
-            contains_f = ic_membership.T[fsafe]  # [n_nodes, S]
-            ic_active = jnp.where(
-                valid_split[:, None], ic_active & contains_f, ic_active
-            )
-            f_onehot = jnp.arange(num_features)[None, :] == fsafe[:, None]
-            ic_used = ic_used | (valid_split[:, None] & f_onehot)
-            ic_has_used = ic_has_used | valid_split
-            ic_active = jnp.repeat(ic_active, 2, axis=0)
-            ic_used = jnp.repeat(ic_used, 2, axis=0)
-            ic_has_used = jnp.repeat(ic_has_used, 2)
+            if mono_on:
+                # Recompute the CHOSEN split's child weights (same clamped
+                # formula find_splits scored with) to narrow the children's
+                # feasible interval at the midpoint — xgboost's monotone bound
+                # propagation. O(n_nodes * bins), negligible next to the build.
+                hist_f = jnp.take_along_axis(
+                    hist_sv, fsafe[:, None, None, None], axis=1
+                )[:, 0]  # [n_nodes, nbt, 2]
+                gf, hf = hist_f[..., 0], hist_f[..., 1]
+                sbin_c = jnp.clip(sp.split_bin, 0, cfg.max_bin - 2)[:, None]
+                gl_c = jnp.take_along_axis(
+                    jnp.cumsum(gf[:, : cfg.max_bin], axis=-1), sbin_c, axis=1
+                )[:, 0]
+                hl_c = jnp.take_along_axis(
+                    jnp.cumsum(hf[:, : cfg.max_bin], axis=-1), sbin_c, axis=1
+                )[:, 0]
+                if cat_mask is not None:
+                    is_cat = cat_mask[fsafe]
+                    gl_c = jnp.where(
+                        is_cat, jnp.take_along_axis(gf, sbin_c, axis=1)[:, 0], gl_c
+                    )
+                    hl_c = jnp.where(
+                        is_cat, jnp.take_along_axis(hf, sbin_c, axis=1)[:, 0], hl_c
+                    )
+                gl_c = jnp.where(sp.default_left, gl_c + gf[:, cfg.max_bin], gl_c)
+                hl_c = jnp.where(sp.default_left, hl_c + hf[:, cfg.max_bin], hl_c)
+                wl = bounded_weight(gl_c, hl_c, cfg.split, lower, upper)
+                wr = bounded_weight(
+                    node_gh[:, 0] - gl_c, node_gh[:, 1] - hl_c, cfg.split,
+                    lower, upper,
+                )
+                mid = 0.5 * (wl + wr)
+                c = jnp.where(valid_split, mono_arr[fsafe], 0.0)
+                lower_l = jnp.where(c < 0, jnp.maximum(lower, mid), lower)
+                upper_l = jnp.where(c > 0, jnp.minimum(upper, mid), upper)
+                lower_r = jnp.where(c > 0, jnp.maximum(lower, mid), lower)
+                upper_r = jnp.where(c < 0, jnp.minimum(upper, mid), upper)
+                lower = jnp.stack([lower_l, lower_r], axis=1).reshape(-1)
+                upper = jnp.stack([upper_l, upper_r], axis=1).reshape(-1)
+
+            if ic_on:
+                contains_f = ic_membership.T[fsafe]  # [n_nodes, S]
+                ic_active = jnp.where(
+                    valid_split[:, None], ic_active & contains_f, ic_active
+                )
+                f_onehot = jnp.arange(num_features)[None, :] == fsafe[:, None]
+                ic_used = ic_used | (valid_split[:, None] & f_onehot)
+                ic_has_used = ic_has_used | valid_split
+                ic_active = jnp.repeat(ic_active, 2, axis=0)
+                ic_used = jnp.repeat(ic_used, 2, axis=0)
+                ic_has_used = jnp.repeat(ic_has_used, 2)
 
     # Final level: every still-active node is a leaf.
     n_nodes = 1 << cfg.max_depth

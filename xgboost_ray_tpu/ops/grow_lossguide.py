@@ -118,10 +118,11 @@ def build_tree_lossguide(
         # Always the one-hot MXU pass: the per-step 2-node fan-out is the
         # regime where every provider would pick it anyway (params.py pins
         # hist_impl to auto|onehot for lossguide).
-        h = hist_onehot(
-            bins, gh_b, pos_b, nn, nbt,
-            chunk=cfg.hist_chunk, precision=cfg.hist_precision,
-        )
+        with jax.named_scope("hist"):
+            h = hist_onehot(
+                bins, gh_b, pos_b, nn, nbt,
+                chunk=cfg.hist_chunk, precision=cfg.hist_precision,
+            )
         return zero_phantom_missing(hist_ar(h), fhm_local)
 
     def _node_gh(hist, gh_b, pos_b, nn):
@@ -152,13 +153,14 @@ def build_tree_lossguide(
     # --- root: evaluate its best split, seed the frontier -------------------
     root_hist = _hist(gh, pos, 1)  # [1, F_local, nbt, 2]
     root_gh = _node_gh(root_hist, gh, pos, 1)  # [1, 2]
-    sp0 = find_splits(deq(root_hist), root_gh, cfg.split,
-                      feature_mask=fmask_local, cat_mask=cat_mask_local)
-    if fshard is not None:
-        sp0 = elect_across_feature_shards(
-            sp0, fshard.offset(num_features), cfg.max_bin, cfg.split,
-            fshard.axis, counter=fshard.counter,
-        )
+    with jax.named_scope("split"):
+        sp0 = find_splits(deq(root_hist), root_gh, cfg.split,
+                          feature_mask=fmask_local, cat_mask=cat_mask_local)
+        if fshard is not None:
+            sp0 = elect_across_feature_shards(
+                sp0, fshard.offset(num_features), cfg.max_bin, cfg.split,
+                fshard.axis, counter=fshard.counter,
+            )
     root_value = lr * leaf_weight(root_gh[:, 0], root_gh[:, 1], cfg.split)[0]
     tree = tree._replace(
         is_leaf=tree.is_leaf.at[0].set(True),
@@ -209,31 +211,36 @@ def build_tree_lossguide(
         )
 
         # route ONLY this leaf's rows
-        sel = (pos == slot) & do_split
-        if fshard is None:
-            bv = jnp.take_along_axis(b32, jnp.full((n, 1), feat), axis=1)[:, 0]
-        else:
-            # split feature is a global index; owner-broadcast its column
-            bv = fshard.bin_column(bins, jnp.full((n,), feat))
-        go_right = route_right_binned(
-            bv, sbin, dl,
-            None if cat_mask is None else cat_mask[feat], missing_bin,
-        )
-        l_slot, r_slot = 2 * slot_c + 1, 2 * slot_c + 2
-        pos = jnp.where(sel, jnp.where(go_right, r_slot, l_slot), pos)
+        with jax.named_scope("partition"):
+            sel = (pos == slot) & do_split
+            if fshard is None:
+                bv = jnp.take_along_axis(
+                    b32, jnp.full((n, 1), feat), axis=1
+                )[:, 0]
+            else:
+                # split feature is a global index; owner-broadcast its column
+                bv = fshard.bin_column(bins, jnp.full((n,), feat))
+            go_right = route_right_binned(
+                bv, sbin, dl,
+                None if cat_mask is None else cat_mask[feat], missing_bin,
+            )
+            l_slot, r_slot = 2 * slot_c + 1, 2 * slot_c + 2
+            pos = jnp.where(sel, jnp.where(go_right, r_slot, l_slot), pos)
 
         # the two children's histograms + best splits
         gh_sel = gh * sel[:, None].astype(gh.dtype)
         pos2 = go_right.astype(jnp.int32)
         hist2 = _hist(gh_sel, pos2, 2)  # [2, F_local, nbt, 2]
         child_gh = _node_gh(hist2, gh_sel, pos2, 2)  # [2, 2]
-        sp2 = find_splits(deq(hist2), child_gh, cfg.split,
-                          feature_mask=fmask_local, cat_mask=cat_mask_local)
-        if fshard is not None:
-            sp2 = elect_across_feature_shards(
-                sp2, fshard.offset(num_features), cfg.max_bin, cfg.split,
-                fshard.axis, counter=fshard.counter,
-            )
+        with jax.named_scope("split"):
+            sp2 = find_splits(deq(hist2), child_gh, cfg.split,
+                              feature_mask=fmask_local,
+                              cat_mask=cat_mask_local)
+            if fshard is not None:
+                sp2 = elect_across_feature_shards(
+                    sp2, fshard.offset(num_features), cfg.max_bin, cfg.split,
+                    fshard.axis, counter=fshard.counter,
+                )
         child_slots = jnp.stack([l_slot, r_slot])
         # children may split further only while their own children fit the
         # depth-bounded heap

@@ -141,7 +141,7 @@ class DoubleBufferedUploader:
                 nbytes = int(getattr(array, "nbytes", 0))
                 if self._tracer is not None:
                     self._tracer.add_span(
-                        "data.h2d", ts, dur,
+                        "data.h2d", ts, t0, dur,
                         attrs={"bytes": nbytes, "device": str(device)},
                     )
                 with self._cond:
