@@ -8,8 +8,11 @@ XLA wants static shapes, so the dynamic frontier of xgboost's tree growth
 becomes a *padded heap*: a tree of max_depth D occupies ``2^(D+1)-1`` node
 slots (root 0, children of i at 2i+1 / 2i+2). At level d all ``2^d`` node
 positions are processed at once; nodes that stopped splitting are masked.
-Rows carry an int32 position vector (their node at the current level) that is
-updated with pure gathers each level — no host round-trips, no sorting.
+Rows carry an int32 position vector (their node at the current level). Each
+level reads its per-node state back per row through dense one-hot forms
+(``lookup_by_node``, ``bin_of_feature``, the chunked node sums) that stream
+the rows once — no per-row gather or scatter keyed by the position, no host
+round-trips, no sorting.
 
 The histogram allreduce point is the ``allreduce`` callable: identity on a
 single device, ``lax.psum(..., "actors")`` inside the shard_map round step —
@@ -36,9 +39,11 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from xgboost_ray_tpu.obs import get_registry
 from xgboost_ray_tpu.ops.histogram import (
+    node_counts_dense,
+    node_sums_dense,
     select_small_child_rows,
-    node_sums,
     update_partition_order,
     zero_phantom_missing,
 )
@@ -73,6 +78,55 @@ def route_right_binned(bin_vals, split_bin, default_left, is_cat, missing_bin):
     if is_cat is not None:
         present_right = jnp.where(is_cat, bin_vals != split_bin, present_right)
     return jnp.where(bin_vals == missing_bin, ~default_left, present_right)
+
+
+def lookup_by_node(pos: jnp.ndarray, *tables: jnp.ndarray) -> list:
+    """``[t[pos] for t in tables]`` for node-sized tables (each ``[n_nodes]``:
+    bool, int32 or float32) without a per-row gather: ``pos`` is compared with
+    the node slots once, each table's words are selected under the hit and one
+    variadic reduce over the slots yields every table's row vector, so the
+    ``[n_nodes, N]`` hit mask lives only inside the fusion. Values travel as
+    int32 bit patterns and exactly one slot hits a row, so the result is
+    bitwise ``t[pos]`` (no float add touches an f32 value). XLA's TPU gather
+    moves one element at a time; at 11M rows on a v5e the five lookups of a
+    level took 2.4 ms at one slot, 22 ms at 128 and 110 ms at 2,048 this way
+    against 170-560 ms as gathers, so every fan-out takes this form. A
+    ``pos`` outside ``[0, n_nodes)`` reads 0 / False."""
+    n_nodes = tables[0].shape[0]
+    hit = jnp.arange(n_nodes, dtype=pos.dtype)[:, None] == pos[None, :]
+    words = tuple(
+        jnp.where(hit, _as_word(t)[:, None], jnp.int32(0)) for t in tables
+    )
+    picked = jax.lax.reduce(
+        words, (jnp.int32(0),) * len(words),
+        lambda a, b: tuple(x + y for x, y in zip(a, b)), (0,),
+    )
+    return [_from_word(w, t.dtype) for w, t in zip(picked, tables)]
+
+
+def _as_word(table: jnp.ndarray) -> jnp.ndarray:
+    if table.dtype == jnp.float32:
+        return jax.lax.bitcast_convert_type(table, jnp.int32)
+    if table.dtype == jnp.bool_ or table.dtype == jnp.int32:
+        return table.astype(jnp.int32)
+    raise TypeError(f"lookup_by_node: unsupported table dtype {table.dtype}")
+
+
+def _from_word(word: jnp.ndarray, dtype) -> jnp.ndarray:
+    if dtype == jnp.float32:
+        return jax.lax.bitcast_convert_type(word, jnp.float32)
+    return word != 0 if dtype == jnp.bool_ else word
+
+
+def bin_of_feature(bins: jnp.ndarray, f_of_row: jnp.ndarray) -> jnp.ndarray:
+    """``bins[i, f_of_row[i]]`` per row as int32: a masked reduce over the
+    feature axis in the storage dtype (uint8 / int16), in place of
+    ``take_along_axis`` on an int32 copy of the whole ``[N, F]`` matrix (an
+    N-element gather: 200 ms against 2 ms at 11M x 28 on a v5e)."""
+    num_features = bins.shape[1]
+    hit = jnp.arange(num_features, dtype=f_of_row.dtype) == f_of_row[..., None]
+    picked = jnp.where(hit, bins, jnp.zeros((), bins.dtype))
+    return picked.sum(axis=-1, dtype=bins.dtype).astype(jnp.int32)
 
 
 def cat_mask_const(cat_features: tuple, num_features: int):
@@ -368,13 +422,8 @@ def build_tree(
         from xgboost_ray_tpu.ops.objectives import dequantize_gh_sums
 
         deq = lambda s: dequantize_gh_sums(s, gh_scale)  # noqa: E731
-        gh_zero = jnp.zeros((), gh.dtype)
     else:
         deq = lambda s: s  # noqa: E731
-        # the bare literal, NOT jnp.zeros((), f32): the float32 path must
-        # keep tracing the exact pre-quantization program (weak-typed
-        # constant and all — the schedule-golden/fingerprint discipline)
-        gh_zero = 0.0
 
     if fshard is None:
         cat_mask = cat_mask_const(cfg.cat_features, num_features)
@@ -480,15 +529,14 @@ def build_tree(
                 # the whole packed payload rides int32 (sums AND counts), so the
                 # side-psum is an exact integer reduction dequantized once
                 # (deq is the identity on the f32 path).
-                cdt = jnp.int32 if quant else jnp.float32
-                gh_live = jnp.where(done[:, None], gh_zero, gh)
+                live_pos = jnp.where(done, -1, pos)
+                sums_live = node_sums_dense(gh, live_pos, n_nodes)
                 packed = allreduce(
                     jnp.concatenate(
                         [
-                            node_sums(gh_live, pos, n_nodes),
-                            jnp.zeros((n_nodes, 1), cdt)
-                            .at[pos, 0]
-                            .add((~done).astype(cdt)),
+                            sums_live,
+                            node_counts_dense(live_pos, n_nodes)[:, None]
+                            .astype(sums_live.dtype),
                         ],
                         axis=1,
                     )
@@ -532,9 +580,9 @@ def build_tree(
                     child_counts = counts_live
                 else:
                     with jax.named_scope("hist"):
-                        live_rows = jnp.zeros((n_nodes,), jnp.float32).at[
-                            pos
-                        ].add((~done).astype(jnp.float32))
+                        live_rows = node_counts_dense(
+                            jnp.where(done, -1, pos), n_nodes
+                        ).astype(jnp.float32)
                     child_counts = allreduce(live_rows)
                 # [n_par] True when the right child is the (weakly) smaller one
                 small_is_right = child_counts[1::2] <= child_counts[0::2]
@@ -562,7 +610,9 @@ def build_tree(
                     def _zeroed(_):
                         parent_pos = pos >> 1
                         is_right = (pos & 1).astype(bool)
-                        sel = (is_right == small_is_right[parent_pos]) & ~done
+                        sel = (
+                            is_right == lookup_by_node(parent_pos, small_is_right)[0]
+                        ) & ~done
                         gh_sel = gh * sel[:, None].astype(gh.dtype)
                         counts_par = counts.reshape(-1, 2).sum(axis=1)
                         return _build(gh_sel, parent_pos, order, counts_par, n_par)
@@ -577,7 +627,9 @@ def build_tree(
                 else:
                     parent_pos = pos >> 1
                     is_right = (pos & 1).astype(bool)
-                    sel = (is_right == small_is_right[parent_pos]) & ~done
+                    sel = (
+                        is_right == lookup_by_node(parent_pos, small_is_right)[0]
+                    ) & ~done
                     gh_sel = gh * sel[:, None].astype(gh.dtype)
                     hist_small = hist_ar(
                         _build(gh_sel, parent_pos, None, None, n_par)
@@ -702,22 +754,30 @@ def build_tree(
                 )
 
             with jax.named_scope("partition"):
-                newly_leafed = is_new_leaf[pos] & ~done
-                row_value = jnp.where(newly_leafed, node_value[pos], row_value)
+                # counted as the level is traced: every fan-out takes the
+                # dense form (lookup_by_node's measurements), so no level
+                # counts under rxgb_route_gather_levels_total
+                get_registry().counter("rxgb_route_dense_levels_total").inc()
+                get_registry().counter("rxgb_route_gather_levels_total")
+                is_cat_node = () if cat_mask is None else (cat_mask[fsafe],)
+                (leaf_of_row, value_of_row, f_of_row, split_bin_of_row,
+                 default_left_of_row, *is_cat_of_row) = lookup_by_node(
+                    pos, is_new_leaf, node_value, fsafe, sp.split_bin,
+                    sp.default_left, *is_cat_node,
+                )
+                newly_leafed = leaf_of_row & ~done
+                row_value = jnp.where(newly_leafed, value_of_row, row_value)
                 done = done | newly_leafed
 
-                f_of_row = fsafe[pos]
                 if fshard is None:
-                    b = jnp.take_along_axis(
-                        bins.astype(jnp.int32), f_of_row[:, None], axis=1
-                    )[:, 0]
+                    b = bin_of_feature(bins, f_of_row)
                 else:
                     # winning feature's bin column, owner-broadcast over the
                     # feature axis: one [N] collective — O(rows), not O(rows x F)
                     b = fshard.bin_column(bins, f_of_row)
                 go_right = route_right_binned(
-                    b, sp.split_bin[pos], sp.default_left[pos],
-                    None if cat_mask is None else cat_mask[f_of_row], missing_bin,
+                    b, split_bin_of_row, default_left_of_row,
+                    is_cat_of_row[0] if is_cat_of_row else None, missing_bin,
                 )
                 effective_right = jnp.where(done, False, go_right)
                 pos = pos * 2 + effective_right.astype(jnp.int32)
@@ -780,8 +840,9 @@ def build_tree(
     # Final level: every still-active node is a leaf.
     n_nodes = 1 << cfg.max_depth
     base = n_nodes - 1
-    gh_final = jnp.where(done[:, None], gh_zero, gh)
-    node_gh = deq(allreduce(node_sums(gh_final, pos, n_nodes)))
+    node_gh = deq(
+        allreduce(node_sums_dense(gh, jnp.where(done, -1, pos), n_nodes))
+    )
     if mono_on:
         node_value = lr * bounded_weight(
             node_gh[:, 0], node_gh[:, 1], cfg.split, lower, upper
@@ -797,7 +858,7 @@ def build_tree(
             jnp.where(active, node_value, 0.0)
         ),
     )
-    row_value = jnp.where(done, row_value, node_value[pos])
+    row_value = jnp.where(done, row_value, lookup_by_node(pos, node_value)[0])
     return tree, row_value
 
 
