@@ -78,6 +78,15 @@ def main() -> int:
         [r["train"]["auc"] for r in results], exp["auc"], atol=1e-5
     )
 
+    # what only a mesh has is summed over BOTH processes' shards (an output
+    # sharded over the actors is not addressable from one process), and is
+    # what one process over the same eight shards reads
+    stats = eng.mesh_round_stats()
+    assert list(stats.values()) == list(exp["mesh_stats"]), (stats, exp["mesh_stats"])
+    assert stats["collectives_per_round"] > 0, stats
+    local_rows = sum(len(s["label"]) for s in shards)
+    assert sum(eng.placement_record()["rows_per_device"].values()) == local_rows
+
     # margins gather across hosts (the VERDICT get_margins fix)
     margins = eng.get_margins()
     assert margins.shape[0] == n

@@ -232,6 +232,7 @@ def test_two_process_training_matches_single_process(tmp_path):
     np.savez(
         expected, x=x, y=y, rounds=rounds,
         logloss=[r["train"]["logloss"] for r in results],
+        mesh_stats=np.array(list(eng.mesh_round_stats().values())),
         auc=[r["train"]["auc"] for r in results],
         margins=bst.predict(x, output_margin=True),
         xr=xr, yr=yr, qid=qid, rank_ndcg=rank_ndcg,

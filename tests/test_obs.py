@@ -768,3 +768,17 @@ def test_scope_times_on_a_recorded_trace(tmp_path):
     })
     assert sum(got.values()) == pytest.approx(1500 * ps)  # the busy time
     assert device.scope_times(str(tmp_path / "nothing_here")) == {}
+
+    # a mesh: a second device whose level 1 waits in the merge's psum
+    merge = op(6, "%all-reduce.6", body + "tree/level1/allreduce/psum:")
+    ops1 = field(2, b"XLA Ops") + field(4, event(6, 0, 700))
+    plane1 = field(2, b"/device:TPU:1") + stat_md + merge + field(3, ops1)
+    (run_dir / "host.xplane.pb").write_bytes(
+        field(1, plane) + field(1, plane1) + field(1, host))
+    by_device = device.scope_times_by_device(str(tmp_path))
+    assert by_device["/device:TPU:0"] == pytest.approx(got)
+    assert by_device["/device:TPU:1"] == pytest.approx(
+        {"tree/level1/allreduce": 700 * ps})
+    both = device.scope_times(str(tmp_path))
+    assert sum(both.values()) == pytest.approx(2200 * ps)
+    assert both["tree/level1/allreduce"] == pytest.approx(700 * ps)

@@ -499,8 +499,8 @@ def test_sibling_compaction_overflow_falls_back():
     """The smaller child is chosen from GLOBAL (allreduced) counts; on a
     skewed shard its local rows can exceed the N//2 compaction buffer. Fake
     the count allreduce so the 'global' choice is the locally-BIGGER child:
-    the lax.cond must fall back to the gh-zeroed full-row build and still
-    grow exactly the tree the direct (no-subtraction) build grows."""
+    the compacted build must run over a second window of the selection and
+    still grow exactly the tree the direct (no-subtraction) build grows."""
     import numpy as np
     import jax.numpy as jnp
     from xgboost_ray_tpu.ops import binning

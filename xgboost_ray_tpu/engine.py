@@ -47,6 +47,7 @@ from xgboost_ray_tpu.constants import (
 from xgboost_ray_tpu.models.booster import RayXGBoostBooster, stack_trees
 from xgboost_ray_tpu.ops import binning
 from xgboost_ray_tpu.ops.histogram import (
+    MESH_STATS,
     AllreduceBytes,
     counting_psum,
     quantized_hist_allreduce,
@@ -792,6 +793,12 @@ class TpuEngine:
         # device-resident payload-byte counter of the latest round's tree
         # allreduces (materialized lazily — see hist_allreduce_bytes_per_round)
         self._ar_bytes_dev = None
+        # the dispatches' mesh_stats outputs, summed on the device as they
+        # come, and the rounds behind the sum; mesh_round_stats() reads it
+        # once. None on a one-device world, whose programs have no such output
+        self._mesh_stats_dev = None
+        self._mesh_stats_rounds = 0
+        self._mesh_stats_fold = None
         # static attributes attached to every "round" span: world size, row
         # counts, and (when sampling is on) the per-shard compacted budget —
         # the "sampling budgets become span attributes" half of the obs plane
@@ -833,6 +840,11 @@ class TpuEngine:
                 pad_width = [(0, local_pad - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
                 arr = np.pad(arr, pad_width, constant_values=fill)
             span_attrs["bytes"] = int(arr.nbytes)
+            # this process's row blocks, one a mesh slot, all equal
+            span_attrs["shards"] = self._local_row_blocks()
+            span_attrs["shard_bytes"] = (
+                int(arr.nbytes) // span_attrs["shards"]
+            )
             return put_rows_global(arr, self._row_sharding)
 
     def _global_row_layout(self, local_n: int):
@@ -1254,7 +1266,8 @@ class TpuEngine:
                        rng, bounds, eval_bins, eval_margins, lane=None):
             """One boosting round; gh_in is None unless a custom objective
             supplied precomputed gradients. Also returns the round's
-            measured tree-path allreduce payload bytes (AllreduceBytes).
+            measured tree-path allreduce payload bytes (AllreduceBytes) and
+            its ``mesh_stats`` (``None`` on a one-device world: no output).
 
             ``lane`` (vmapped-K only) is a dict of TRACED per-lane scalars:
             the lane-vectorizable split params, plus optionally
@@ -1455,7 +1468,7 @@ class TpuEngine:
             # feature-axis election/broadcast traffic
             counter.absorb(counter_f)
             return (new_margins, tuple(new_eval_margins), forest,
-                    counter.as_scalar())
+                    counter.as_scalar(), counter.mesh_stats())
 
         def metric_contribs(new_margins, new_eval_margins, label, w_eff,
                             train_group_rows, eval_data, bounds=None):
@@ -1668,7 +1681,7 @@ class TpuEngine:
                  bounds, eval_data):
             eval_bins = tuple(d.bins for d in eval_data)
             eval_margins = tuple(d.margins for d in eval_data)
-            new_margins, new_eval_margins, forest, ar_bytes = tree_round(
+            new_margins, new_eval_margins, forest, ar_bytes, mesh_stats = tree_round(
                 bins, valid, label, weight, margins, group_rows,
                 gh_in if custom else None, rng, bounds, eval_bins, eval_margins,
             )
@@ -1677,7 +1690,8 @@ class TpuEngine:
                 weight * valid.astype(jnp.float32), group_rows, eval_data,
                 bounds=bounds,
             )
-            return new_margins, new_eval_margins, forest, contribs, ar_bytes
+            return (new_margins, new_eval_margins, forest, contribs, ar_bytes,
+                    mesh_stats)
 
         eval_specs = self._eval_arr_specs()
         mapped = jax.shard_map(
@@ -1704,6 +1718,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes (identical on every shard)
+                P(AXIS_ACTORS),  # mesh_stats, a [3] a shard (None: no output)
             ),
             check_vma=False,
         )
@@ -1734,7 +1749,7 @@ class TpuEngine:
             def scan_body(carry, iteration):
                 margins_c, eval_margins_c = carry
                 rng = jax.random.fold_in(seed_key, iteration)
-                new_margins, new_eval_margins, forest, ar_bytes = tree_round(
+                new_margins, new_eval_margins, forest, ar_bytes, mesh_stats = tree_round(
                     bins, valid, label, weight, margins_c, group_rows, None,
                     rng, bounds, eval_bins, eval_margins_c,
                 )
@@ -1743,12 +1758,15 @@ class TpuEngine:
                     weight * valid.astype(jnp.float32), group_rows, eval_data,
                     bounds=bounds,
                 )
-                return (new_margins, new_eval_margins), (forest, contribs, ar_bytes)
+                return (new_margins, new_eval_margins), (
+                    forest, contribs, ar_bytes, mesh_stats)
 
-            (margins_out, eval_margins_out), (forests, contribs, ar_bytes) = (
+            (margins_out, eval_margins_out), (
+                forests, contribs, ar_bytes, mesh_stats) = (
                 jax.lax.scan(scan_body, (margins, eval_margins0), iterations)
             )
-            return margins_out, eval_margins_out, forests, contribs, ar_bytes
+            return (margins_out, eval_margins_out, forests, contribs, ar_bytes,
+                    mesh_stats)
 
         eval_specs = self._eval_arr_specs()
         mapped = jax.shard_map(
@@ -1771,6 +1789,7 @@ class TpuEngine:
                 P(),
                 tuple(tuple((P(), P()) for _ in self._device_metrics) for _ in self.evals),
                 P(),  # per-round allreduce payload bytes [n_rounds]
+                P(None, AXIS_ACTORS),  # mesh_stats [n_rounds, 3 a shard]
             ),
             check_vma=False,
         )
@@ -1870,7 +1889,7 @@ class TpuEngine:
                 # the scan program compiles once per distinct chunk length; the
                 # strict guard arms only for chunk lengths already dispatched
                 with strict_transfer_guard(active=prog in self._warm_programs):
-                    new_margins, new_eval_margins, forests, contribs, ar_bytes = self._scan_fn(
+                    new_margins, new_eval_margins, forests, contribs, ar_bytes, mesh_stats = self._scan_fn(
                         self.bins,
                         self.valid,
                         self.label_dev,
@@ -1885,6 +1904,7 @@ class TpuEngine:
             # keep the device scalar; materialized lazily by the accessor so the
             # steady-state step path adds NO host reads (transfer-count contract)
             self._ar_bytes_dev = ar_bytes[0]
+            self._keep_mesh_stats(mesh_stats)
             self.margins = new_margins
             self._adopt_eval_margins(new_eval_margins)
             # defer forest transfer: keep the whole stacked chunk on device
@@ -1973,7 +1993,7 @@ class TpuEngine:
                     gh_in = jnp.zeros((), jnp.float32)
                 bounds = self._default_bounds()
                 with strict_transfer_guard(active=prog in self._warm_programs):
-                    new_margins, new_eval_margins, forest, contribs, ar_bytes = fn(
+                    new_margins, new_eval_margins, forest, contribs, ar_bytes, mesh_stats = fn(
                         self.bins,
                         self.valid,
                         self.label_dev,
@@ -1987,6 +2007,7 @@ class TpuEngine:
                     )
             self._warm_programs.add(prog)
             self._ar_bytes_dev = ar_bytes
+            self._keep_mesh_stats(mesh_stats)
             self.margins = new_margins
             self._adopt_eval_margins(new_eval_margins)
             self._trees_dev.append((forest, None))
@@ -2160,7 +2181,11 @@ class TpuEngine:
         """Measured collective payload bytes of one boosting round's tree
         path (histogram merges + small exact reductions), from the
         device-side counter threaded through the compiled step. ``None``
-        before the first round. This is the ``hist_quant`` traffic metric:
+        before the first round. Every round program sets it from its latest
+        dispatch: ``step`` / ``step_custom``, the fused ``step_many`` (its
+        first round), the K-lane ``step_vmapped`` (lane 0) and the DART
+        step; 0 on a one-device world, where there is no wire. This is the
+        ``hist_quant`` traffic metric:
         int8 cuts it ~4x vs the f32 psum. Reading it costs one device->host
         transfer, so callers (bench/driver) fetch it once after training,
         never per round."""
@@ -2168,27 +2193,110 @@ class TpuEngine:
             return None
         return int(np.asarray(self._ar_bytes_dev))
 
+    def _keep_mesh_stats(self, mesh_stats) -> None:
+        """Add a dispatch's ``mesh_stats`` output (``[..., 3 a shard]``:
+        rounds or lanes by shards) to the running sum on the device: an
+        enqueue, no host read on the round path. A one-device program has
+        no such output."""
+        if mesh_stats is None:
+            return
+        if self._mesh_stats_dev is None:
+            # the sum starts as zeros in the layout the fold hands back, so
+            # the first dispatch compiles the fold and no later one does
+            per_shard = NamedSharding(self.mesh, P(AXIS_ACTORS))
+            zeros = np.zeros(mesh_stats.shape[-1], np.int32)
+            self._mesh_stats_dev = jax.make_array_from_callback(
+                zeros.shape, per_shard, lambda idx: zeros[idx]
+            )
+            self._mesh_stats_fold = jax.jit(
+                lambda acc, new: acc + new.reshape(-1, acc.shape[0]).sum(axis=0),
+                out_shardings=per_shard,
+            )
+        self._mesh_stats_dev = self._mesh_stats_fold(
+            self._mesh_stats_dev, mesh_stats
+        )
+        self._mesh_stats_rounds += int(np.prod(mesh_stats.shape[:-1]))
+
+    def mesh_round_stats(self) -> Dict[str, int]:
+        """What only a mesh has, from ``AllreduceBytes.mesh_stats`` as the
+        round programs returned it: ``collectives_per_round`` (the tree
+        path's collectives, counted where the bytes are), and over every
+        round dispatched since the last reset and every shard,
+        ``hist_sibling_builds`` (compacted sibling-subtraction builds in
+        the skew-tolerant window loop) and ``hist_skew_fallback_builds``
+        (those that needed more than one window because the shard's rows of
+        the chosen children overflowed its ``N // 2`` buffer). All 0 on a
+        one-device world, whose programs have no wire and no such loop.
+        Reads this process's shards of the one running sum (a small
+        device->host read each, after training only) and, on a
+        multi-process world, allgathers the processes' sums: every process
+        must call it, and every process gets the world's numbers."""
+        out = {"collectives_per_round": 0, "hist_skew_fallback_builds": 0,
+               "hist_sibling_builds": 0}
+        if self._mesh_stats_dev is None:
+            return out
+        # one [3] a row shard; a 2D mesh repeats it along the feature axis
+        total = np.zeros(len(MESH_STATS), np.int64)
+        for s in self._mesh_stats_dev.addressable_shards:
+            if s.replica_id == 0:
+                total += np.asarray(s.data).reshape(-1, len(MESH_STATS)).sum(axis=0)
+        if jax.process_count() > 1:
+            from jax.experimental import multihost_utils
+
+            total = np.asarray(
+                multihost_utils.process_allgather(total)
+            ).reshape(-1, len(MESH_STATS)).sum(axis=0)
+        calls, fallback, sibling = (int(v) for v in total)
+        shards = int(self.mesh.shape[AXIS_ACTORS])
+        out["collectives_per_round"] = calls // (
+            self._mesh_stats_rounds * shards)
+        out["hist_skew_fallback_builds"] = fallback
+        out["hist_sibling_builds"] = sibling
+        return out
+
     def placement_record(self) -> Dict[str, Any]:
         """Where this engine runs and which chip-or-CPU defaults it
         resolved: platform, device_kind and device count as JAX reports
         them, the training mesh's shape and devices, the real (unpadded)
-        rows each mesh device holds, and the resolved ``hist_impl`` /
+        rows each of this process's mesh devices holds (``rows_per_device``
+        by device id; no device read), and the resolved ``hist_impl`` /
         ``hist_precision``. Recorded under ``additional_results["device"]``
-        once per ``train()``; reading the per-device row counts costs one
-        small transfer per addressable device."""
+        once per ``train()``."""
         from xgboost_ray_tpu.util import device_record
 
+        blocks = self.rows_per_device()
+        block = self._local_pad // len(blocks)
+        shards = self.valid.addressable_shards
+        first = min(s.index[0].start or 0 for s in shards)
         return {
             **device_record(),
             "mesh_shape": {k: int(v) for k, v in self.mesh.shape.items()},
             "mesh_device_ids": [int(d.id) for d in self.mesh.devices.flat],
             "rows_per_device": {
-                str(s.device.id): int(np.asarray(s.data).sum())
-                for s in self.valid.addressable_shards
+                str(s.device.id): blocks[
+                    ((s.index[0].start or 0) - first) // block]
+                for s in shards
             },
             "hist_impl": self.cfg.hist_impl,
             "hist_precision": self.cfg.hist_precision,
         }
+
+    def _local_row_blocks(self) -> int:
+        """Row blocks (``AXIS_ACTORS`` slots) this process holds."""
+        return max(1, self.n_devices // jax.process_count())
+
+    def rows_per_device(self) -> List[int]:
+        """The real (unpadded) rows in each of this process's row blocks,
+        in mesh order. ``valid`` is this process's rows followed by its
+        padding, cut into equal blocks, so this is arithmetic: no device
+        read. The one source of the number (``placement_record``, the
+        ``engine.init`` span)."""
+        n_local = self._local_row_blocks()
+        block = self._local_pad // n_local
+        return [
+            max(0, min(block, self._local_rows - i * block))
+            for i in range(n_local)
+        ]
 
     def gh_plane_bytes_per_shard(self) -> int:
         """Static per-shard bytes of one tree's (grad, hess) plane — the
@@ -2493,7 +2601,7 @@ class TpuEngine:
             eval_margins_k = tuple(d.margins for d in eval_data)
 
             def one_lane(margins, eval_margins, lane, rng):
-                new_margins, new_eval_margins, forest, ar_bytes = tree_round(
+                new_margins, new_eval_margins, forest, ar_bytes, mesh_stats = tree_round(
                     bins, valid, label, weight, margins, group_rows, None,
                     rng, bounds, eval_bins, eval_margins, lane=lane,
                 )
@@ -2502,7 +2610,8 @@ class TpuEngine:
                     weight * valid.astype(jnp.float32), group_rows,
                     eval_data, bounds=bounds,
                 )
-                return new_margins, new_eval_margins, forest, contribs, ar_bytes
+                return (new_margins, new_eval_margins, forest, contribs,
+                        ar_bytes, mesh_stats)
 
             return jax.vmap(one_lane, in_axes=(0, 0, 0, 0))(
                 margins_k, eval_margins_k, lane_arrs, rngs
@@ -2534,6 +2643,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes [K]
+                P(None, AXIS_ACTORS),  # mesh_stats [K, 3 a shard]
             ),
             check_vma=False,
         )
@@ -2585,7 +2695,7 @@ class TpuEngine:
                 bounds = self._default_bounds()
                 rngs = self._vk_rngs(iteration)
                 with strict_transfer_guard(active=prog in self._warm_programs):
-                    new_margins, new_eval_margins, forests, contribs, ar_bytes = fn(
+                    new_margins, new_eval_margins, forests, contribs, ar_bytes, mesh_stats = fn(
                         self.bins,
                         self.valid,
                         self.label_dev,
@@ -2599,6 +2709,7 @@ class TpuEngine:
                     )
             self._warm_programs.add(prog)
             self._ar_bytes_dev = ar_bytes[0]
+            self._keep_mesh_stats(mesh_stats)
             self.margins = new_margins
             self._adopt_eval_margins(new_eval_margins)
             # defer the [K, T, heap] forest transfer like the scalar path
@@ -2864,6 +2975,8 @@ class TpuEngine:
         self._stack_rows = 0
         self._stack_buf = None
         self._ar_bytes_dev = None
+        self._mesh_stats_dev = None
+        self._mesh_stats_rounds = 0
         self.iteration_offset = (
             init_booster.num_boosted_rounds() if init_booster is not None else 0
         )
@@ -2960,7 +3073,7 @@ class TpuEngine:
                       bounds, forest, w_eff, w_post, new_w, slot, rng, eval_data):
             m_eff = forest_margin(forest, bins, static_margins, w_eff)
             eval_bins = tuple(d.bins for d in eval_data)
-            new_margins, _, round_forest, ar_bytes = tree_round(
+            new_margins, _, round_forest, ar_bytes, mesh_stats = tree_round(
                 bins, valid, label, weight, m_eff, group_rows, None, rng,
                 bounds, (), (),
             )
@@ -2989,7 +3102,7 @@ class TpuEngine:
                 bounds=bounds,
             )
             return (m_full, tuple(new_eval_margins), forest, round_forest,
-                    contribs, ar_bytes)
+                    contribs, ar_bytes, mesh_stats)
 
         eval_specs = self._eval_arr_specs()
         mapped = jax.shard_map(
@@ -3021,6 +3134,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes
+                P(AXIS_ACTORS),  # mesh_stats, a [3] a shard
             ),
             check_vma=False,
         )
@@ -3109,7 +3223,7 @@ class TpuEngine:
                 new_w_dev = jax.device_put(np.float32(new_w), repl)
                 dart_t_dev = jax.device_put(np.int32(self.dart_t), repl)
                 with strict_transfer_guard(active="dart" in self._warm_programs):
-                    m_full, new_eval_margins, forest, round_forest, contribs, ar_bytes = self._dart_fn(
+                    m_full, new_eval_margins, forest, round_forest, contribs, ar_bytes, mesh_stats = self._dart_fn(
                         self.bins,
                         self.valid,
                         self.label_dev,
@@ -3128,6 +3242,7 @@ class TpuEngine:
             self._warm_programs.add("dart")
             self.margins = m_full
             self._ar_bytes_dev = ar_bytes
+            self._keep_mesh_stats(mesh_stats)
             self.dart_forest_dev = forest
             self._adopt_eval_margins(new_eval_margins)
             self._trees_dev.append((round_forest, None))

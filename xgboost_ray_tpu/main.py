@@ -481,6 +481,14 @@ def _handle_queue(queue: Queue, checkpoint: _Checkpoint, callback_returns: Dict)
             callback_returns.setdefault(rank, []).append(item)
 
 
+def _shard_attrs(dtrain) -> Dict:
+    """``data.load``'s view of the training matrix: how many host shards it
+    holds now and the bytes of each, by rank."""
+    sizes = dtrain.get_shard_bytes()
+    return {"shards": len(sizes),
+            "shard_bytes": [sizes[r] for r in sorted(sizes)]}
+
+
 def _record_engine_readouts(state, engine) -> None:
     """Surface the engine's measured per-round collective payload bytes
     (the ``hist_quant`` traffic metric) and where the run ran in
@@ -503,11 +511,34 @@ def _record_engine_readouts(state, engine) -> None:
     if getter is None:
         return
     val = getter()
-    if val is not None:
-        state.additional_results["hist_allreduce_bytes_per_round"] = val
-        obs.get_tracer().event(
-            "allreduce.bytes", attrs={"bytes_per_round": int(val)}
-        )
+    if val is None:
+        return
+    # set by every tree round program (step, step_many, the K-lane step,
+    # DART); the linear booster has no tree path and no getter
+    mesh = engine.mesh_round_stats()
+    state.additional_results["hist_allreduce_bytes_per_round"] = val
+    state.additional_results.update(mesh)
+    tracer = obs.get_tracer()
+    tracer.event(
+        "allreduce.bytes",
+        attrs={
+            "bytes_per_round": int(val),
+            "collectives_per_round": mesh["collectives_per_round"],
+            "mesh": {k: int(v) for k, v in engine.mesh.shape.items()},
+        },
+    )
+    tracer.event(
+        "hist.skew_builds",
+        attrs={
+            "fallback_builds": mesh["hist_skew_fallback_builds"],
+            "sibling_builds": mesh["hist_sibling_builds"],
+        },
+    )
+    registry = obs.get_registry()
+    registry.counter("rxgb_hist_skew_fallback_builds_total").inc(
+        mesh["hist_skew_fallback_builds"])
+    registry.counter("rxgb_hist_sibling_builds_total").inc(
+        mesh["hist_sibling_builds"])
 
 
 def _stop_profile_if_running():
@@ -711,7 +742,7 @@ def _train(
 
     # 3) data loading on every alive actor (mirror _PrepareActorTask)
     load_errors = []
-    with tracer.span("data.load", matrices=1 + len(evals)):
+    with tracer.span("data.load", matrices=1 + len(evals)) as load_attrs:
         for actor in state.actors:
             if actor is None:
                 continue
@@ -723,6 +754,7 @@ def _train(
                 raise
             except Exception as exc:  # noqa: BLE001 - surfaced as task error
                 load_errors.append((actor.rank, exc))
+        load_attrs.update(_shard_attrs(dtrain))
     if load_errors:
         err = RayTaskError(f"Data loading failed on ranks {load_errors}")
         err.ranks = [rank for rank, _ in load_errors]
@@ -809,7 +841,7 @@ def _train(
                 )
         with tracer.span(
             "engine.init", booster=parsed.booster, world=len(world_actors)
-        ):
+        ) as init_attrs:
             if parsed.booster == "gblinear":
                 from xgboost_ray_tpu.linear import LinearEngine
 
@@ -838,6 +870,7 @@ def _train(
                     categories=train_cats,
                     stream_donor=donor,
                 )
+                init_attrs["rows_per_device"] = eng.rows_per_device()
         eng._world_key = key
         eng._shard_fingerprint = fp
         return eng
@@ -1832,10 +1865,13 @@ def _train_impl(
     xgb_model = _coerce_model(kwargs.get("xgb_model"))
 
     # eager central loading on the driver (mirror main.py:1555-1556)
-    with obs.get_tracer().span("data.load", matrices=1 + len(evals)):
+    with obs.get_tracer().span(
+        "data.load", matrices=1 + len(evals)
+    ) as load_attrs:
         dtrain.load_data(ray_params.num_actors)
         for deval, _ in evals:
             deval.load_data(ray_params.num_actors)
+        load_attrs.update(_shard_attrs(dtrain))
 
     state = _TrainingState(
         actors=[None] * ray_params.num_actors,

@@ -782,6 +782,15 @@ class RayDMatrix:
 
         return {r: size(s) for r, s in self.refs.items()}
 
+    def get_shard_bytes(self) -> Dict[int, int]:
+        """Host bytes of each materialised shard's arrays, by rank (a
+        streamed shard holds no rows on the host: 0)."""
+        return {
+            r: sum(int(v.nbytes) for v in s.values()
+                   if isinstance(v, np.ndarray))
+            for r, s in self.refs.items()
+        }
+
     @property
     def resolved_feature_names(self) -> Optional[List[str]]:
         return self.feature_names or self.loader._resolved_feature_names

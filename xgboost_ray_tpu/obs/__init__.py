@@ -23,8 +23,9 @@ lock-guarded metrics island). This package is the one plane they now share:
   / backend compile / cache load as a ``compile.*`` span under the span
   that caused it, and counts them in the registry.
 * :mod:`xgboost_ray_tpu.obs.device` — ``scope_times(trace_dir)``: device
-  seconds per ``DEVICE_SCOPES`` path from a profiler trace
-  (``python -m xgboost_ray_tpu.obs.device <dir>``); imports jax when called.
+  seconds per ``DEVICE_SCOPES`` path from a profiler trace, and
+  ``scope_times_by_device`` the same for each device of a mesh
+  (``python -m xgboost_ray_tpu.obs.device <dir>``); stdlib only.
 
 ``train()`` scopes a fresh tracer per run and returns its timeline under
 ``additional_results["obs"]``: one ``attempt`` span, under it ``data.load``,

@@ -95,24 +95,50 @@ def _plane_times(plane, out):
         close(float("inf"))
 
 
-def scope_times(trace_dir: str) -> dict:
-    """``{scope path: device seconds}`` over the ``XLA Ops`` of every device
-    plane of the newest ``.xplane.pb`` under ``trace_dir`` (summed over
-    devices; the values add up to the devices' busy seconds)."""
+def scope_times_by_device(trace_dir: str) -> dict:
+    """``{device plane: {scope path: device seconds}}`` over the ``XLA Ops``
+    of every device plane of the newest ``.xplane.pb`` under ``trace_dir``.
+    A device's values add up to its busy seconds; on a mesh the scope
+    ``allreduce`` holds each device's wait at the level's barrier too."""
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
     out = {}
     if paths:
         with open(max(paths, key=os.path.getmtime), "rb") as fh:
             space = memoryview(fh.read())
         for n, plane in _fields(space):  # repeated XPlane planes = 1; name = 2
-            if n == 1 and bytes(dict(_fields(plane)).get(2, b"")).startswith(b"/device:"):
-                _plane_times(plane, out)
+            name = bytes(dict(_fields(plane)).get(2, b"")).decode() if n == 1 else ""
+            if name.startswith("/device:"):
+                _plane_times(plane, out.setdefault(name, {}))
     return out
 
 
-if __name__ == "__main__":
-    times = scope_times(sys.argv[1])
+def _summed(by_device: dict) -> dict:
+    out = {}
+    for times in by_device.values():
+        for name, sec in times.items():
+            out[name] = out.get(name, 0.0) + sec
+    return out
+
+
+def scope_times(trace_dir: str) -> dict:
+    """``{scope path: device seconds}`` summed over the devices of
+    :func:`scope_times_by_device` (the values add up to the devices' busy
+    seconds together)."""
+    return _summed(scope_times_by_device(trace_dir))
+
+
+def _print_table(times):
     total = sum(times.values()) or 1.0
     for name, sec in sorted(times.items(), key=lambda kv: -kv[1]):
         print(f"{sec:12.6f} s  {100 * sec / total:6.2f} %  {name}")
     print(f"{sum(times.values()):12.6f} s  busy, all scopes")
+
+
+if __name__ == "__main__":
+    by_device = scope_times_by_device(sys.argv[1])
+    if len(by_device) > 1:
+        for device, times in sorted(by_device.items()):
+            print(device)
+            _print_table(times)
+        print("all devices")
+    _print_table(_summed(by_device))

@@ -105,6 +105,9 @@ TRACE_NAMES = frozenset({
     "attempt", "failure.detected", "recovered", "backoff",
     "world.shrink", "world.grow", "world.resume", "world.restart",
     "checkpoint.commit", "allreduce.bytes",
+    # after training, beside allreduce.bytes: the sibling builds that sat
+    # in the skew fallback's window loop, and how many needed a second window
+    "hist.skew_builds",
     # failure domains (main.py): domain_down when a failure takes a whole
     # domain's last alive rank (one per lost domain, beside the single
     # coalesced world.shrink), deaths_coalesced when one shrink absorbed

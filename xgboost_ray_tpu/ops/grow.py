@@ -234,10 +234,10 @@ class GrowConfig:
     # inside this hashable jit-static config.
     cat_features: tuple = ()
     # True when this shard's counts can differ from the allreduced ones
-    # (world size > 1): the compacted sibling build then carries a lax.cond
-    # fallback for selections overflowing the N//2 buffer. Single-shard
-    # training sets False — the selection provably fits, and skipping the
-    # cond halves the per-level histogram code to compile.
+    # (world size > 1): the compacted sibling build then sits in a loop that
+    # runs it once per N // 2 window of the selection, for selections
+    # overflowing the buffer. Single-shard training sets False — the
+    # selection provably fits, and the build stands alone.
     shards_may_skew: bool = True
     # per-feature monotone constraints (len == F, values -1/0/+1) or () —
     # xgboost's monotone_constraints via per-node weight-bound propagation
@@ -348,7 +348,8 @@ def build_tree(
     feature_log_weights: Optional[jnp.ndarray] = None,  # [F] log(fw), -inf at 0
     feat_has_missing: Optional[jnp.ndarray] = None,  # [F] bool, global
     hist_allreduce: Optional[Callable[[jnp.ndarray], jnp.ndarray]] = None,
-    ar_counter=None,  # AllreduceBytes: scan-scoped byte accounting
+    ar_counter=None,  # AllreduceBytes: scan-scoped byte accounting, and the
+    #   count of sibling builds that sit in the skew fallback's window loop
     fshard=None,  # ops.provider.FeatureShard on a 2D row x feature mesh
     gh_scale: Optional[jnp.ndarray] = None,  # [2] f32 per-channel scales of a
     #   quantized integer gh buffer (gh_precision; None = f32 legacy path)
@@ -589,41 +590,48 @@ def build_tree(
                 if track_order:
                     # compact the smaller child's rows into an [N // 2] buffer so
                     # every impl processes HALF the rows (vs just zeroing gh).
-                    # The child choice is GLOBAL (allreduced counts), so on a
-                    # skewed shard the chosen children's LOCAL rows can exceed
-                    # N // 2 — lax.cond falls back to the gh-zeroed full-row
-                    # build there (shard-local control flow; the psum sits
-                    # outside and runs on every shard either way).
-                    with jax.named_scope("hist"):
-                        rows, par_of_slot, _valid_sel, counts_sel = (
-                            select_small_child_rows(order, counts, small_is_right)
-                        )
-
-                    def _compacted(_):
+                    def _compacted(window=None):
                         # done rows only live under inactive parents (they always
                         # route left below their leaf), so the active nodes this
                         # histogram feeds never see them — no done-mask needed;
                         # sentinel slots zero out via the layouts' appended row.
+                        with jax.named_scope("hist"):
+                            rows, par_of_slot, _valid_sel, counts_sel = (
+                                select_small_child_rows(
+                                    order, counts, small_is_right, offset=window
+                                )
+                            )
                         return _build(gh, par_of_slot, None, counts_sel, n_par,
                                       rows_sel=rows)
 
-                    def _zeroed(_):
-                        parent_pos = pos >> 1
-                        is_right = (pos & 1).astype(bool)
-                        sel = (
-                            is_right == lookup_by_node(parent_pos, small_is_right)[0]
-                        ) & ~done
-                        gh_sel = gh * sel[:, None].astype(gh.dtype)
-                        counts_par = counts.reshape(-1, 2).sum(axis=1)
-                        return _build(gh_sel, parent_pos, order, counts_par, n_par)
-
                     if cfg.shards_may_skew:
-                        fits = counts_sel.sum() <= rows.shape[0]
-                        hist_small = hist_ar(
-                            jax.lax.cond(fits, _compacted, _zeroed, None)
-                        )
+                        # The child choice is GLOBAL (allreduced counts), so on a
+                        # skewed shard the chosen children's LOCAL rows can exceed
+                        # N // 2. The shard then runs the SAME compacted build
+                        # once more per further N // 2 window of its selection
+                        # and adds the results up (shard-local control flow; the
+                        # psum sits outside and runs on every shard either way).
+                        # One loop body, not a cond between this build and a
+                        # full-row one: a build's code grows with its row
+                        # extent (some 75 MB a level at 11M rows), so a second
+                        # build a level more than doubles the program.
+                        n_half = max(n // 2, 1)
+                        with jax.named_scope("hist"):
+                            picked = counts[
+                                2 * jnp.arange(n_par, dtype=jnp.int32)
+                                + small_is_right.astype(jnp.int32)
+                            ].sum()
+                        n_windows = (picked + n_half - 1) // n_half
+                        if ar_counter is not None:
+                            ar_counter.note_sibling_build(n_windows <= 1)
+                        one = jax.eval_shape(_compacted, jnp.int32(0))
+                        hist_small = hist_ar(jax.lax.fori_loop(
+                            0, n_windows,
+                            lambda w, acc: acc + _compacted(w * n_half),
+                            jnp.zeros(one.shape, one.dtype),
+                        ))
                     else:
-                        hist_small = hist_ar(_compacted(None))
+                        hist_small = hist_ar(_compacted())
                 else:
                     parent_pos = pos >> 1
                     is_right = (pos & 1).astype(bool)

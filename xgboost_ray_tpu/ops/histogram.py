@@ -93,6 +93,10 @@ HIST_QUANT_DEFAULT_BLOCK = 512
 HIST_QUANT_MIN_BYTES = 32768
 
 
+#: what ``AllreduceBytes.mesh_stats`` holds, in order
+MESH_STATS = ("collectives", "skew_fallback_builds", "sibling_builds")
+
+
 class AllreduceBytes:
     """Per-actor wire-byte counter for one traced round, under the standard
     ring-collective cost model.
@@ -113,29 +117,42 @@ class AllreduceBytes:
     mode is *measured from the program that ran*, not asserted. On a
     1-device mesh every term is zero — there is no wire. ``lax.scan``
     bodies trace once but execute per step: growers wrap such regions in
-    ``repeated(n_steps)``."""
+    ``repeated(n_steps)``.
+
+    Beside the bytes it counts what else only a mesh has, at the same call
+    sites: ``calls``, the collectives a round (one per recorded op, a
+    multi-hop ring once per hop; 0 on a 1-device axis, where there is no
+    wire), and the sibling-subtraction builds that sit in a window loop
+    because a shard's rows may skew (``note_sibling_build``). They leave the
+    round program through ``mesh_stats``, which is ``None`` -- no output at
+    all -- where the round traced no collective and no such build, so a
+    one-device program is the program it was."""
 
     def __init__(self, n_actors: int):
         self.n = max(1, int(n_actors))
         self.total = 0  # python int: operand shapes are trace-time constants
+        self.calls = 0
+        self.sibling_builds = 0
+        self.fallback_builds = 0  # a traced int32 once a build was noted
         self._mult = 1
 
     @staticmethod
     def _nbytes(arr) -> int:
         return int(arr.size) * arr.dtype.itemsize
 
+    def _add(self, nbytes: float, calls: int = 1) -> None:
+        self.total += int(nbytes) * self._mult
+        if self.n > 1:
+            self.calls += calls * self._mult
+
     def add_allreduce(self, arr) -> None:
-        self.total += (
-            int(2 * (self.n - 1) * self._nbytes(arr) / self.n) * self._mult
-        )
+        self._add(2 * (self.n - 1) * self._nbytes(arr) / self.n)
 
     def add_all_to_all(self, arr) -> None:
-        self.total += (
-            int((self.n - 1) * self._nbytes(arr) / self.n) * self._mult
-        )
+        self._add((self.n - 1) * self._nbytes(arr) / self.n)
 
     def add_all_gather(self, chunk) -> None:
-        self.total += (self.n - 1) * self._nbytes(chunk) * self._mult
+        self._add((self.n - 1) * self._nbytes(chunk))
 
     def add_ppermute(self, arr, hops: int = 1) -> None:
         """One ``ppermute`` ring hop: every actor ships the full operand to
@@ -143,7 +160,18 @@ class AllreduceBytes:
         (``hops`` times for a multi-hop ring recorded at one call site).
         Without this the counter would have no model for the block-scale
         ring and would silently charge it as an allreduce."""
-        self.total += self._nbytes(arr) * int(hops) * self._mult
+        self._add(self._nbytes(arr) * int(hops), calls=int(hops))
+
+    def note_sibling_build(self, fits) -> None:
+        """One compacted sibling build in a skew-tolerant loop: ``fits`` is
+        this shard's traced predicate "one N // 2 window holds my rows of
+        the chosen children", and ``~fits`` a build that fell back to
+        further windows (up to twice the rows, while the other shards wait
+        at the level's psum)."""
+        self.sibling_builds += self._mult
+        self.fallback_builds = self.fallback_builds + (
+            jnp.logical_not(fits).astype(jnp.int32) * self._mult
+        )
 
     def repeated(self, n: int):
         """Context manager: collectives traced inside run ``n`` times."""
@@ -167,11 +195,23 @@ class AllreduceBytes:
         the single emission point. ``None`` is a no-op."""
         if other is not None:
             self.total += int(other.total)
+            self.calls += int(other.calls)
 
     def as_scalar(self) -> jnp.ndarray:
         """The total as a device int32 (clamped; ~2 GB/round is beyond any
         real per-round payload)."""
         return jnp.int32(min(self.total, 2**31 - 1))
+
+    def mesh_stats(self) -> Optional[jnp.ndarray]:
+        """``MESH_STATS`` of this shard's round as int32 ``[3]``, or ``None``
+        where there is nothing to say (see the class's text)."""
+        if not self.calls and not self.sibling_builds:
+            return None
+        return jnp.stack([
+            jnp.int32(self.calls),
+            jnp.asarray(self.fallback_builds, jnp.int32),
+            jnp.int32(self.sibling_builds),
+        ])
 
 
 def counting_psum(axis_name: str, counter: Optional[AllreduceBytes]):
@@ -610,6 +650,7 @@ def select_small_child_rows(
     order: jnp.ndarray,  # [N] rows sorted stably by child node
     counts: jnp.ndarray,  # [2 * n_par] rows per child node
     small_is_right: jnp.ndarray,  # [n_par] bool
+    offset=None,  # traced int32: the window's first slot of the selection
 ):
     """Compact the rows of every parent's smaller child into [N // 2] slots.
 
@@ -621,6 +662,12 @@ def select_small_child_rows(
     slots, parent index per slot [N//2], valid mask [N//2], counts_sel
     [n_par]); rows come out sorted by parent, so they are directly a
     presorted (order=arange, counts=counts_sel) layout.
+
+    On a mesh the child choice is global, so one shard's selection can be
+    longer than its N // 2 slots. ``offset`` then names a window of it:
+    slots ``[offset, offset + N // 2)`` of the selection, with counts_sel
+    the rows of each parent inside the window. Windows 0, N // 2, ... tile
+    the selection, and window 0 of a selection that fits is the whole of it.
     """
     n = order.shape[0]
     n_par = small_is_right.shape[0]
@@ -633,6 +680,10 @@ def select_small_child_rows(
     cum_sel = jnp.cumsum(counts_sel)
     start_sel = jnp.concatenate([jnp.zeros((1,), cum_sel.dtype), cum_sel[:-1]])
     i = jnp.arange(n_half)
+    if offset is not None:
+        i = i + offset
+        counts_sel = (jnp.clip(cum_sel - offset, 0, n_half)
+                      - jnp.clip(start_sel - offset, 0, n_half))
     p = jnp.searchsorted(cum_sel, i, side="right")
     pc = jnp.clip(p, 0, n_par - 1).astype(jnp.int32)
     src = seg_start[c_small[pc]] + (i - start_sel[pc])
