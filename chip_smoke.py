@@ -339,8 +339,9 @@ class Smoke:
         ).astype(np.float32)
         gh = jnp.asarray(gh_np)
         out = {}
-        # fan-out 2 takes mixed's one-hot branch, 8 its partition branch
-        for n_nodes in (2, 8):
+        # fan-out 2 takes mixed's dense one-hot build (4 matmul columns),
+        # 1,024 (2,048 columns, past the crossover) its presorted-blocks build
+        for n_nodes in (2, 1024):
             pos = jnp.asarray(rng.randint(0, n_nodes, n).astype(np.int32))
             scatter = jax.jit(
                 lambda b, g, p, nn=n_nodes: hist_scatter(b, g, p, nn, nbt))

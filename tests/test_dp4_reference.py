@@ -1,12 +1,17 @@
 """The four-actor data-parallel job (``higgs-d6-dp4``'s deployment) at a
 small size: 40,000 x 28, depth 6, 256 bins, 5 rounds, seeded, over 4 of
-conftest's 8 host devices, with the chip's ``hist_impl`` (``mixed``: the
-presorted builds, whose compacted sibling build carries the skew fallback).
+conftest's 8 host devices, with the chip's ``hist_impl`` (``mixed``: at
+this depth the dense build at every level, which streams every row, compacts
+nothing and so has no skew fallback to fire) and with ``partition`` (the
+presorted builds at every fan-out, whose compacted sibling build carries the
+skew fallback's window loop).
 
 Two ways of cutting the same rows into shards: (a) i.i.d. shards (the rows
 as generated, in contiguous blocks) and (b) the rows sorted by feature 0
-first, so that every shard holds one quarter of feature 0's range, a split
-on it sends whole shards to one side, and the fallback must fire.
+first, so that every shard holds one quarter of feature 0's range and a
+split on it sends whole shards to one side: under ``partition`` (case
+``sorted``) the fallback must fire, under ``mixed`` (case ``sorted-dense``)
+the same rows grow the same forest with no fallback at all.
 
 The 4-device forest is held to the benchmark's plain reference
 (``benchmarks/reference.py``: ``follow`` / ``compare``, which imports nothing
@@ -51,28 +56,33 @@ LIMITS = {"loss": 1e-5, "leaf": 1e-4, "cover": 1e-4, "split": 0.15,
 NBT = 257  # 256 bins and the missing bucket
 
 
+#: case -> hist_impl
+CASES = {"iid": "mixed", "sorted": "partition", "sorted-dense": "mixed"}
+
+
 def _rows(case):
     x, y = datagen.make(ROWS, FEATURES, SEED, levels=257)
-    if case == "sorted":
+    if case.startswith("sorted"):
         order = np.argsort(x[:, 0], kind="stable")
         x, y = x[order], y[order]
     return x, y
 
 
-def _train(x, y, actors):
+def _train(x, y, actors, hist_impl):
     evals_result, extra = {}, {}
     dtrain = RayDMatrix(x, y, sharding=RayShardingMode.BATCH)
-    bst = train(PARAMS, dtrain, ROUNDS, evals=[(dtrain, "train")],
+    bst = train(dict(PARAMS, hist_impl=hist_impl), dtrain, ROUNDS,
+                evals=[(dtrain, "train")],
                 evals_result=evals_result, additional_results=extra,
                 ray_params=RayParams(num_actors=actors, max_actor_restarts=1))
     return bst, evals_result, extra
 
 
-@pytest.fixture(scope="module", params=["iid", "sorted"])
+@pytest.fixture(scope="module", params=list(CASES))
 def job(request):
     x, y = _rows(request.param)
-    bst4, evals4, extra4 = _train(x, y, ACTORS)
-    bst1, _, extra1 = _train(x, y, 1)
+    bst4, evals4, extra4 = _train(x, y, ACTORS, CASES[request.param])
+    bst1, _, extra1 = _train(x, y, 1, CASES[request.param])
     return {
         "case": request.param, "x": x, "y": y,
         "forest4": reference.forest_arrays(bst4.forest),
@@ -87,7 +97,8 @@ def _recount_skew_builds(forest, x, actors):
     at every level >= 1, per parent the child with fewer live rows over ALL
     shards is built (the right one on a tie), and a shard whose own rows of
     those children (rows parked under a leaf included: they ride down its
-    left edge) exceed half its rows falls back to a second window."""
+    left edge) exceed half its rows falls back to a second window -- where
+    the build compacts them, i.e. under ``partition``."""
     shards = np.array_split(np.arange(x.shape[0]), actors)
     depth = PARAMS["max_depth"]
     fallback = sibling = 0
@@ -157,9 +168,15 @@ def test_skew_fallback_counters_match_a_recount_from_the_forest(job):
     # one build a (round, level >= 1, shard)
     assert sibling == ROUNDS * (PARAMS["max_depth"] - 1) * ACTORS
     assert extra["hist_sibling_builds"] == sibling
+    if job["case"] == "sorted-dense":
+        # the shards are as skewed as in "sorted" (a split on feature 0 sends
+        # whole shards to one side), but the dense build has no buffer to
+        # overflow: every noted sibling build needed no further window
+        assert fallback > 0
+        assert extra["hist_skew_fallback_builds"] == 0
+        return
     assert extra["hist_skew_fallback_builds"] == fallback
     if job["case"] == "sorted":
-        # a split on feature 0 sends whole shards to one side
         assert fallback > 0
     else:
         # i.i.d. shards: the chosen children hold at most half of all rows
@@ -277,13 +294,15 @@ def _count_primitives(jaxpr, counts):
     return counts
 
 
-def test_mesh_tree_holds_one_sibling_build_a_level():
+@pytest.mark.parametrize("hist_impl", ["partition", "mixed"])
+def test_mesh_tree_holds_one_sibling_build_a_level(hist_impl):
     """The skew fallback is a loop around the ONE compacted build, not a
     ``cond`` between it and a full-row build: at 11M rows a device a second
     build a level made the 4-device program 1.15 GB of code, which the chip's
     host could not compile (PERF.md section 6, PR 29). Same matmuls in the
-    mesh's tree as in the one-device tree, no ``cond``, one ``while`` more a
-    level >= 1."""
+    mesh's tree as in the one-device tree, no ``cond``, and under
+    ``partition`` one ``while`` more a level >= 1; ``mixed``'s dense levels
+    need no loop, so its mesh tree has the one-device tree's control flow."""
     import jax
     import jax.numpy as jnp
 
@@ -300,12 +319,13 @@ def test_mesh_tree_holds_one_sibling_build_a_level():
     counts = {}
     for skew in (False, True):
         cfg = GrowConfig(max_depth=depth, max_bin=32, split=SplitParams(),
-                         hist_impl="mixed", shards_may_skew=skew)
+                         hist_impl=hist_impl, shards_may_skew=skew)
         jaxpr = jax.make_jaxpr(
             lambda b, g, c: build_tree(b, g, c, cfg))(bins, gh, jnp.asarray(cuts))
         counts[skew] = _count_primitives(jaxpr.jaxpr, {})
     assert counts[True].get("cond", 0) == counts[False].get("cond", 0) == 0
     assert counts[True]["dot_general"] == counts[False]["dot_general"]
     assert counts[True]["scan"] == counts[False]["scan"]
+    loops = depth - 1 if hist_impl == "partition" else 0
     assert (counts[True].get("while", 0)
-            == counts[False].get("while", 0) + depth - 1)
+            == counts[False].get("while", 0) + loops)

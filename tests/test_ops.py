@@ -308,7 +308,8 @@ def test_sibling_subtraction_matches_direct_build():
     bins = binning.bin_matrix_np(x, cuts, max_bin=32)
     gh = jnp.asarray(np.stack([g, h], 1))
     outs = {}
-    for impl in ("scatter", "mixed"):
+    impls = ("scatter", "mixed", "partition")
+    for impl in impls:
         for sib in (True, False):
             cfg = GrowConfig(max_depth=6, max_bin=32,
                              split=SplitParams(learning_rate=1.0),
@@ -316,7 +317,7 @@ def test_sibling_subtraction_matches_direct_build():
             tree, rv = build_tree(jnp.asarray(bins), gh, jnp.asarray(cuts), cfg)
             outs[(impl, sib)] = (np.asarray(rv), np.asarray(tree.feature),
                                  np.asarray(tree.value))
-    for impl in ("scatter", "mixed"):
+    for impl in impls:
         np.testing.assert_array_equal(
             outs[(impl, True)][1], outs[(impl, False)][1]
         )
@@ -495,12 +496,15 @@ def test_select_small_child_rows_edges():
     assert (rows[~valid] == n).all()
 
 
-def test_sibling_compaction_overflow_falls_back():
+@pytest.mark.parametrize("hist_impl", ["partition", "mixed"])
+def test_sibling_compaction_overflow_falls_back(hist_impl):
     """The smaller child is chosen from GLOBAL (allreduced) counts; on a
     skewed shard its local rows can exceed the N//2 compaction buffer. Fake
     the count allreduce so the 'global' choice is the locally-BIGGER child:
-    the compacted build must run over a second window of the selection and
-    still grow exactly the tree the direct (no-subtraction) build grows."""
+    the compacted build (``partition``, presorted at every fan-out) must run
+    over a second window of the selection and still grow exactly the tree
+    the direct (no-subtraction) build grows; ``mixed``'s dense build streams
+    every row, compacts nothing, and grows that tree with no window at all."""
     import numpy as np
     import jax.numpy as jnp
     from xgboost_ray_tpu.ops import binning
@@ -528,7 +532,7 @@ def test_sibling_compaction_overflow_falls_back():
     for sib in (True, False):
         cfg = GrowConfig(max_depth=5, max_bin=32,
                          split=SplitParams(learning_rate=1.0),
-                         hist_impl="mixed", sibling_subtract=sib)
+                         hist_impl=hist_impl, sibling_subtract=sib)
         tree, rv = build_tree(jnp.asarray(bins), gh, jnp.asarray(cuts), cfg,
                               allreduce=skew_allreduce)
         outs[sib] = (np.asarray(tree.feature), np.asarray(rv))

@@ -259,11 +259,13 @@ def _row_indexed_eqns(jaxpr, n_rows):
     return found
 
 
-@pytest.mark.parametrize("hist_impl", ["scatter", "mixed"])
+@pytest.mark.parametrize("hist_impl", ["scatter", "mixed", "partition"])
 def test_no_row_keyed_gather_or_scatter_in_the_level_loop(hist_impl):
     """The routing block, the live-row count and the final node sums stream
     the rows: at depth 3 the only gathers / scatters with a row-sized index
-    are the order update's, the smaller-child selection's and the provider's."""
+    are the provider's -- ``scatter``'s scatter-add, ``partition``'s order
+    update, smaller-child selection and block layout, and none at all under
+    ``mixed``, whose dense build streams the rows too."""
     n = 4096
     bins, gh, cuts, fhm, cat, max_bin = _tree_data(categorical=True, missing=True)
     bins, gh = bins[:n], gh[:n]
@@ -279,6 +281,8 @@ def test_no_row_keyed_gather_or_scatter_in_the_level_loop(hist_impl):
     )
     assert {fn for _, fn in dense} <= _MAY_INDEX_BY_ROW, dense
     if hist_impl == "mixed":
+        assert dense == []
+    if hist_impl == "partition":
         assert {"update_partition_order", "select_small_child_rows"} <= {
             fn for _, fn in dense
         }

@@ -65,6 +65,7 @@ from xgboost_ray_tpu.ops.grow import (
     sample_feature_mask,
 )
 from xgboost_ray_tpu.ops.provider import (
+    WIDEST_BUILD_NODES,
     FeatureShard,
     default_hist_impl,
     resolve_hist_provider,
@@ -2222,11 +2223,13 @@ class TpuEngine:
         round programs returned it: ``collectives_per_round`` (the tree
         path's collectives, counted where the bytes are), and over every
         round dispatched since the last reset and every shard,
-        ``hist_sibling_builds`` (compacted sibling-subtraction builds in
-        the skew-tolerant window loop) and ``hist_skew_fallback_builds``
-        (those that needed more than one window because the shard's rows of
-        the chosen children overflowed its ``N // 2`` buffer). All 0 on a
-        one-device world, whose programs have no wire and no such loop.
+        ``hist_sibling_builds`` (a shard's sibling-subtraction builds, one
+        a level >= 1) and ``hist_skew_fallback_builds`` (those among them
+        that were compacted builds in the skew-tolerant window loop and
+        needed more than one window because the shard's rows of the chosen
+        children overflowed its ``N // 2`` buffer; a dense build cannot).
+        All 0 on a one-device world, whose programs have no wire and whose
+        shard cannot skew.
         Reads this process's shards of the one running sum (a small
         device->host read each, after training only) and, on a
         multi-process world, allgathers the processes' sums: every process
@@ -2428,7 +2431,7 @@ class TpuEngine:
         prov = resolve_hist_provider(
             base_impl, self.cfg.hist_precision, self.cfg.hist_chunk
         )
-        if prov.wants_order:
+        if prov.uses_order(WIDEST_BUILD_NODES):
             if self.params.hist_impl == "auto":
                 # auto resolves per backend; under lanes the order-free
                 # scatter build is the auto choice

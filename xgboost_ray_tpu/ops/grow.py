@@ -18,19 +18,25 @@ The histogram allreduce point is the ``allreduce`` callable: identity on a
 single device, ``lax.psum(..., "actors")`` inside the shard_map round step —
 this is the exact spot where the reference relied on Rabit (SURVEY §5.8).
 
-Histogram impl choice (and the fate of the hand-written Pallas kernel):
-``scatter`` (segment-sum), ``onehot`` (one-hot matmul on the MXU), and
-``partition``/``mixed`` (node-contiguous presorted blocks; ``mixed`` =
-onehot at tiny fan-out, presorted beyond) are all XLA formulations.
-A hand-written Pallas presorted-histogram kernel shipped r2-r4 behind an
-opt-in flag and was DELETED in r5: the one on-chip v5e session (pre-PR-1,
-2026-07-29) showed it ~1.4x SLOWER per level than the identical-layout
-XLA einsum — the blocked one-hot matmul IS the idiomatic MXU formulation,
-XLA already fuses/tiles it, and the kernel's only remaining niche
-(high-bin scatter-bound shapes) is served by ``partition`` without custom
-code. Verdict: a kernel that loses to the compiler on its own target
-hardware is dead weight; the learning stays here (ROADMAP P2 records what
-a revisit would need).
+Histogram impl choice: ``scatter`` (segment-sum), ``onehot`` (the dense
+one-hot matmul on the MXU at every fan-out), ``partition`` (node-contiguous
+presorted blocks at every fan-out) and ``mixed`` are all XLA formulations.
+``mixed``, the chip's default, is ``onehot``'s build while a level's
+``2 * build_nodes`` right-hand-side columns are at most 1,024 (every level
+of ``max_depth <= 11`` under sibling subtraction) and the presorted blocks
+beyond; a tree whose every level is under that crossover keeps no row order,
+compacts no smaller child and copies no row into blocks (PR 30: on a v5e at
+11M rows a level then costs 66-73 ms up to 32 columns and 144 ms at 128,
+against 300-390 ms plus a 170 ms order update before; PERF.md §5, §6).
+
+The hand-written Pallas presorted-histogram kernel of r2-r4 was deleted in
+r5: the one on-chip session of its time (pre-PR-1, 2026-07-29) showed it
+~1.4x slower per level than the identical-layout XLA einsum. That verdict
+was about that kernel against that layout, not about kernels: the ledger has
+since shown the blocked layout itself to be the cost (the row copies and the
+order update were 62% of a round, PR 28), and XLA's dense build still runs
+some 500x over its bytes floor. Whether a kernel that keeps the one-hot in
+VMEM beats it is ROADMAP P2's open question.
 """
 
 import dataclasses
@@ -234,10 +240,12 @@ class GrowConfig:
     # inside this hashable jit-static config.
     cat_features: tuple = ()
     # True when this shard's counts can differ from the allreduced ones
-    # (world size > 1): the compacted sibling build then sits in a loop that
-    # runs it once per N // 2 window of the selection, for selections
-    # overflowing the buffer. Single-shard training sets False — the
-    # selection provably fits, and the build stands alone.
+    # (world size > 1): a COMPACTED sibling build (a level built from the
+    # presorted order) then sits in a loop that runs it once per N // 2
+    # window of the selection, for selections overflowing the buffer; a
+    # dense level compacts nothing and is only noted to the mesh's build
+    # count. Single-shard training sets False — the selection provably
+    # fits, and the build stands alone.
     shards_may_skew: bool = True
     # per-feature monotone constraints (len == F, values -1/0/+1) or () —
     # xgboost's monotone_constraints via per-node weight-bound propagation
@@ -349,7 +357,7 @@ def build_tree(
     feat_has_missing: Optional[jnp.ndarray] = None,  # [F] bool, global
     hist_allreduce: Optional[Callable[[jnp.ndarray], jnp.ndarray]] = None,
     ar_counter=None,  # AllreduceBytes: scan-scoped byte accounting, and the
-    #   count of sibling builds that sit in the skew fallback's window loop
+    #   count of a skew-prone shard's sibling builds (and window fallbacks)
     fshard=None,  # ops.provider.FeatureShard on a 2D row x feature mesh
     gh_scale: Optional[jnp.ndarray] = None,  # [2] f32 per-channel scales of a
     #   quantized integer gh buffer (gh_precision; None = f32 legacy path)
@@ -492,9 +500,18 @@ def build_tree(
         ic_used = jnp.zeros((1, num_features), bool)
         ic_has_used = jnp.zeros((1,), bool)
 
-    # partition-based providers keep rows sorted by node across levels with
-    # an O(N) stable segment split (no per-level argsort)
-    track_order = provider.wants_order
+    # providers with a level that builds from presorted blocks keep rows
+    # sorted by node across levels with an O(N) stable segment split (no
+    # per-level argsort); a tree whose every level takes a dense build (on
+    # the chip's ``mixed``: max_depth <= 11) tracks no order at all
+    def _build_nodes(d):
+        # node slots of level d's build: the smaller children only (one a
+        # parent) under sibling subtraction
+        return (1 << d) // 2 if cfg.sibling_subtract and d > 0 else 1 << d
+
+    track_order = any(
+        provider.uses_order(_build_nodes(d)) for d in range(cfg.max_depth)
+    )
     order = counts = None
     if track_order:
         order = jnp.arange(n, dtype=jnp.int32)
@@ -512,8 +529,15 @@ def build_tree(
             # threshold levels take the exact f32 psum, and then node totals
             # also come from the histogram readout — bit-identical to
             # hist_quant="none", so small problems are a provable no-op.
-            sib = cfg.sibling_subtract and d > 0
-            build_nodes = (n_nodes // 2) if sib else n_nodes
+            build_nodes = _build_nodes(d)
+            # does this level's build read the presorted order, or stream
+            # every row once (counted as the level is traced)?
+            from_order = provider.uses_order(build_nodes)
+            dense_levels = get_registry().counter("rxgb_hist_dense_levels_total")
+            presorted_levels = get_registry().counter(
+                "rxgb_hist_presorted_levels_total"
+            )
+            (presorted_levels if from_order else dense_levels).inc()
             exact_totals = (
                 cfg.hist_quant != "none"
                 and build_nodes * num_features * nbt * 2 * 4
@@ -587,7 +611,7 @@ def build_tree(
                     child_counts = allreduce(live_rows)
                 # [n_par] True when the right child is the (weakly) smaller one
                 small_is_right = child_counts[1::2] <= child_counts[0::2]
-                if track_order:
+                if from_order:
                     # compact the smaller child's rows into an [N // 2] buffer so
                     # every impl processes HALF the rows (vs just zeroing gh).
                     def _compacted(window=None):
@@ -633,6 +657,11 @@ def build_tree(
                     else:
                         hist_small = hist_ar(_compacted())
                 else:
+                    # every row streams through the build once, the bigger
+                    # child's with zeroed gh: no compaction, so no shard's
+                    # selection can overflow and no build needs a window
+                    if cfg.shards_may_skew and ar_counter is not None:
+                        ar_counter.note_sibling_build(True)
                     parent_pos = pos >> 1
                     is_right = (pos & 1).astype(bool)
                     sel = (
@@ -651,7 +680,8 @@ def build_tree(
                         (n_nodes,) + hist_small.shape[1:]
                     )
             else:
-                hist = hist_ar(_build(gh, pos, order, counts, n_nodes))
+                layout = (order, counts) if from_order else (None, None)
+                hist = hist_ar(_build(gh, pos, *layout, n_nodes))
             prev_hist = hist
             # [n_nodes, 2]: feature 0's buckets cover every row. Under
             # hist_precision="fast" these totals carry the regular bins' bf16

@@ -12,11 +12,15 @@ Two implementations:
 
 * ``hist_scatter`` — one flat XLA scatter-add. Correct everywhere (CPU tests,
   TPU), shape-static, reasonable on TPU for moderate fan-out.
-* ``hist_onehot`` — row-chunked one-hot × (grad,hess) matmuls that run on the
-  MXU; scan over features and row chunks keeps peak VMEM bounded. Preferred
-  on TPU for large rows×bins products.
+* ``hist_onehot`` — the dense MXU build: row-chunked ``onehot(bins)ᵀ @
+  (gh ⊗ onehot(node))`` matmuls, one pass over all rows at every fan-out, no
+  row order; the scan over row chunks and feature tiles bounds the one-hot
+  transient. The TPU default while a level's columns fit the MXU.
+* ``hist_partition`` / ``hist_partition_presorted`` — node-uniform row blocks
+  from a (maintained) stable order; FLOPs independent of the fan-out, paid
+  for in row-indexed data movement. The TPU path past the crossover.
 
-Selection happens in the trainer via params ("tpu_hist_impl").
+Selection happens through ``ops/provider.py`` (params ``hist_impl``).
 """
 
 import functools
@@ -122,8 +126,9 @@ class AllreduceBytes:
     Beside the bytes it counts what else only a mesh has, at the same call
     sites: ``calls``, the collectives a round (one per recorded op, a
     multi-hop ring once per hop; 0 on a 1-device axis, where there is no
-    wire), and the sibling-subtraction builds that sit in a window loop
-    because a shard's rows may skew (``note_sibling_build``). They leave the
+    wire), and the sibling-subtraction builds of a shard whose rows may skew
+    with those among them that needed a further window
+    (``note_sibling_build``). They leave the
     round program through ``mesh_stats``, which is ``None`` -- no output at
     all -- where the round traced no collective and no such build, so a
     one-device program is the program it was."""
@@ -163,11 +168,13 @@ class AllreduceBytes:
         self._add(self._nbytes(arr) * int(hops), calls=int(hops))
 
     def note_sibling_build(self, fits) -> None:
-        """One compacted sibling build in a skew-tolerant loop: ``fits`` is
-        this shard's traced predicate "one N // 2 window holds my rows of
-        the chosen children", and ``~fits`` a build that fell back to
-        further windows (up to twice the rows, while the other shards wait
-        at the level's psum)."""
+        """One sibling build on a shard whose rows may skew. ``fits`` is the
+        shard's predicate "this build needed no further window": traced for
+        a compacted build in the skew-tolerant loop ("one N // 2 window
+        holds my rows of the chosen children"; ``~fits`` is a build that
+        fell back to further windows, up to twice the rows, while the other
+        shards wait at the level's psum), plain ``True`` for a dense build,
+        which streams every row, compacts nothing and cannot overflow."""
         self.sibling_builds += self._mult
         self.fallback_builds = self.fallback_builds + (
             jnp.logical_not(fits).astype(jnp.int32) * self._mult
@@ -482,6 +489,33 @@ def _chunk_node_sums(ghk: jnp.ndarray, pk: jnp.ndarray, n_nodes: int):
     return jnp.matmul(oh_node.T, ghk, precision=jax.lax.Precision.HIGHEST)
 
 
+def _for_row_chunks(step, init, chunk: int, pos, *rows):
+    """``init`` carried through ``step(carry, pos_window, *row_windows)`` over
+    ``chunk``-row windows of ``pos`` and the row-aligned arrays ``rows``.
+    Each window is read in place by a dynamic slice: no padded copy and no
+    ``[n_chunks, chunk, ...]`` reshape of a row-extent array, which under the
+    chip's tiled layouts is no bitcast -- an 11M-row build's 1.3 GB of
+    transients and all but two of the 213 s it took to compile went into
+    that reshape (PERF.md §6, PR 30). The last window is clamped onto the
+    arrays' end; the rows it shares with the window before come with
+    ``pos`` -1, the callers' "in no node"."""
+    n = pos.shape[0]
+    if n == 0:
+        return init
+    chunk = min(chunk, n)
+
+    def body(i, carry):
+        start = jnp.minimum(i * chunk, n - chunk)
+        pk, *windows = (
+            jax.lax.dynamic_slice_in_dim(r, start, chunk, axis=0)
+            for r in (pos, *rows)
+        )
+        fresh = start + jnp.arange(chunk, dtype=jnp.int32) >= i * chunk
+        return step(carry, jnp.where(fresh, pk, -1), *windows)
+
+    return jax.lax.fori_loop(0, -(-n // chunk), body, init)
+
+
 def hist_scatter(
     bins: jnp.ndarray,  # [N, F] integer bins in 0..n_bins (n_bins == missing)
     gh: jnp.ndarray,  # [N, 2] float32 (grad, hess); padding rows must be 0
@@ -504,6 +538,12 @@ def hist_scatter(
     return out.reshape(n_nodes, num_features, n_bins_total, 2)
 
 
+# most feature columns per sequential matmul step of ``hist_onehot``: a
+# [7 x 256 bins, 8192 rows] one-hot is the tile a v5e builds fastest (PERF.md
+# §6, PR 30); tiles are sized evenly so that 28 features make four of 7
+_ONEHOT_FTILE_MAX = 8
+
+
 def hist_onehot(
     bins: jnp.ndarray,
     gh: jnp.ndarray,
@@ -513,91 +553,97 @@ def hist_onehot(
     chunk: int = 8192,
     precision: str = "highest",
 ) -> jnp.ndarray:
-    """MXU-friendly histogram: per feature, hist = onehot(node*bins)ᵀ @ gh.
+    """Dense, order-free MXU histogram: one pass over the rows whatever the
+    fan-out, ``hist[f, b, (node, c)] = onehot(bins[:, f])ᵀ @ (gh ⊗ onehot(pos))``.
 
-    Scans row chunks (outer) and features (inner); each inner step builds a
-    [chunk, n_nodes*n_bins] one-hot over the REGULAR bins (missing rows get an
-    all-zero one-hot and are reconstructed by subtraction, see
-    ``_append_missing``) and contracts it against the chunk's [chunk, 2]
-    grad/hess — a matmul XLA tiles onto the MXU. Padding rows have gh == 0 so
-    over-padding of the last chunk is harmless.
+    Loops over row chunks (outer, ``_for_row_chunks``) and feature tiles
+    (inner). Each inner step builds the ``[ftile * n_bins, chunk]`` one-hot of
+    the REGULAR bins only -- the same at every level; missing rows match no
+    bin and are reconstructed by subtraction, see ``_append_missing`` -- and
+    contracts it over the rows against the chunk's ``[chunk, 2 * n_nodes]``
+    right-hand side, where a row's (grad, hess) sits in its node's two
+    columns. The node therefore costs matmul columns, not one-hot width:
+    compares and one-hot traffic do not grow with ``n_nodes``. The one-hot
+    has the bins leading and the rows along the minor axis: the chip keeps
+    ``bins`` feature-major, so a chunk's ``[F, chunk]`` view is free, the
+    compare broadcasts along the major axis, and the contraction is the
+    matmul's natural ``(M, K) @ (K, N)`` (3x faster on a v5e than the
+    ``[chunk, ftile * n_bins]`` orientation, PERF.md §6, PR 30).
+    Rows whose ``pos`` lies outside ``[0, n_nodes)`` and rows whose gh the
+    caller zeroed (padding, the bigger sibling) add nothing.
     """
     n, num_features = bins.shape
     nb_reg = n_bins_total - 1  # regular bins; bucket nb_reg == missing
-    nb = n_nodes * nb_reg
+    width = 2 * n_nodes
     prec = _einsum_precision(precision)
-    n_chunks = -(-n // chunk)
-    pad = n_chunks * chunk - n
-    b = bins  # keep the storage dtype (uint8/int16): HBM matters at 11M rows
-    if pad:
-        b = jnp.pad(b, ((0, pad), (0, 0)))
-        gh = jnp.pad(gh, ((0, pad), (0, 0)))
-        pos = jnp.pad(pos, (0, pad))
-    b = b.reshape(n_chunks, chunk, num_features)
-    ghc = gh.reshape(n_chunks, chunk, 2)
-    posc = pos.reshape(n_chunks, chunk)
 
-    # quantized gradients (gh_precision): the one-hot and gh ride the matmul
+    # quantized gradients (gh_precision): both one-hots and gh ride the matmul
     # in the narrow integer dtype accumulating int32 — exact, and the
     # int8 x int8 -> int32 MXU path on modern hardware. The bf16 "fast" knob
     # is meaningless here (integer accumulation is already the cheap mode).
     int_gh = jnp.issubdtype(gh.dtype, jnp.integer)
     acc_dt = jnp.int32 if int_gh else jnp.float32
-    # fast mode: materialize the one-hot (the HBM-bound operand) in bf16 —
-    # exact for 0/1 values, halves the traffic; gh rounds to bf16 (~0.2%)
+    # fast mode: the one-hot (the big operand) in bf16 — exact for 0/1
+    # values, halves the traffic; gh rounds to bf16 (~0.2%) once, and its
+    # product with the node's 0/1 is exact
     if int_gh:
         oh_dtype = gh.dtype
     else:
         oh_dtype = jnp.bfloat16 if precision == "fast" else jnp.float32
 
-    # tile features so each sequential step does one WIDE dot — the scan/fori
-    # step count, not FLOPs or HBM, bounds this path on TPU (measured v5e)
-    ftile = min(4, num_features)
-    n_ftiles = -(-num_features // ftile)
+    n_ftiles = -(-num_features // _ONEHOT_FTILE_MAX)
+    ftile = -(-num_features // n_ftiles)
     f_pad = n_ftiles * ftile - num_features
+    bin_ids = jnp.arange(nb_reg, dtype=jnp.int32)
+    node_of_col = jnp.arange(width, dtype=jnp.int32) // 2
+    col_is_hess = (jnp.arange(width, dtype=jnp.int32) % 2).astype(bool)
 
-    def chunk_step(carry, args):
-        acc, tot = carry
-        bc, ghk, pk = args  # [chunk, F], [chunk, 2], [chunk]
-        bc = bc.astype(jnp.int32)  # per-chunk transient upcast
+    def chunk_step(carry, pk, bc, ghk):
+        acc, tot = carry  # pk [rows], bc [rows, F] in the storage dtype, ghk [rows, 2]
+        rows = pk.shape[0]
+        bct = bc.T.astype(jnp.int32)  # [F, rows]: per-chunk transient upcast
         if f_pad:
-            # pad with missing-valued columns -> all-zero one-hot rows
-            bc = jnp.pad(bc, ((0, 0), (0, f_pad)), constant_values=nb_reg)
-        base = pk * nb_reg  # [chunk]
-        ghk_c = ghk.astype(oh_dtype)
+            # pad with missing-valued features -> all-zero one-hot rows
+            bct = jnp.pad(bct, ((0, f_pad), (0, 0)), constant_values=nb_reg)
+        # the node rides the right-hand side: column 2 * node + c holds a
+        # row's g (c = 0) or h (c = 1) where the row sits in that node
+        ghc = ghk.astype(oh_dtype)
+        of_col = jnp.where(col_is_hess[None, :], ghc[:, 1:2], ghc[:, 0:1])
+        rhs = jnp.where(
+            pk[:, None] == node_of_col[None, :], of_col, jnp.zeros((), oh_dtype)
+        )
 
         def ftile_step(t, acc):
-            cols = jax.lax.dynamic_slice_in_dim(bc, t * ftile, ftile, axis=1)
-            # missing rows -> index -1 -> all-zero one-hot row
-            idx = jnp.where(cols >= nb_reg, -1, base[:, None] + cols)
-            oh = jax.nn.one_hot(idx, nb, dtype=oh_dtype)  # [chunk, ftile, nb]
-            oh = oh.reshape(oh.shape[0], ftile * nb)
+            cols = jax.lax.dynamic_slice_in_dim(bct, t * ftile, ftile, axis=0)
+            # bins == nb_reg (missing) match no regular bin -> zero one-hot
+            oh = (cols[:, None, :] == bin_ids[None, :, None]).astype(oh_dtype)
             contrib = jax.lax.dot_general(
-                oh, ghk_c, (((0,), (0,)), ((), ())),
+                oh.reshape(ftile * nb_reg, rows), rhs, (((1,), (0,)), ((), ())),
                 precision=prec, preferred_element_type=acc_dt,
-            )  # [ftile*nb, 2] (MXU, f32 — or exact int32 — accumulate)
+            )  # [ftile*nb_reg, 2*n_nodes] (MXU, f32 — or exact int32 — accumulate)
             return jax.lax.dynamic_update_slice_in_dim(
                 acc,
                 jax.lax.dynamic_slice_in_dim(acc, t * ftile, ftile, axis=0)
-                + contrib.reshape(ftile, nb, 2),
+                + contrib.reshape(ftile, nb_reg, width),
                 t * ftile,
                 axis=0,
             )
 
         acc = jax.lax.fori_loop(0, n_ftiles, ftile_step, acc)
-        # node totals ride the scan as one extra tiny matmul per chunk
+        # node totals ride the loop as one extra tiny matmul per chunk
         tot = tot + _chunk_node_sums(ghk, pk, n_nodes)
-        return (acc, tot), None
+        return acc, tot
 
     acc0 = (
-        jnp.zeros((n_ftiles * ftile, nb, 2), acc_dt),
+        jnp.zeros((n_ftiles * ftile, nb_reg, width), acc_dt),
         jnp.zeros((n_nodes, 2), acc_dt),
     )
-    (acc, node_tot), _ = jax.lax.scan(chunk_step, acc0, (b, ghc, posc))
-    # [F, n_nodes*nb_reg, 2] -> [n_nodes, F, nb_reg, 2]
+    # bins stay in the storage dtype (uint8/int16) until a chunk is read
+    acc, node_tot = _for_row_chunks(chunk_step, acc0, chunk, pos, bins, gh)
+    # [F, nb_reg, n_nodes * 2] -> [n_nodes, F, nb_reg, 2]
     hist_reg = acc[:num_features].reshape(
-        num_features, n_nodes, nb_reg, 2
-    ).transpose(1, 0, 2, 3)
+        num_features, nb_reg, n_nodes, 2
+    ).transpose(2, 0, 1, 3)
     return _append_missing(hist_reg, node_tot)
 
 
@@ -871,23 +917,10 @@ def node_sums(gh: jnp.ndarray, pos: jnp.ndarray, n_nodes: int) -> jnp.ndarray:
     return out.at[pos].add(gh if gh.dtype == acc else gh.astype(acc))
 
 
-# rows per scan step of the dense node reductions: large enough that the
-# ~340 steps of an 11M-row shard cost under 3 ms of step overhead on a v5e,
-# small enough that a step's [n_nodes, chunk] one-hot stays a transient
+# rows per step of the dense node reductions: large enough that the ~340
+# steps of an 11M-row shard cost under 3 ms of step overhead on a v5e, small
+# enough that a step's [n_nodes, chunk] one-hot stays a transient
 _NODE_CHUNK = 32768
-
-
-def _scan_row_chunks(step, init, pos: jnp.ndarray, gh=None):
-    """``init`` carried through ``step`` over ``_NODE_CHUNK``-row chunks of
-    ``pos`` (and ``gh``); the tail is padded with rows at slot -1."""
-    n = pos.shape[0]
-    chunk = min(_NODE_CHUNK, max(n, 1))
-    n_chunks = -(-n // chunk)
-    pad = n_chunks * chunk - n
-    xs = (jnp.pad(pos, (0, pad), constant_values=-1).reshape(n_chunks, chunk),)
-    if gh is not None:
-        xs += (jnp.pad(gh, ((0, pad), (0, 0))).reshape(n_chunks, chunk, 2),)
-    return jax.lax.scan(lambda c, x: (step(c, *x), None), init, xs)[0]
 
 
 def node_sums_dense(gh: jnp.ndarray, pos: jnp.ndarray, n_nodes: int) -> jnp.ndarray:
@@ -896,9 +929,9 @@ def node_sums_dense(gh: jnp.ndarray, pos: jnp.ndarray, n_nodes: int) -> jnp.ndar
     integer ``gh`` sums stay exact int32; f32 sums agree with ``node_sums``
     to reassociation. Rows with ``pos`` outside ``[0, n_nodes)`` (the
     callers' -1 for finished rows) add to no node."""
-    return _scan_row_chunks(
+    return _for_row_chunks(
         lambda tot, pk, ghk: tot + _chunk_node_sums(ghk, pk, n_nodes),
-        jnp.zeros((n_nodes, 2), _acc_dtype(gh)), pos, gh,
+        jnp.zeros((n_nodes, 2), _acc_dtype(gh)), _NODE_CHUNK, pos, gh,
     )
 
 
@@ -906,9 +939,9 @@ def node_counts_dense(pos: jnp.ndarray, n_nodes: int) -> jnp.ndarray:
     """Rows per node slot, exact int32 [n_nodes], by the same chunked
     one-hot in place of ``zeros.at[pos].add(1)``."""
     slots = jnp.arange(n_nodes, dtype=pos.dtype)[:, None]
-    return _scan_row_chunks(
+    return _for_row_chunks(
         lambda cnt, pk: cnt + jnp.sum(slots == pk[None, :], axis=1, dtype=jnp.int32),
-        jnp.zeros((n_nodes,), jnp.int32), pos,
+        jnp.zeros((n_nodes,), jnp.int32), _NODE_CHUNK, pos,
     )
 
 

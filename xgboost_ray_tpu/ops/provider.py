@@ -60,6 +60,12 @@ def _gather_rows(bins, gh, rows_sel):
     return bins[rows_c], gh[rows_c] * ok
 
 
+#: the widest build a grower can ask for: the deepest level of the deepest
+#: tree params.py admits (max_depth 14). The K-lane checks ask
+#: ``uses_order`` with it to learn whether a provider ever reads an order.
+WIDEST_BUILD_NODES = 1 << 13
+
+
 @dataclasses.dataclass(frozen=True)
 class HistogramProvider:
     """One histogram build strategy behind a uniform interface.
@@ -70,7 +76,8 @@ class HistogramProvider:
 
     * ``pos`` — per-row (or per-selected-slot) node index, always present;
     * ``order``/``counts`` — rows stably sorted by node + per-node counts,
-      maintained by the grower iff :attr:`wants_order` is True;
+      maintained by the grower iff some level of the tree builds from them,
+      and handed to the builds for which :meth:`uses_order` is True;
     * ``rows_sel`` — a compacted row-id view (sibling subtraction's
       smaller-child selection or a sampling selection), sentinel ``n`` for
       unused slots. Presorted builds consume it directly as the row order;
@@ -82,9 +89,13 @@ class HistogramProvider:
 
     #: registry key (subclasses override)
     name = "base"
-    #: True when the grower should maintain the presorted order/counts
-    #: layout across levels (the O(N) stable segment split)
-    wants_order = False
+
+    def uses_order(self, n_nodes: int) -> bool:
+        """Does a build over ``n_nodes`` node slots read the presorted
+        order/counts layout (the O(N) stable segment split the grower then
+        keeps across levels)? The grower asks once a level with the fan-out
+        it is about to build, and tracks an order iff any level says yes."""
+        return False
 
     def build(self, bins, gh, pos, n_nodes, n_bins_total, *, order=None,
               counts=None, rows_sel=None):
@@ -121,7 +132,9 @@ class PartitionHistogram(HistogramProvider):
     """Node-contiguous presorted blocks: FLOPs independent of node fan-out."""
 
     name = "partition"
-    wants_order = True
+
+    def uses_order(self, n_nodes: int) -> bool:
+        return True
 
     def build(self, bins, gh, pos, n_nodes, n_bins_total, *, order=None,
               counts=None, rows_sel=None):
@@ -136,32 +149,39 @@ class PartitionHistogram(HistogramProvider):
         )
 
 
+#: widest right-hand side, in matmul columns (2 per node slot), that
+#: ``mixed`` gives to the dense build; beyond it the presorted blocks. Set
+#: from the v5e at 11M x 28 x 256 (PERF.md §6, PR 30): the dense build costs
+#: 469 / 921 / 2,010 / 3,980 ms at 256 / 512 / 1,024 / 2,048 node slots; the
+#: compacted presorted build costs 765 ms at 256 slots and no less with more
+#: blocks, and needs an order update of 185 ms or more a level. So dense wins
+#: through 512 slots (921 < 765 + 185); at 1,024 slots the presorted side was
+#: not measured (158 s of compile a point) and keeps the level.
+DENSE_MAX_COLUMNS = 1024
+
+
 @dataclasses.dataclass(frozen=True)
 class MixedHistogram(HistogramProvider):
-    """One-hot at tiny node fan-out, presorted blocks beyond (measured v5e
-    crossover; see ops/grow.py module docstring)."""
+    """The chip's default: the dense one-hot build (``hist_onehot``: one pass
+    over all rows, no row order, the node on the matmul's right-hand side)
+    while a level's ``2 * n_nodes`` columns fit ``DENSE_MAX_COLUMNS``, the
+    presorted node-uniform blocks beyond. Under sibling subtraction every
+    level of a tree of ``max_depth <= 11`` is under the crossover, and such
+    a tree keeps no order at all (``build_tree`` asks :meth:`uses_order`
+    with each level's fan-out)."""
 
     name = "mixed"
-    wants_order = True
+
+    def uses_order(self, n_nodes: int) -> bool:
+        return 2 * n_nodes > DENSE_MAX_COLUMNS
 
     def build(self, bins, gh, pos, n_nodes, n_bins_total, *, order=None,
               counts=None, rows_sel=None):
-        order_in = rows_sel if rows_sel is not None else order
-        if order_in is not None:
-            if n_nodes <= 2:
-                bins_g, gh_g = _gather_rows(bins, gh, rows_sel)
-                return hist_onehot(bins_g, gh_g, pos, n_nodes, n_bins_total,
-                                   chunk=self.chunk,
-                                   precision=self.precision)
-            return hist_partition_presorted(
-                bins, gh, order_in, counts, n_nodes, n_bins_total,
-                precision=self.precision,
-            )
-        if n_nodes <= 4:
-            return hist_onehot(bins, gh, pos, n_nodes, n_bins_total,
-                               chunk=self.chunk, precision=self.precision)
-        return hist_partition(bins, gh, pos, n_nodes, n_bins_total,
-                              precision=self.precision)
+        form = PartitionHistogram if self.uses_order(n_nodes) else OnehotHistogram
+        return form(self.precision, self.chunk).build(
+            bins, gh, pos, n_nodes, n_bins_total,
+            order=order, counts=counts, rows_sel=rows_sel,
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,20 +198,20 @@ class VmappedKProvider(HistogramProvider):
     subclass here and every grower picks it up through ``cfg.hist_provider``
     with zero grower changes, exactly like any other ``hist_impl``.
 
-    ``base`` must name a gather-based provider (``wants_order`` False):
-    the presorted-partition layouts maintain ONE row order per tree, but
-    vmapped lanes sample and route rows independently, so a shared order
-    table would be wrong for every lane but one.
+    ``base`` must name a provider that reads no order at any fan-out
+    (``uses_order(WIDEST_BUILD_NODES)`` False): the presorted-partition
+    layouts maintain ONE row order per tree, but vmapped lanes sample and
+    route rows independently, so a shared order table would be wrong for
+    every lane but one.
     """
 
     base: str = "scatter"
 
     name = "vmapped_k"
-    wants_order = False
 
     def delegate(self) -> HistogramProvider:
         prov = resolve_hist_provider(self.base, self.precision, self.chunk)
-        if prov.wants_order:
+        if prov.uses_order(WIDEST_BUILD_NODES):
             raise NotImplementedError(
                 f"hist_impl {self.base!r} maintains a presorted row order "
                 "and cannot back the vmapped-K build (per-lane row "
@@ -255,8 +275,8 @@ def available_hist_impls() -> Tuple[str, ...]:
 
 def default_hist_impl() -> str:
     """Backend policy behind ``hist_impl='auto'``: scatter on CPU (parity
-    tests), mixed on accelerators (one-hot MXU matmuls while the node
-    fan-out is small, node-contiguous partitioning beyond)."""
+    tests), mixed on accelerators (the dense one-hot MXU build while the
+    level's columns fit the MXU, node-contiguous partitioning beyond)."""
     return "scatter" if jax.default_backend() == "cpu" else "mixed"
 
 
