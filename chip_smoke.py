@@ -48,7 +48,7 @@ N_FEATURES = 28
 ROWS_PER_DEVICE = 1_000_000
 PREDICT_ROWS = 100_000
 #: what hist_impl / hist_precision "auto" must resolve to, by platform
-EXPECTED_AUTO = {"tpu": ("mixed", "fast"), "cpu": ("scatter", "highest")}
+EXPECTED_AUTO = {"tpu": ("onehot", "fast"), "cpu": ("scatter", "highest")}
 
 # Tolerances. Two differently-compiled programs over one forest may sum the
 # trees in another order, so probabilities (in [0, 1]) are compared to an
@@ -319,14 +319,13 @@ class Smoke:
         return {"max_abs_diff": diffs, "recompile_count": after}
 
     def step_histogram(self):
-        """One level's histogram through the provider the engine resolved on
-        a chip (``mixed``) at ``fast`` and ``highest``, against
-        ``hist_scatter`` in f32 on the same data, on this device."""
+        """One level's histogram by the build the engine resolved on a chip
+        (``onehot``) at ``fast`` and ``highest``, against ``hist_scatter``
+        in f32 on the same data, on this device."""
         import jax
         import jax.numpy as jnp
 
-        from xgboost_ray_tpu.ops.histogram import hist_scatter
-        from xgboost_ray_tpu.ops.provider import resolve_hist_provider
+        from xgboost_ray_tpu.ops.histogram import build_histogram, hist_scatter
 
         n = self.rows_per_device
         nbt = PARAMS["max_bin"] + 1
@@ -339,8 +338,8 @@ class Smoke:
         ).astype(np.float32)
         gh = jnp.asarray(gh_np)
         out = {}
-        # fan-out 2 takes mixed's dense one-hot build (4 matmul columns),
-        # 1,024 (2,048 columns, past the crossover) its presorted-blocks build
+        # a narrow level (4 matmul columns) and a wide one (2,048 columns:
+        # the deepest level of max_depth 12 under sibling subtraction)
         for n_nodes in (2, 1024):
             pos = jnp.asarray(rng.randint(0, n_nodes, n).astype(np.int32))
             scatter = jax.jit(
@@ -352,18 +351,17 @@ class Smoke:
             mass[:, :, -1, :] = mass.sum(axis=2)
             for precision, rel in (("fast", HIST_FAST_REL),
                                    ("highest", HIST_HIGHEST_REL)):
-                prov = resolve_hist_provider("mixed", precision)
                 got = np.asarray(jax.jit(
-                    lambda b, g, p, pr=prov, nn=n_nodes: pr.build(
-                        b, g, p, nn, nbt)
+                    lambda b, g, p, pr=precision, nn=n_nodes: build_histogram(
+                        b, g, p, nn, nbt, impl="onehot", precision=pr)
                 )(bins, gh, pos))
                 _check(got.shape == ref.shape == (n_nodes, N_FEATURES, nbt, 2)
                        and np.all(np.isfinite(got)),
-                       f"mixed/{precision} histogram shape {got.shape}")
+                       f"onehot/{precision} histogram shape {got.shape}")
                 err = np.abs(got - ref)
                 worst = float(np.max(err / np.maximum(mass, 1e-30)))
                 _check(worst <= rel,
-                       f"mixed/{precision} n_nodes={n_nodes}: error is "
+                       f"onehot/{precision} n_nodes={n_nodes}: error is "
                        f"{worst:.3e} of the bucket's |gh| mass, bound "
                        f"{rel:.3e}")
                 out[f"n{n_nodes}_{precision}"] = {

@@ -1,17 +1,14 @@
 """The four-actor data-parallel job (``higgs-d6-dp4``'s deployment) at a
 small size: 40,000 x 28, depth 6, 256 bins, 5 rounds, seeded, over 4 of
-conftest's 8 host devices, with the chip's ``hist_impl`` (``mixed``: at
-this depth the dense build at every level, which streams every row, compacts
-nothing and so has no skew fallback to fire) and with ``partition`` (the
-presorted builds at every fan-out, whose compacted sibling build carries the
-skew fallback's window loop).
+conftest's 8 host devices, with the chip's ``hist_impl`` (``onehot``: the
+dense build, which streams every row, compacts nothing and so has nothing a
+skewed shard could overflow).
 
 Two ways of cutting the same rows into shards: (a) i.i.d. shards (the rows
 as generated, in contiguous blocks) and (b) the rows sorted by feature 0
-first, so that every shard holds one quarter of feature 0's range and a
-split on it sends whole shards to one side: under ``partition`` (case
-``sorted``) the fallback must fire, under ``mixed`` (case ``sorted-dense``)
-the same rows grow the same forest with no fallback at all.
+first (case ``sorted``), so that every shard holds one quarter of feature
+0's range and a split on it sends whole shards to one side: the same checks
+hold, and no sibling build is counted as a fallback.
 
 The 4-device forest is held to the benchmark's plain reference
 (``benchmarks/reference.py``: ``follow`` / ``compare``, which imports nothing
@@ -42,7 +39,7 @@ ROWS, FEATURES, ROUNDS, ACTORS, SEED = 40_000, 28, 5, 4, 2_900_000_029
 PARAMS = {
     "objective": "binary:logistic", "tree_method": "tpu_hist",
     "eval_metric": ["logloss", "error"], "max_depth": 6, "eta": 0.3,
-    "max_bin": 256, "hist_impl": "mixed",
+    "max_bin": 256, "hist_impl": "onehot",
 }
 # Limits at this size, on the CPU (float32 sums, ``hist_precision`` highest).
 # loss / leaf / cover: the program sums 40,000 float32 (g, h) in another
@@ -56,33 +53,32 @@ LIMITS = {"loss": 1e-5, "leaf": 1e-4, "cover": 1e-4, "split": 0.15,
 NBT = 257  # 256 bins and the missing bucket
 
 
-#: case -> hist_impl
-CASES = {"iid": "mixed", "sorted": "partition", "sorted-dense": "mixed"}
+CASES = ("iid", "sorted")
 
 
 def _rows(case):
     x, y = datagen.make(ROWS, FEATURES, SEED, levels=257)
-    if case.startswith("sorted"):
+    if case == "sorted":
         order = np.argsort(x[:, 0], kind="stable")
         x, y = x[order], y[order]
     return x, y
 
 
-def _train(x, y, actors, hist_impl):
+def _train(x, y, actors):
     evals_result, extra = {}, {}
     dtrain = RayDMatrix(x, y, sharding=RayShardingMode.BATCH)
-    bst = train(dict(PARAMS, hist_impl=hist_impl), dtrain, ROUNDS,
+    bst = train(PARAMS, dtrain, ROUNDS,
                 evals=[(dtrain, "train")],
                 evals_result=evals_result, additional_results=extra,
                 ray_params=RayParams(num_actors=actors, max_actor_restarts=1))
     return bst, evals_result, extra
 
 
-@pytest.fixture(scope="module", params=list(CASES))
+@pytest.fixture(scope="module", params=CASES)
 def job(request):
     x, y = _rows(request.param)
-    bst4, evals4, extra4 = _train(x, y, ACTORS, CASES[request.param])
-    bst1, _, extra1 = _train(x, y, 1, CASES[request.param])
+    bst4, evals4, extra4 = _train(x, y, ACTORS)
+    bst1, _, extra1 = _train(x, y, 1)
     return {
         "case": request.param, "x": x, "y": y,
         "forest4": reference.forest_arrays(bst4.forest),
@@ -93,12 +89,11 @@ def job(request):
 
 
 def _recount_skew_builds(forest, x, actors):
-    """(fallback builds, sibling builds) by the rule ``build_tree`` states:
+    """(skewed builds, sibling builds) by the rule ``build_tree`` states:
     at every level >= 1, per parent the child with fewer live rows over ALL
-    shards is built (the right one on a tie), and a shard whose own rows of
-    those children (rows parked under a leaf included: they ride down its
-    left edge) exceed half its rows falls back to a second window -- where
-    the build compacts them, i.e. under ``partition``."""
+    shards is built (the right one on a tie); a shard is skewed at a level
+    when its own rows of those children (rows parked under a leaf included:
+    they ride down its left edge) exceed half its rows."""
     shards = np.array_split(np.arange(x.shape[0]), actors)
     depth = PARAMS["max_depth"]
     fallback = sibling = 0
@@ -164,26 +159,16 @@ def test_one_device_world_counts_nothing(job):
 
 def test_skew_fallback_counters_match_a_recount_from_the_forest(job):
     extra = job["extra4"]
-    fallback, sibling = _recount_skew_builds(job["forest4"], job["x"], ACTORS)
+    skewed, sibling = _recount_skew_builds(job["forest4"], job["x"], ACTORS)
     # one build a (round, level >= 1, shard)
     assert sibling == ROUNDS * (PARAMS["max_depth"] - 1) * ACTORS
     assert extra["hist_sibling_builds"] == sibling
-    if job["case"] == "sorted-dense":
-        # the shards are as skewed as in "sorted" (a split on feature 0 sends
-        # whole shards to one side), but the dense build has no buffer to
-        # overflow: every noted sibling build needed no further window
-        assert fallback > 0
-        assert extra["hist_skew_fallback_builds"] == 0
-        return
-    assert extra["hist_skew_fallback_builds"] == fallback
     if job["case"] == "sorted":
-        assert fallback > 0
-    else:
-        # i.i.d. shards: the chosen children hold at most half of all rows
-        # and every shard about its quarter of them, so the buffer fits
-        # unless a level splits nearly all its rows evenly (reads 0 of 100
-        # at this seed; the recount above is what holds the counter)
-        assert fallback <= sibling // 20
+        # a split on feature 0 sends whole shards to one side ...
+        assert skewed > 0
+    # ... but the dense build has no buffer to overflow: every noted sibling
+    # build held its shard's rows in one pass
+    assert extra["hist_skew_fallback_builds"] == 0
 
 
 def test_wire_counters_are_the_shapes_own_arithmetic(job):
@@ -284,48 +269,63 @@ def test_events_and_spans_carry_what_the_mesh_adds(job):
         job["extra4"]["device"]["rows_per_device"].values())
 
 
-def _count_primitives(jaxpr, counts):
+def _walk(jaxpr, scope=""):
+    """(primitive, named scope, operand shapes) of every equation, loop and
+    shard_map bodies included; a body's scopes continue its equation's."""
     import jax
 
     for eqn in jaxpr.eqns:
-        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        here = scope + str(eqn.source_info.name_stack)
+        yield (eqn.primitive.name, here,
+               [getattr(v.aval, "shape", ()) for v in eqn.invars])
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _count_primitives(sub, counts)
-    return counts
+            yield from _walk(sub, here + "/")
 
 
-@pytest.mark.parametrize("hist_impl", ["partition", "mixed"])
-def test_mesh_tree_holds_one_sibling_build_a_level(hist_impl):
-    """The skew fallback is a loop around the ONE compacted build, not a
-    ``cond`` between it and a full-row build: at 11M rows a device a second
-    build a level made the 4-device program 1.15 GB of code, which the chip's
-    host could not compile (PERF.md section 6, PR 29). Same matmuls in the
-    mesh's tree as in the one-device tree, no ``cond``, and under
-    ``partition`` one ``while`` more a level >= 1; ``mixed``'s dense levels
-    need no loop, so its mesh tree has the one-device tree's control flow."""
+@pytest.mark.parametrize("actors", [1, ACTORS])
+@pytest.mark.parametrize("depth", [6, 8])
+def test_mesh_tree_holds_one_sibling_build_a_level(depth, actors):
+    """The fused round program of the benchmark's depths under the chip's
+    build (``onehot``, ``fast``), on one device and as the 4-device mesh's:
+    a level's histogram is ONE dense build. Its only loops are that build's
+    own (row chunks, and feature tiles inside them) and, from level 1 on,
+    the live-row count's: no loop of traced length (the window loop a
+    compacted build needed on a skewed shard), no ``cond`` between two
+    builds (at 11M rows a device a second build a level made the 4-device
+    program 1.15 GB of code, which the chip's host could not compile;
+    PERF.md section 6, PR 29), no sort, and no gather or scatter keyed by
+    the row. The mesh's tree has the one-device tree's control flow."""
+    import re
+
     import jax
-    import jax.numpy as jnp
 
-    from xgboost_ray_tpu.ops import binning
-    from xgboost_ray_tpu.ops.grow import GrowConfig, build_tree
-    from xgboost_ray_tpu.ops.split import SplitParams
+    from xgboost_ray_tpu.engine import TpuEngine
+    from xgboost_ray_tpu.params import parse_params
 
-    rng = np.random.RandomState(3)
-    x = rng.randn(2048, 6).astype(np.float32)
-    cuts = binning.sketch_cuts_np(x, max_bin=32)
-    bins = jnp.asarray(binning.bin_matrix_np(x, cuts, max_bin=32))
-    gh = jnp.asarray(rng.rand(2048, 2).astype(np.float32))
-    depth = 4
-    counts = {}
-    for skew in (False, True):
-        cfg = GrowConfig(max_depth=depth, max_bin=32, split=SplitParams(),
-                         hist_impl=hist_impl, shards_may_skew=skew)
-        jaxpr = jax.make_jaxpr(
-            lambda b, g, c: build_tree(b, g, c, cfg))(bins, gh, jnp.asarray(cuts))
-        counts[skew] = _count_primitives(jaxpr.jaxpr, {})
-    assert counts[True].get("cond", 0) == counts[False].get("cond", 0) == 0
-    assert counts[True]["dot_general"] == counts[False]["dot_general"]
-    assert counts[True]["scan"] == counts[False]["scan"]
-    loops = depth - 1 if hist_impl == "partition" else 0
-    assert (counts[True].get("while", 0)
-            == counts[False].get("while", 0) + loops)
+    rows = 8192
+    x, y = _rows("iid")
+    shards = [{"data": x[idx], "label": y[idx]}
+              for idx in np.array_split(np.arange(rows), actors)]
+    params = dict(PARAMS, max_depth=depth, max_bin=32, hist_impl="onehot",
+                  hist_precision="fast")
+    eng = TpuEngine(shards, parse_params(params), actors,
+                    evals=[(shards, "train")], devices=jax.devices()[:actors])
+    eng.build_programs()
+    eqns = list(_walk(
+        jax.make_jaxpr(eng._scan_fn)(*eng._scan_example_args()).jaxpr))
+    names = {name for name, _, _ in eqns}
+    assert "dot_general" in names and "scan" in names
+    assert not names & {"while", "cond", "sort"}, names
+    per_shard = rows // actors
+    row_keyed = [
+        (name, scope, shapes) for name, scope, shapes in eqns
+        if (name == "gather" or name.startswith("scatter"))
+        and shapes[1] and shapes[1][0] >= per_shard // 2
+    ]
+    assert row_keyed == []
+    loops = {}
+    for name, scope, _ in eqns:
+        level = re.search(r"tree/level(\d+)/hist", scope)
+        if name == "scan" and level:
+            loops[int(level.group(1))] = loops.get(int(level.group(1)), 0) + 1
+    assert loops == {d: 2 if d == 0 else 3 for d in range(depth)}

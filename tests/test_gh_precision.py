@@ -21,14 +21,13 @@ import jax.numpy as jnp
 from xgboost_ray_tpu import progreg
 from xgboost_ray_tpu.engine import TpuEngine
 from xgboost_ray_tpu.ops import sampling
-from xgboost_ray_tpu.ops.histogram import hist_onehot, hist_scatter, node_sums
+from xgboost_ray_tpu.ops.histogram import build_histogram, node_sums
 from xgboost_ray_tpu.ops.objectives import (
     CustomObjective,
     dequantize_gh_sums,
     get_objective,
     quantize_gh,
 )
-from xgboost_ray_tpu.ops.provider import resolve_hist_provider
 from xgboost_ray_tpu.params import parse_params
 
 
@@ -165,9 +164,8 @@ def test_quantize_zero_channel_and_clip_range():
 
 
 def test_int_histogram_builders_match_f32_of_quantized_values():
-    """Every provider accumulates the int buffer EXACTLY: the int32
-    histogram equals the f32 build of the same integer values (cast), for
-    the plain, compacted-selection, and presorted layouts."""
+    """Either build accumulates the int buffer EXACTLY: the int32 histogram
+    equals the f32 build of the same integer values (cast)."""
     rng = np.random.RandomState(1)
     n, F, nbt, nn = 257, 3, 9, 4
     bins = jnp.asarray(rng.randint(0, nbt, size=(n, F)), jnp.uint8)
@@ -175,24 +173,13 @@ def test_int_histogram_builders_match_f32_of_quantized_values():
     pos = jnp.asarray(rng.randint(0, nn, size=(n,)), jnp.int32)
     gh_i = jnp.asarray(q, jnp.int8)
     gh_f = jnp.asarray(q, jnp.float32)
-    for impl in ("scatter", "onehot", "partition", "mixed"):
-        p = resolve_hist_provider(impl, chunk=64)
-        hi = p.build(bins, gh_i, pos, nn, nbt)
-        hf = p.build(bins, gh_f, pos, nn, nbt)
+    for impl in ("scatter", "onehot"):
+        hi = build_histogram(bins, gh_i, pos, nn, nbt, impl=impl, chunk=64)
+        hf = build_histogram(bins, gh_f, pos, nn, nbt, impl=impl, chunk=64)
         assert jnp.issubdtype(hi.dtype, jnp.integer), impl
         np.testing.assert_array_equal(
             np.asarray(hi, np.float32), np.asarray(hf), err_msg=impl
         )
-    # compacted row selection (sentinel slots) stays exact too
-    rows_sel = jnp.asarray(
-        np.concatenate([rng.permutation(n)[: n // 2], [n] * 5]), jnp.int32
-    )
-    pos_sel = jnp.asarray(rng.randint(0, nn, size=(rows_sel.shape[0],)),
-                          jnp.int32)
-    p = resolve_hist_provider("scatter")
-    hi = p.build(bins, gh_i, pos_sel, nn, nbt, rows_sel=rows_sel)
-    hf = p.build(bins, gh_f, pos_sel, nn, nbt, rows_sel=rows_sel)
-    np.testing.assert_array_equal(np.asarray(hi, np.float32), np.asarray(hf))
     ns_i = node_sums(gh_i, pos, nn)
     assert ns_i.dtype == jnp.int32
     np.testing.assert_array_equal(
@@ -317,8 +304,7 @@ def test_float32_default_dedupes_onto_default_program():
      "other_rate": 0.2},
     {"grow_policy": "lossguide", "max_leaves": 8},
     {"hist_quant": "int8", "hist_quant_min_bytes": 0},
-    {"hist_impl": "partition"},
-], ids=["subsample", "goss", "lossguide", "int8wire", "partition"])
+], ids=["subsample", "goss", "lossguide", "int8wire"])
 def test_int8_gh_composes(extra):
     """int8 gh through each composition leg: trains to a sane metric and
     reruns bitwise."""

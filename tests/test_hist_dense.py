@@ -1,22 +1,21 @@
 """The dense, order-free histogram build (``hist_onehot``: the node rides the
-matmul's right-hand side) against ``hist_scatter``, the structural test that
-keeps the presorted order, the compaction and the block copies out of a
-``mixed`` tree of ``max_depth <= 11``, and ``mixed`` forests against
-``scatter``'s on one device and on the 4-device CPU mesh."""
+matmul's right-hand side) against ``hist_scatter`` at every fan-out a tree of
+``max_depth <= 14`` asks for, the structural test that keeps a row order, a
+compaction and block copies out of ``build_tree``, and ``onehot`` forests
+against ``scatter``'s on one device and on the 4-device CPU mesh."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from xgboost_ray_tpu import RayDMatrix, RayParams, obs, train
+from xgboost_ray_tpu import RayDMatrix, RayParams, train
 from xgboost_ray_tpu.ops import binning
 from xgboost_ray_tpu.ops.grow import GrowConfig, build_tree
-from xgboost_ray_tpu.ops.histogram import hist_onehot, hist_scatter
-from xgboost_ray_tpu.ops.provider import (
-    DENSE_MAX_COLUMNS,
-    WIDEST_BUILD_NODES,
-    resolve_hist_provider,
+from xgboost_ray_tpu.ops.histogram import (
+    AllreduceBytes,
+    hist_onehot,
+    hist_scatter,
 )
 from xgboost_ray_tpu.ops.split import SplitParams
 
@@ -46,7 +45,12 @@ def _rows(n_nodes, gh_dtype="float32", n=6000, features=7, seed=0):
     return bins, gh, pos, gh_ref, pos_ref
 
 
-@pytest.mark.parametrize("n_nodes", [1, 2, 16, 64, 256])
+#: node slots of a build: the root's, a shallow tree's, depth 6's and 8's
+#: widest, and the widest of max_depth 12, 13 and 14 under sibling subtraction
+FAN_OUTS = [1, 2, 16, 64, 256, 1024, 2048, 4096]
+
+
+@pytest.mark.parametrize("n_nodes", FAN_OUTS)
 @pytest.mark.parametrize("precision", ["highest", "fast"])
 def test_dense_build_matches_scatter(precision, n_nodes):
     bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes)
@@ -68,7 +72,7 @@ def test_dense_build_matches_scatter(precision, n_nodes):
     assert np.abs(want[:, :, -1, :]).max() > 0  # missing values were there
 
 
-@pytest.mark.parametrize("n_nodes", [1, 2, 16, 64, 256])
+@pytest.mark.parametrize("n_nodes", FAN_OUTS)
 def test_dense_build_is_exact_for_int8_gh(n_nodes):
     bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes, gh_dtype="int8")
     got = jax.jit(lambda b, g, p: hist_onehot(b, g, p, n_nodes, NBT,
@@ -79,20 +83,7 @@ def test_dense_build_is_exact_for_int8_gh(n_nodes):
     np.testing.assert_array_equal(got, want)
 
 
-def test_mixed_picks_the_build_from_the_static_shape():
-    mixed = resolve_hist_provider("mixed")
-    # a build: dense while its 2 columns a node slot stay under the crossover
-    slots = DENSE_MAX_COLUMNS // 2
-    assert [mixed.uses_order(nn) for nn in (1, 2, 64, slots, 2 * slots)] == [
-        False, False, False, False, True]
-    # could it ever (what the K-lane build has to know)
-    assert mixed.uses_order(WIDEST_BUILD_NODES)
-    assert resolve_hist_provider("partition").uses_order(1)
-    for impl in ("scatter", "onehot"):
-        assert not resolve_hist_provider(impl).uses_order(WIDEST_BUILD_NODES)
-
-
-def _tree_inputs(n=4096, features=6, seed=3):
+def _tree_inputs(n=40_000, features=6, seed=3):
     rng = np.random.RandomState(seed)
     x = rng.randn(n, features).astype(np.float32)
     x[rng.rand(n, features) < 0.05] = np.nan
@@ -130,63 +121,31 @@ def _row_extent_eqns(jaxpr, n_rows):
     return found
 
 
-def _trace_tree(depth, hist_impl="mixed", **cfg_kw):
+#: (max_depth, sibling_subtract, shards of the mesh the tree is traced for)
+TREES = [(d, True, shards) for d in (6, 8, 11) for shards in (1, 4)] + [
+    (d, sib, 1) for d in (12, 14) for sib in (True, False)]
+
+
+@pytest.mark.parametrize("depth,sibling_subtract,shards", TREES)
+def test_a_tree_moves_no_row(depth, sibling_subtract, shards):
+    """No order, no compaction, no block copy: the jaxpr of an ``onehot``
+    tree holds no gather, scatter, sort or prefix sum of row extent, at the
+    benchmark's depths and at the deepest ``params.py`` admits (whose widest
+    tables, 2^14 node slots, stay under half the rows here), with and
+    without sibling subtraction, on one device and as a mesh's shard."""
     bins, gh, cuts, fhm = _tree_inputs()
     cfg = GrowConfig(max_depth=depth, max_bin=N_BINS, split=SplitParams(),
-                     hist_impl=hist_impl, hist_precision="fast", **cfg_kw)
-    reg = obs.get_registry()
-    dense = reg.counter("rxgb_hist_dense_levels_total")
-    presorted = reg.counter("rxgb_hist_presorted_levels_total")
-    before = dense.value, presorted.value
+                     hist_impl="onehot", hist_precision="fast",
+                     sibling_subtract=sibling_subtract)
+    counter = AllreduceBytes(shards)
     jaxpr = jax.make_jaxpr(
-        lambda *a: build_tree(a[0], a[1], a[2], cfg, feat_has_missing=a[3])
+        lambda *a: build_tree(a[0], a[1], a[2], cfg, feat_has_missing=a[3],
+                              ar_counter=counter)
     )(bins, gh, cuts, fhm)
-    moved = _row_extent_eqns(jaxpr.jaxpr, bins.shape[0])
-    return moved, dense.value - before[0], presorted.value - before[1]
-
-
-#: the deepest ``mixed`` tree whose every level is dense: under sibling
-#: subtraction its last level builds 2^(depth-2) node slots
-DENSE_DEPTH = (DENSE_MAX_COLUMNS // 2).bit_length() + 1
-
-
-@pytest.mark.parametrize("skew", [False, True])
-@pytest.mark.parametrize("depth", [6, 8, DENSE_DEPTH])
-def test_mixed_tree_under_the_crossover_moves_no_row(depth, skew):
-    """No order, no compaction, no block copy, no window loop: a ``mixed``
-    tree of depth 6, 8 or the deepest under the crossover holds no gather,
-    scatter, sort or prefix sum of row extent, on one device and as a mesh's
-    shard, and every level counts as a dense one."""
-    moved, dense, presorted = _trace_tree(depth, shards_may_skew=skew)
-    assert moved == []
-    assert (dense, presorted) == (depth, 0)
-
-
-def test_mixed_tree_past_the_crossover_keeps_its_presorted_levels():
-    """Two levels deeper than the deepest all-dense tree: the levels up to
-    ``DENSE_MAX_COLUMNS // 2`` node slots take the dense build, the last
-    two the presorted blocks, from an order kept since level 0."""
-    depth = DENSE_DEPTH + 2
-    moved, dense, presorted = _trace_tree(depth)
-    assert (dense, presorted) == (DENSE_DEPTH, 2)
-    by_fn = {}
-    for name, fn in moved:
-        by_fn.setdefault(fn, []).append(name)
-    assert {"update_partition_order", "select_small_child_rows",
-            "presorted_block_layout"} <= set(by_fn)
-    # the order is kept at every level (the last one's update is dead code
-    # that XLA drops), the selection and the blocks are the two deep levels'
-    assert len([n for n in by_fn["update_partition_order"]
-                if n.startswith("scatter")]) == depth
-    assert len([n for n in by_fn["presorted_block_layout"]
-                if n.startswith("scatter")]) == 2
-
-
-def test_partition_stays_presorted_at_every_fan_out():
-    moved, dense, presorted = _trace_tree(4, hist_impl="partition")
-    assert (dense, presorted) == (0, 4)
-    assert {"update_partition_order", "select_small_child_rows",
-            "presorted_block_layout"} <= {fn for _, fn in moved}
+    assert _row_extent_eqns(jaxpr.jaxpr, bins.shape[0]) == []
+    # a mesh's shard notes one sibling build a level >= 1, a lone device none
+    noted = depth - 1 if sibling_subtract and shards > 1 else 0
+    assert counter.sibling_builds == noted
 
 
 def _forest_fields(bst):
@@ -206,8 +165,8 @@ def _higgs_like(n, seed):
 
 
 @pytest.mark.parametrize("actors", [1, 4])
-@pytest.mark.parametrize("depth", [6, 8])
-def test_mixed_forest_has_scatters_splits(depth, actors):
+@pytest.mark.parametrize("depth", [6, 8, 12])
+def test_dense_forest_has_scatters_splits(depth, actors):
     """Three rounds at ``highest``, on one device and over the 4-device mesh.
     The first tree's (g, h) are +-0.5 and 0.25, whose sums are exact in any
     order: its splits are ``scatter``'s at every node. Later trees sum other
@@ -216,7 +175,7 @@ def test_mixed_forest_has_scatters_splits(depth, actors):
     noted sibling builds report no fallback and the wire is ``scatter``'s."""
     x, y = _higgs_like(6000, seed=depth)
     forests, extras, losses = {}, {}, {}
-    for impl in ("scatter", "mixed"):
+    for impl in ("scatter", "onehot"):
         extras[impl], losses[impl] = {}, {}
         dtrain = RayDMatrix(x, y)
         bst = train(
@@ -228,7 +187,7 @@ def test_mixed_forest_has_scatters_splits(depth, actors):
             ray_params=RayParams(num_actors=actors),
         )
         forests[impl] = _forest_fields(bst)
-    got, want = forests["mixed"], forests["scatter"]
+    got, want = forests["onehot"], forests["scatter"]
     for field in ("feature", "split_bin", "default_left", "is_leaf"):
         np.testing.assert_array_equal(got[field][0], want[field][0],
                                       err_msg=field)
@@ -236,10 +195,10 @@ def test_mixed_forest_has_scatters_splits(depth, actors):
                                rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(got["cover"][0], want["cover"][0], rtol=1e-6)
     assert int(want["is_leaf"][0].sum()) > 8
-    np.testing.assert_allclose(losses["mixed"]["train"]["logloss"],
+    np.testing.assert_allclose(losses["onehot"]["train"]["logloss"],
                                losses["scatter"]["train"]["logloss"],
                                rtol=1e-4)
-    extra = extras["mixed"]
+    extra = extras["onehot"]
     if actors == 1:
         assert extra["hist_sibling_builds"] == 0
     else:

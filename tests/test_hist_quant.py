@@ -438,17 +438,15 @@ def test_scan_path_matches_per_round_under_int8():
     )
 
 
-def test_hist_quant_lossguide_and_partition_impls():
-    """The quantized wire plugs into both growers and the partition-order
-    histogram impls."""
+def test_hist_quant_lossguide_and_dense_impl():
+    """The quantized wire plugs into both growers and the dense build."""
     rng = np.random.RandomState(5)
     x = rng.randn(500, 8).astype(np.float32)
     y = (x[:, 2] > 0).astype(np.float32)
     shards = [{"data": x, "label": y}]
     for extra in (
         {"grow_policy": "lossguide", "max_leaves": 8},
-        {"hist_impl": "partition"},
-        {"hist_impl": "mixed"},
+        {"hist_impl": "onehot"},
     ):
         p = dict(_KEYSTONE)
         p.update(extra)

@@ -131,7 +131,7 @@ def test_grow_policy_validation():
     # an explicit non-onehot hist impl must not be silently dropped
     with pytest.raises(NotImplementedError, match="hist_impl"):
         train({"objective": "reg:squarederror", "grow_policy": "lossguide",
-               "hist_impl": "partition"}, RayDMatrix(x, y), 1,
+               "hist_impl": "scatter"}, RayDMatrix(x, y), 1,
               ray_params=RP1)
 
 

@@ -220,63 +220,31 @@ def test_ranking_gradients_point_the_right_way():
     assert np.all(np.asarray(h) > 0)
 
 
-def test_hist_partition_matches_scatter():
-    from xgboost_ray_tpu.ops.histogram import hist_partition
-
-    rng = np.random.RandomState(7)
-    n, f, nb = 700, 6, 8
-    bins = rng.randint(0, nb + 1, size=(n, f)).astype(np.uint8)
-    gh = rng.randn(n, 2).astype(np.float32)
-    for n_nodes in (1, 4, 16):
-        pos = rng.randint(0, n_nodes, size=n).astype(np.int32)
-        ref = np.asarray(
-            hist_scatter(jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(pos),
-                         n_nodes, nb + 1)
-        )
-        out = np.asarray(
-            hist_partition(jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(pos),
-                           n_nodes, nb + 1, block=32, block_chunk=8)
-        )
-        np.testing.assert_allclose(out, ref, atol=1e-4)
-
-
-def test_hist_partition_skewed_nodes():
-    from xgboost_ray_tpu.ops.histogram import hist_partition
-
-    rng = np.random.RandomState(8)
-    n, f, nb, n_nodes = 500, 3, 4, 8
-    bins = rng.randint(0, nb + 1, size=(n, f)).astype(np.uint8)
-    gh = rng.randn(n, 2).astype(np.float32)
-    # extreme skew: almost everything in node 0, some nodes empty
-    pos = np.zeros(n, np.int32)
-    pos[:20] = rng.randint(1, n_nodes, size=20)
-    ref = np.asarray(
-        hist_scatter(jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(pos),
-                     n_nodes, nb + 1)
-    )
-    out = np.asarray(
-        hist_partition(jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(pos),
-                       n_nodes, nb + 1, block=64, block_chunk=4)
-    )
-    np.testing.assert_allclose(out, ref, atol=1e-4)
-
-
-def test_unknown_hist_impl_rejected():
-    """hist_impl='pallas' was REMOVED in r5 (the hand-written kernel lost to
-    the identical-layout XLA einsum on-chip — rationale in ops/grow.py's
-    module docstring); an explicit request must fail loudly at parse time,
-    never silently run a different impl."""
+@pytest.mark.parametrize("name,says", [
+    ("partition", "takes its place"),
+    ("mixed", "takes its place"),
+    ("pallas", "takes its place"),
+    ("bogus", "auto | scatter | onehot"),
+])
+def test_unknown_hist_impl_rejected(name, says):
+    """The builds over node-sorted row blocks (``partition``, ``mixed``, the
+    ``pallas`` kernel before them) lost on the chip and are gone (rationale
+    in ops/grow.py's module docstring); asking for one, or for a name that
+    never was, fails loudly at parse time with what to use, never silently
+    runs another build."""
     from xgboost_ray_tpu.params import parse_params
 
-    with pytest.raises(ValueError, match="Pallas kernel was removed"):
-        parse_params({"hist_impl": "pallas"})
-    with pytest.raises(ValueError, match="Unknown hist_impl"):
-        parse_params({"hist_impl": "bogus"})
+    with pytest.raises(ValueError, match="Unknown hist_impl") as err:
+        parse_params({"hist_impl": name})
+    assert says in str(err.value)
+    assert ("takes its place" in str(err.value)) == (name != "bogus")
+    for ok in ("auto", "scatter", "onehot"):
+        assert parse_params({"hist_impl": ok}).hist_impl == ok
 
 
 def test_build_tree_impls_produce_identical_trees():
-    """scatter / partition (incremental ordering) / mixed must grow the exact
-    same tree — the partition path's O(N) order maintenance is pure layout."""
+    """The scatter-add and the dense MXU build must grow the exact same
+    tree."""
     rng = np.random.RandomState(12)
     x = rng.randn(800, 6).astype(np.float32)
     g = rng.randn(800).astype(np.float32)
@@ -285,16 +253,15 @@ def test_build_tree_impls_produce_identical_trees():
     bins = binning.bin_matrix_np(x, cuts, max_bin=16)
     gh = jnp.asarray(np.stack([g, h], 1))
     outs = {}
-    for impl in ("scatter", "partition", "mixed"):
+    for impl in ("scatter", "onehot"):
         cfg = GrowConfig(max_depth=5, max_bin=16,
                          split=SplitParams(learning_rate=1.0), hist_impl=impl)
         tree, rv = build_tree(jnp.asarray(bins), gh, jnp.asarray(cuts), cfg)
         outs[impl] = (np.asarray(rv), np.asarray(tree.feature),
                       np.asarray(tree.value))
-    for impl in ("partition", "mixed"):
-        np.testing.assert_allclose(outs[impl][0], outs["scatter"][0], atol=1e-4)
-        np.testing.assert_array_equal(outs[impl][1], outs["scatter"][1])
-        np.testing.assert_allclose(outs[impl][2], outs["scatter"][2], atol=1e-4)
+    np.testing.assert_allclose(outs["onehot"][0], outs["scatter"][0], atol=1e-4)
+    np.testing.assert_array_equal(outs["onehot"][1], outs["scatter"][1])
+    np.testing.assert_allclose(outs["onehot"][2], outs["scatter"][2], atol=1e-4)
 
 
 def test_sibling_subtraction_matches_direct_build():
@@ -308,7 +275,7 @@ def test_sibling_subtraction_matches_direct_build():
     bins = binning.bin_matrix_np(x, cuts, max_bin=32)
     gh = jnp.asarray(np.stack([g, h], 1))
     outs = {}
-    impls = ("scatter", "mixed", "partition")
+    impls = ("scatter", "onehot")
     for impl in impls:
         for sib in (True, False):
             cfg = GrowConfig(max_depth=6, max_bin=32,
@@ -327,33 +294,6 @@ def test_sibling_subtraction_matches_direct_build():
         np.testing.assert_allclose(
             outs[(impl, True)][2], outs[(impl, False)][2], atol=1e-3
         )
-
-
-def test_update_partition_order_maintains_sorted_invariant():
-    from xgboost_ray_tpu.ops.histogram import update_partition_order
-
-    rng = np.random.RandomState(13)
-    n = 500
-    order = jnp.arange(n, dtype=jnp.int32)
-    counts = jnp.full((1,), n, jnp.int32)
-    pos = np.zeros(n, np.int64)
-    for level in range(4):
-        go_right = rng.rand(n) < 0.4
-        new_pos = pos * 2 + go_right
-        order, counts = update_partition_order(
-            order, counts, jnp.asarray(go_right)
-        )
-        pos = new_pos
-        o = np.asarray(order)
-        assert sorted(o.tolist()) == list(range(n))  # a permutation
-        assert np.all(np.diff(pos[o]) >= 0)  # sorted by node
-        np.testing.assert_array_equal(
-            np.asarray(counts), np.bincount(pos, minlength=2 ** (level + 1))
-        )
-        # stability: within a node, original relative order preserved
-        for node in np.unique(pos):
-            rows = o[pos[o] == node]
-            assert np.all(np.diff(rows) > 0) or len(rows) <= 1
 
 
 def test_new_objectives_train_and_improve():
@@ -428,12 +368,12 @@ def test_huber_slope_changes_model_and_sle_validates():
 
 
 def test_hist_missing_bucket_reconstruction():
-    """All impls build only the regular bins on the MXU and reconstruct the
-    missing bucket as node_total - sum(regular); verify against scatter."""
+    """The dense build covers only the regular bins on the MXU and
+    reconstructs the missing bucket as node_total - sum(regular); verify
+    against scatter."""
     import numpy as np
     import jax.numpy as jnp
-    from xgboost_ray_tpu.ops.histogram import (
-        hist_onehot, hist_partition, hist_scatter)
+    from xgboost_ray_tpu.ops.histogram import hist_onehot, hist_scatter
 
     rng = np.random.RandomState(3)
     n, f, nbt = 5000, 5, 17  # max_bin=16, bucket 16 == missing
@@ -443,10 +383,9 @@ def test_hist_missing_bucket_reconstruction():
     ref = np.asarray(hist_scatter(jnp.asarray(bins), jnp.asarray(gh),
                                   jnp.asarray(pos), 4, nbt))
     assert np.abs(ref[:, :, nbt - 1, :]).max() > 0  # missing bucket populated
-    for impl in (hist_onehot, hist_partition):
-        got = np.asarray(impl(jnp.asarray(bins), jnp.asarray(gh),
-                              jnp.asarray(pos), 4, nbt))
-        np.testing.assert_allclose(got, ref, atol=2e-3)
+    got = np.asarray(hist_onehot(jnp.asarray(bins), jnp.asarray(gh),
+                                 jnp.asarray(pos), 4, nbt))
+    np.testing.assert_allclose(got, ref, atol=2e-3)
 
 
 def test_hist_precision_param_accepted_and_fast_close():
@@ -471,40 +410,13 @@ def test_hist_precision_param_accepted_and_fast_close():
     assert np.abs(preds["fast"] - preds["highest"]).mean() < 2e-3
 
 
-def test_select_small_child_rows_edges():
-    """Compaction helper: empty children, fully one-sided splits, sentinel
-    rows for unused capacity."""
-    import numpy as np
-    import jax.numpy as jnp
-    from xgboost_ray_tpu.ops.histogram import select_small_child_rows
-
-    # parent 0: all rows left (right child empty -> right is 'smaller');
-    # parent 1: 3 left / 5 right -> left smaller
-    pos = np.array([0] * 6 + [2] * 3 + [3] * 5, np.int32)
-    n = pos.shape[0]
-    order = np.argsort(pos, kind="stable").astype(np.int32)
-    counts = np.bincount(pos, minlength=4).astype(np.int32)
-    small_is_right = counts[1::2] <= counts[0::2]  # [True, False]
-    rows, pc, valid, counts_sel = map(np.asarray, select_small_child_rows(
-        jnp.asarray(order), jnp.asarray(counts), jnp.asarray(small_is_right)))
-    assert counts_sel.tolist() == [0, 3]
-    assert valid.sum() == 3
-    # the selected rows are exactly parent 1's left-child rows
-    assert set(rows[valid].tolist()) == set(np.where(pos == 2)[0].tolist())
-    assert (pc[valid] == 1).all()
-    # unused slots carry the sentinel row id n
-    assert (rows[~valid] == n).all()
-
-
-@pytest.mark.parametrize("hist_impl", ["partition", "mixed"])
-def test_sibling_compaction_overflow_falls_back(hist_impl):
+@pytest.mark.parametrize("hist_impl", ["scatter", "onehot"])
+def test_sibling_subtraction_holds_on_a_skewed_shard(hist_impl):
     """The smaller child is chosen from GLOBAL (allreduced) counts; on a
-    skewed shard its local rows can exceed the N//2 compaction buffer. Fake
-    the count allreduce so the 'global' choice is the locally-BIGGER child:
-    the compacted build (``partition``, presorted at every fan-out) must run
-    over a second window of the selection and still grow exactly the tree
-    the direct (no-subtraction) build grows; ``mixed``'s dense build streams
-    every row, compacts nothing, and grows that tree with no window at all."""
+    skewed shard it can hold most of the shard's rows. Fake the count
+    allreduce so the 'global' choice is the locally-BIGGER child: the build
+    streams every row, so it grows exactly the tree the direct
+    (no-subtraction) build grows."""
     import numpy as np
     import jax.numpy as jnp
     from xgboost_ray_tpu.ops import binning

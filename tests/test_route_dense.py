@@ -19,7 +19,6 @@ from xgboost_ray_tpu.ops.grow import (
 )
 from xgboost_ray_tpu.ops.histogram import node_counts_dense, node_sums_dense
 from xgboost_ray_tpu.ops.objectives import quantize_gh
-from xgboost_ray_tpu.ops.provider import vmapped_k_impl
 from xgboost_ray_tpu.ops.split import SplitParams
 
 import _route_reference as ref
@@ -150,12 +149,12 @@ def _both_forms(fn, *args):
 TREE_CASES = {
     "depth3": dict(depth=3),
     "depth8": dict(depth=8),
-    "depth3-presorted": dict(depth=3, hist_impl="mixed"),
-    "depth8-presorted": dict(depth=8, hist_impl="mixed"),
+    "depth3-onehot": dict(depth=3, hist_impl="onehot"),
+    "depth8-onehot": dict(depth=8, hist_impl="onehot"),
     "categorical": dict(depth=3, categorical=True),
     "missing": dict(depth=3, missing=True),
     "missing-categorical-depth8": dict(depth=8, missing=True, categorical=True,
-                                       hist_impl="mixed"),
+                                       hist_impl="onehot"),
     "subsample": dict(depth=3, subsample=0.5),
     "gh-int8": dict(depth=3, gh_precision="int8"),
     "gh-int8-quantized-wire": dict(depth=3, gh_precision="int8",
@@ -209,7 +208,7 @@ def test_vmapped_lanes_grow_the_same_trees_as_the_gather_form():
     bins, gh, cuts, fhm, _, max_bin = _tree_data(missing=True)
     cfg = GrowConfig(max_depth=4, max_bin=max_bin,
                      split=SplitParams(learning_rate=0.3),
-                     hist_impl=vmapped_k_impl("scatter"))
+                     hist_impl="scatter")
     ghk = jnp.stack([gh, gh * jnp.asarray([1.0, 2.0]), -gh * jnp.asarray([1.0, -1.0])])
     limits = jnp.asarray([4, 2, 3], jnp.int32)
 
@@ -226,13 +225,9 @@ def test_vmapped_lanes_grow_the_same_trees_as_the_gather_form():
         assert depth_of[np.asarray(got[0].is_leaf[lane])].max() <= limit
 
 
-# the row-sized permutations that are real data movement (not table lookups)
-# and stay: the partition order, the smaller-child selection, the providers
-_MAY_INDEX_BY_ROW = {
-    "update_partition_order", "select_small_child_rows",
-    "presorted_block_layout", "hist_scatter", "hist_partition", "_blocked_hist",
-    "_node_totals_from_blocks", "_gather_rows",
-}
+# the one row-sized scatter that is real data movement (not a table lookup)
+# and stays: the CPU's histogram build
+_MAY_INDEX_BY_ROW = {"hist_scatter"}
 
 
 def _row_indexed_eqns(jaxpr, n_rows):
@@ -259,13 +254,12 @@ def _row_indexed_eqns(jaxpr, n_rows):
     return found
 
 
-@pytest.mark.parametrize("hist_impl", ["scatter", "mixed", "partition"])
+@pytest.mark.parametrize("hist_impl", ["scatter", "onehot"])
 def test_no_row_keyed_gather_or_scatter_in_the_level_loop(hist_impl):
     """The routing block, the live-row count and the final node sums stream
-    the rows: at depth 3 the only gathers / scatters with a row-sized index
-    are the provider's -- ``scatter``'s scatter-add, ``partition``'s order
-    update, smaller-child selection and block layout, and none at all under
-    ``mixed``, whose dense build streams the rows too."""
+    the rows: at depth 3 the only scatter with a row-sized index is
+    ``scatter``'s own scatter-add, and there is none at all under ``onehot``,
+    whose dense build streams the rows too."""
     n = 4096
     bins, gh, cuts, fhm, cat, max_bin = _tree_data(categorical=True, missing=True)
     bins, gh = bins[:n], gh[:n]
@@ -280,12 +274,8 @@ def test_no_row_keyed_gather_or_scatter_in_the_level_loop(hist_impl):
         jax.make_jaxpr(lambda *a: grow_one(*a))(bins, gh, cuts, fhm).jaxpr, n
     )
     assert {fn for _, fn in dense} <= _MAY_INDEX_BY_ROW, dense
-    if hist_impl == "mixed":
+    if hist_impl == "onehot":
         assert dense == []
-    if hist_impl == "partition":
-        assert {"update_partition_order", "select_small_child_rows"} <= {
-            fn for _, fn in dense
-        }
     # the walk does see the old forms: traced with the reference gathers the
     # same tree shows them, issued from the level loop itself
     with ref.gather_form():

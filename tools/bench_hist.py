@@ -1,12 +1,11 @@
 """Microbenchmark the histogram implementations (the tpu_hist hot op).
 
-Run on real hardware to pin ``resolve_hist_impl``'s accelerator default:
+Run on real hardware to check ``default_hist_impl``'s accelerator default:
 
     python tools/bench_hist.py                    # ambient backend
     JAX_PLATFORMS=cpu python tools/bench_hist.py  # CPU sanity
 
-Prints per-(impl, n_nodes) timings plus a full build_tree comparison; the
-winning impl per fan-out regime is what `mixed` should select.
+Prints per-(impl, n_nodes) timings plus a full build_tree comparison.
 
 Timing: each kernel is one jitted call timed on the host clock around
 ``block_until_ready`` (the iteration index perturbs the input so no call is
@@ -43,7 +42,7 @@ def main():
     parser.add_argument("--depth", type=int, default=6)
     parser.add_argument("--repeats", type=int, default=8)
     parser.add_argument("--impls", nargs="+",
-                        default=["scatter", "onehot", "partition"])
+                        default=["scatter", "onehot"])
     args = parser.parse_args()
 
     import jax
@@ -85,10 +84,10 @@ def main():
                 print(f"  hist n_nodes={n_nodes:3d} {impl:10s} FAILED: "
                       f"{str(exc)[:120]}", flush=True)
 
-    # full tree builds (includes partition-order maintenance, split search)
+    # full tree builds (includes row routing and the split search)
     x = rng.randn(args.rows, args.features).astype(np.float32)
     cuts = jnp.asarray(binning.sketch_cuts_np(x[:100_000], args.max_bin))
-    for impl, prec in [(i, p) for i in args.impls + ["mixed"]
+    for impl, prec in [(i, p) for i in args.impls
                        for p in ("fast", "highest")]:
         try:
             cfg = GrowConfig(max_depth=args.depth, max_bin=args.max_bin,

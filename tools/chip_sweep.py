@@ -47,7 +47,6 @@ ROWS = {
     "goss": (1, "sampling_method=gradient_based, top/other_rate=0.2"),
     "stream": (1, "RayDMatrix(stream=True, chunk_rows=25000)"),
     "lanes_k4": (1, "K=4 vmapped lanes (enable_lanes/step_vmapped)"),
-    "hist_partition": (1, "hist_impl=partition"),
     "hist_onehot": (1, "hist_impl=onehot"),
     "serve_node_array": (1, "serve layout=node_array, value+leaf+contribs"),
     "hist_quant_int8": (2, "hist_quant=int8, hist_quant_min_bytes=0"),
@@ -133,9 +132,8 @@ def _run_row(name, n_dev, rows_per_device):
     if name == "stream":
         return _train(BASE, x, y, n_dev,
                       dm_kwargs={"stream": True, "chunk_rows": 25_000})[1]
-    if name in ("hist_partition", "hist_onehot"):
-        return _train(dict(BASE, hist_impl=name[len("hist_"):]),
-                      x, y, n_dev)[1]
+    if name == "hist_onehot":
+        return _train(dict(BASE, hist_impl="onehot"), x, y, n_dev)[1]
     if name in ("hist_quant_int8", "hist_quant_int8_block"):
         return _train(dict(BASE, hist_quant=name[len("hist_quant_"):],
                            hist_quant_min_bytes=0), x, y, n_dev)[1]

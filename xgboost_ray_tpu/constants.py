@@ -20,7 +20,7 @@ AXIS_ACTORS = "actors"
 #: allreduces only its [N/R, F/C] tile. Histograms psum over
 #: :data:`AXIS_ACTORS` only; this axis carries the tiny per-node best-split
 #: election gather and the winning feature's bin-column broadcast (see
-#: ops/provider.py FeatureShard).
+#: ops/feature_shard.py FeatureShard).
 AXIS_FEATURES = "features"
 
 #: synthesized per-row fill for an optional column absent on SOME shards

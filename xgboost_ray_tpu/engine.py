@@ -50,6 +50,7 @@ from xgboost_ray_tpu.ops.histogram import (
     MESH_STATS,
     AllreduceBytes,
     counting_psum,
+    default_hist_impl,
     quantized_hist_allreduce,
 )
 from xgboost_ray_tpu.ops.grow import (
@@ -64,13 +65,7 @@ from xgboost_ray_tpu.ops.grow import (
     predict_tree_binned_fsharded,
     sample_feature_mask,
 )
-from xgboost_ray_tpu.ops.provider import (
-    WIDEST_BUILD_NODES,
-    FeatureShard,
-    default_hist_impl,
-    resolve_hist_provider,
-    vmapped_k_impl,
-)
+from xgboost_ray_tpu.ops.feature_shard import FeatureShard
 from xgboost_ray_tpu.ops import sampling
 from xgboost_ray_tpu.ops.metrics import (
     compute_metric,
@@ -90,15 +85,6 @@ from xgboost_ray_tpu.ops.split import SplitParams
 from xgboost_ray_tpu.params import LaneParams, TrainParams
 
 logger = logging.getLogger(__name__)
-
-
-def resolve_hist_impl(impl: str) -> str:
-    """Resolve 'auto' via the histogram-provider registry's backend policy
-    (ops/provider.py — the one string -> strategy point); explicit names
-    pass through and are validated at provider resolution."""
-    if impl != "auto":
-        return impl
-    return default_hist_impl()
 
 
 def resolve_hist_precision(precision: str) -> str:
@@ -300,7 +286,10 @@ class TpuEngine:
                 learning_rate=params.learning_rate,
                 max_delta_step=params.max_delta_step,
             ),
-            hist_impl=resolve_hist_impl(params.hist_impl),
+            hist_impl=(
+                default_hist_impl() if params.hist_impl == "auto"
+                else params.hist_impl
+            ),
             hist_precision=resolve_hist_precision(params.hist_precision),
             hist_quant=params.hist_quant,
             hist_quant_min_bytes=params.hist_quant_min_bytes,
@@ -309,7 +298,6 @@ class TpuEngine:
             hist_chunk=params.hist_chunk,
             sibling_subtract=params.sibling_subtract,
             cat_features=self._cat_features,
-            shards_may_skew=self.n_devices > 1 or jax.process_count() > 1,
             grow_policy=params.grow_policy,
             # leaf budget: 0 means depth-bounded only; a budget beyond
             # 2^max_depth is unreachable, so cap it (keeps the frontier
@@ -2225,9 +2213,8 @@ class TpuEngine:
         round dispatched since the last reset and every shard,
         ``hist_sibling_builds`` (a shard's sibling-subtraction builds, one
         a level >= 1) and ``hist_skew_fallback_builds`` (those among them
-        that were compacted builds in the skew-tolerant window loop and
-        needed more than one window because the shard's rows of the chosen
-        children overflowed its ``N // 2`` buffer; a dense build cannot).
+        that did not hold the shard's rows of the chosen children in one
+        pass: 0, since every build streams every row).
         All 0 on a one-device world, whose programs have no wire and whose
         shard cannot skew.
         Reads this process's shards of the one running sum (a small
@@ -2423,29 +2410,6 @@ class TpuEngine:
                 f"{[p.max_depth for p in lanes]} vs cfg.max_depth="
                 f"{self.cfg.max_depth}"
             )
-        # histogram-provider seam: the lane build must go through an
-        # order-free provider (presorted-row-order providers carry state
-        # the lane axis cannot batch) — route cfg.hist_impl through the
-        # registry's vmapped_k wrapper, which validates and delegates
-        base_impl = self.cfg.hist_impl
-        prov = resolve_hist_provider(
-            base_impl, self.cfg.hist_precision, self.cfg.hist_chunk
-        )
-        if prov.uses_order(WIDEST_BUILD_NODES):
-            if self.params.hist_impl == "auto":
-                # auto resolves per backend; under lanes the order-free
-                # scatter build is the auto choice
-                base_impl = "scatter"
-            else:
-                raise NotImplementedError(
-                    f"hist_impl {self.params.hist_impl!r} maintains a "
-                    f"presorted row order and cannot back the vmapped-K "
-                    f"build; use hist_impl='auto' or an order-free "
-                    f"implementation (scatter, onehot)"
-                )
-        self.cfg = dataclasses.replace(
-            self.cfg, hist_impl=vmapped_k_impl(base_impl)
-        )
         # per-lane param planes: f32 split params always; depth/budget
         # masks only when they actually vary (uniform lanes keep the
         # scalar program's exact arithmetic — the bitwise-parity contract)

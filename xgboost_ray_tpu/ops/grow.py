@@ -18,25 +18,19 @@ The histogram allreduce point is the ``allreduce`` callable: identity on a
 single device, ``lax.psum(..., "actors")`` inside the shard_map round step —
 this is the exact spot where the reference relied on Rabit (SURVEY §5.8).
 
-Histogram impl choice: ``scatter`` (segment-sum), ``onehot`` (the dense
-one-hot matmul on the MXU at every fan-out), ``partition`` (node-contiguous
-presorted blocks at every fan-out) and ``mixed`` are all XLA formulations.
-``mixed``, the chip's default, is ``onehot``'s build while a level's
-``2 * build_nodes`` right-hand-side columns are at most 1,024 (every level
-of ``max_depth <= 11`` under sibling subtraction) and the presorted blocks
-beyond; a tree whose every level is under that crossover keeps no row order,
-compacts no smaller child and copies no row into blocks (PR 30: on a v5e at
-11M rows a level then costs 66-73 ms up to 32 columns and 144 ms at 128,
-against 300-390 ms plus a 170 ms order update before; PERF.md §5, §6).
+A level's histogram comes from ``ops.histogram.build_histogram``: ``scatter``
+(one scatter-add; the CPU's) or ``onehot`` (the dense one-hot matmul on the
+MXU at every fan-out; the chip's). Either streams every row once: the tree
+keeps no row order, compacts no smaller child and copies no row into blocks
+(on a v5e at 11M rows a dense level costs 66-73 ms up to 32 columns and
+144 ms at 128; PERF.md §5, §6).
 
-The hand-written Pallas presorted-histogram kernel of r2-r4 was deleted in
-r5: the one on-chip session of its time (pre-PR-1, 2026-07-29) showed it
-~1.4x slower per level than the identical-layout XLA einsum. That verdict
-was about that kernel against that layout, not about kernels: the ledger has
-since shown the blocked layout itself to be the cost (the row copies and the
-order update were 62% of a round, PR 28), and XLA's dense build still runs
-some 500x over its bytes floor. Whether a kernel that keeps the one-hot in
-VMEM beats it is ROADMAP P2's open question.
+A hand-written Pallas kernel over node-contiguous row blocks (r2-r4) and
+the XLA build over the same blocks (until PR 31) both lost to the dense
+build on the chip: the blocked layout itself was the cost (the row copies
+and the order update were 62% of a round, PR 28). XLA's dense build still
+runs some 500x over its bytes floor; whether a kernel that keeps the one-hot
+in VMEM beats it is ROADMAP P2's open question.
 """
 
 import dataclasses
@@ -47,10 +41,9 @@ import jax.numpy as jnp
 
 from xgboost_ray_tpu.obs import get_registry
 from xgboost_ray_tpu.ops.histogram import (
+    build_histogram,
     node_counts_dense,
     node_sums_dense,
-    select_small_child_rows,
-    update_partition_order,
     zero_phantom_missing,
 )
 from xgboost_ray_tpu.ops.split import (
@@ -239,14 +232,6 @@ class GrowConfig:
     # one-vs-rest partitions routed by equality). Static tuple so it can ride
     # inside this hashable jit-static config.
     cat_features: tuple = ()
-    # True when this shard's counts can differ from the allreduced ones
-    # (world size > 1): a COMPACTED sibling build (a level built from the
-    # presorted order) then sits in a loop that runs it once per N // 2
-    # window of the selection, for selections overflowing the buffer; a
-    # dense level compacts nothing and is only noted to the mesh's build
-    # count. Single-shard training sets False — the selection provably
-    # fits, and the build stands alone.
-    shards_may_skew: bool = True
     # per-feature monotone constraints (len == F, values -1/0/+1) or () —
     # xgboost's monotone_constraints via per-node weight-bound propagation
     # (reference passthrough surface: xgboost_ray/main.py:745-752)
@@ -284,18 +269,6 @@ class GrowConfig:
     @property
     def heap_size(self) -> int:
         return (1 << (self.max_depth + 1)) - 1
-
-    def hist_provider(self):
-        """Resolve (hist_impl, hist_precision, hist_chunk) into the one
-        :class:`~xgboost_ray_tpu.ops.provider.HistogramProvider` object
-        every build in this tree dispatches through — the protocol that
-        replaced the per-site string branching."""
-        from xgboost_ray_tpu.ops.provider import resolve_hist_provider
-
-        return resolve_hist_provider(
-            self.hist_impl, precision=self.hist_precision,
-            chunk=self.hist_chunk,
-        )
 
 
 class Tree(NamedTuple):
@@ -357,8 +330,8 @@ def build_tree(
     feat_has_missing: Optional[jnp.ndarray] = None,  # [F] bool, global
     hist_allreduce: Optional[Callable[[jnp.ndarray], jnp.ndarray]] = None,
     ar_counter=None,  # AllreduceBytes: scan-scoped byte accounting, and the
-    #   count of a skew-prone shard's sibling builds (and window fallbacks)
-    fshard=None,  # ops.provider.FeatureShard on a 2D row x feature mesh
+    #   count of a mesh shard's sibling builds
+    fshard=None,  # ops.feature_shard.FeatureShard on a 2D row x feature mesh
     gh_scale: Optional[jnp.ndarray] = None,  # [2] f32 per-channel scales of a
     #   quantized integer gh buffer (gh_precision; None = f32 legacy path)
     depth_limit: Optional[jnp.ndarray] = None,  # traced int32 scalar: levels
@@ -420,7 +393,6 @@ def build_tree(
     nbt = cfg.max_bin + 1
     lr = cfg.split.learning_rate
     missing_bin = cfg.max_bin
-    provider = cfg.hist_provider()
 
     # quantized-gh mode: sums stay in the exact integer domain until this
     # one dequantization point (gh_scale is None on the f32 legacy path,
@@ -500,23 +472,6 @@ def build_tree(
         ic_used = jnp.zeros((1, num_features), bool)
         ic_has_used = jnp.zeros((1,), bool)
 
-    # providers with a level that builds from presorted blocks keep rows
-    # sorted by node across levels with an O(N) stable segment split (no
-    # per-level argsort); a tree whose every level takes a dense build (on
-    # the chip's ``mixed``: max_depth <= 11) tracks no order at all
-    def _build_nodes(d):
-        # node slots of level d's build: the smaller children only (one a
-        # parent) under sibling subtraction
-        return (1 << d) // 2 if cfg.sibling_subtract and d > 0 else 1 << d
-
-    track_order = any(
-        provider.uses_order(_build_nodes(d)) for d in range(cfg.max_depth)
-    )
-    order = counts = None
-    if track_order:
-        order = jnp.arange(n, dtype=jnp.int32)
-        counts = jnp.full((1,), n, jnp.int32)
-
     prev_hist = None
     for d in range(cfg.max_depth):
         with jax.named_scope(f"level{d}"):
@@ -529,15 +484,11 @@ def build_tree(
             # threshold levels take the exact f32 psum, and then node totals
             # also come from the histogram readout — bit-identical to
             # hist_quant="none", so small problems are a provable no-op.
-            build_nodes = _build_nodes(d)
-            # does this level's build read the presorted order, or stream
-            # every row once (counted as the level is traced)?
-            from_order = provider.uses_order(build_nodes)
-            dense_levels = get_registry().counter("rxgb_hist_dense_levels_total")
-            presorted_levels = get_registry().counter(
-                "rxgb_hist_presorted_levels_total"
+            # node slots of this level's build: the smaller children only
+            # (one a parent) under sibling subtraction
+            build_nodes = (
+                n_nodes // 2 if cfg.sibling_subtract and d > 0 else n_nodes
             )
-            (presorted_levels if from_order else dense_levels).inc()
             exact_totals = (
                 cfg.hist_quant != "none"
                 and build_nodes * num_features * nbt * 2 * 4
@@ -569,14 +520,8 @@ def build_tree(
                 node_gh_exact = deq(packed[:, :2])
                 counts_live = packed[:, 2]
 
-            def _build(gh_b, pos_b, order_b, counts_b, nn, rows_sel=None):
-                """One histogram build over nn node slots via the provider.
-
-                ``rows_sel`` is a compacted row-id view into the FULL bins/gh
-                (sentinel n for unused slots). Presorted providers consume it
-                directly as the row order — the padded-block gather is then the
-                only copy; gather-based providers materialize the selection
-                first (``ops.provider._gather_rows``).
+            def _build(gh_b, pos_b, nn):
+                """One histogram build over nn node slots.
 
                 The missing bucket is reconstructed by subtraction (node_total -
                 sum of regular bins), so with hist_precision="fast" the bf16
@@ -587,9 +532,9 @@ def build_tree(
                 """
                 with jax.named_scope("hist"):
                     return zero_phantom_missing(
-                        provider.build(
-                            bins, gh_b, pos_b, nn, nbt,
-                            order=order_b, counts=counts_b, rows_sel=rows_sel,
+                        build_histogram(
+                            bins, gh_b, pos_b, nn, nbt, impl=cfg.hist_impl,
+                            chunk=cfg.hist_chunk, precision=cfg.hist_precision,
                         ),
                         fhm_local,
                     )
@@ -611,66 +556,18 @@ def build_tree(
                     child_counts = allreduce(live_rows)
                 # [n_par] True when the right child is the (weakly) smaller one
                 small_is_right = child_counts[1::2] <= child_counts[0::2]
-                if from_order:
-                    # compact the smaller child's rows into an [N // 2] buffer so
-                    # every impl processes HALF the rows (vs just zeroing gh).
-                    def _compacted(window=None):
-                        # done rows only live under inactive parents (they always
-                        # route left below their leaf), so the active nodes this
-                        # histogram feeds never see them — no done-mask needed;
-                        # sentinel slots zero out via the layouts' appended row.
-                        with jax.named_scope("hist"):
-                            rows, par_of_slot, _valid_sel, counts_sel = (
-                                select_small_child_rows(
-                                    order, counts, small_is_right, offset=window
-                                )
-                            )
-                        return _build(gh, par_of_slot, None, counts_sel, n_par,
-                                      rows_sel=rows)
-
-                    if cfg.shards_may_skew:
-                        # The child choice is GLOBAL (allreduced counts), so on a
-                        # skewed shard the chosen children's LOCAL rows can exceed
-                        # N // 2. The shard then runs the SAME compacted build
-                        # once more per further N // 2 window of its selection
-                        # and adds the results up (shard-local control flow; the
-                        # psum sits outside and runs on every shard either way).
-                        # One loop body, not a cond between this build and a
-                        # full-row one: a build's code grows with its row
-                        # extent (some 75 MB a level at 11M rows), so a second
-                        # build a level more than doubles the program.
-                        n_half = max(n // 2, 1)
-                        with jax.named_scope("hist"):
-                            picked = counts[
-                                2 * jnp.arange(n_par, dtype=jnp.int32)
-                                + small_is_right.astype(jnp.int32)
-                            ].sum()
-                        n_windows = (picked + n_half - 1) // n_half
-                        if ar_counter is not None:
-                            ar_counter.note_sibling_build(n_windows <= 1)
-                        one = jax.eval_shape(_compacted, jnp.int32(0))
-                        hist_small = hist_ar(jax.lax.fori_loop(
-                            0, n_windows,
-                            lambda w, acc: acc + _compacted(w * n_half),
-                            jnp.zeros(one.shape, one.dtype),
-                        ))
-                    else:
-                        hist_small = hist_ar(_compacted())
-                else:
-                    # every row streams through the build once, the bigger
-                    # child's with zeroed gh: no compaction, so no shard's
-                    # selection can overflow and no build needs a window
-                    if cfg.shards_may_skew and ar_counter is not None:
-                        ar_counter.note_sibling_build(True)
-                    parent_pos = pos >> 1
-                    is_right = (pos & 1).astype(bool)
-                    sel = (
-                        is_right == lookup_by_node(parent_pos, small_is_right)[0]
-                    ) & ~done
-                    gh_sel = gh * sel[:, None].astype(gh.dtype)
-                    hist_small = hist_ar(
-                        _build(gh_sel, parent_pos, None, None, n_par)
-                    )
+                # every row streams through the build once, the bigger child's
+                # with zeroed gh: nothing is compacted, so no shard's rows of
+                # the chosen children can overflow a buffer
+                if ar_counter is not None:
+                    ar_counter.note_sibling_build(True)
+                parent_pos = pos >> 1
+                is_right = (pos & 1).astype(bool)
+                sel = (
+                    is_right == lookup_by_node(parent_pos, small_is_right)[0]
+                ) & ~done
+                gh_sel = gh * sel[:, None].astype(gh.dtype)
+                hist_small = hist_ar(_build(gh_sel, parent_pos, n_par))
                 with jax.named_scope("hist"):
                     hist_big = prev_hist - hist_small
                     sir = small_is_right[:, None, None, None]
@@ -680,8 +577,7 @@ def build_tree(
                         (n_nodes,) + hist_small.shape[1:]
                     )
             else:
-                layout = (order, counts) if from_order else (None, None)
-                hist = hist_ar(_build(gh, pos, *layout, n_nodes))
+                hist = hist_ar(_build(gh, pos, n_nodes))
             prev_hist = hist
             # [n_nodes, 2]: feature 0's buckets cover every row. Under
             # hist_precision="fast" these totals carry the regular bins' bf16
@@ -820,8 +716,6 @@ def build_tree(
                 effective_right = jnp.where(done, False, go_right)
                 pos = pos * 2 + effective_right.astype(jnp.int32)
                 active = jnp.repeat(valid_split, 2)
-                if track_order:
-                    order, counts = update_partition_order(order, counts, effective_right)
 
             if mono_on:
                 # Recompute the CHOSEN split's child weights (same clamped

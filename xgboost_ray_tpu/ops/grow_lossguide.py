@@ -37,7 +37,7 @@ from xgboost_ray_tpu.ops.grow import (
     route_right_binned,
 )
 from xgboost_ray_tpu.ops.histogram import (
-    hist_onehot,
+    build_histogram,
     node_sums,
     zero_phantom_missing,
 )
@@ -62,7 +62,7 @@ def build_tree_lossguide(
     hist_allreduce: Optional[Callable[[jnp.ndarray], jnp.ndarray]] = None,
     ar_counter=None,  # AllreduceBytes: the scan body traces once, runs
     #   leaves-1 times — the repeated() scope keeps byte accounting exact
-    fshard=None,  # ops.provider.FeatureShard on a 2D row x feature mesh
+    fshard=None,  # ops.feature_shard.FeatureShard on a 2D row x feature mesh
     gh_scale: Optional[jnp.ndarray] = None,  # [2] f32 scales of a quantized
     #   integer gh buffer (gh_precision); None = the f32 legacy path
 ):
@@ -115,12 +115,11 @@ def build_tree_lossguide(
         # feature-0 row, so under hist_precision="fast" they carry the
         # regular bins' bf16 rounding — the SAME accepted contract as the
         # depthwise grower's node_gh (see ops/grow.py's node_gh comment).
-        # Always the one-hot MXU pass: the per-step 2-node fan-out is the
-        # regime where every provider would pick it anyway (params.py pins
+        # Always the one-hot MXU pass, on the CPU too (params.py pins
         # hist_impl to auto|onehot for lossguide).
         with jax.named_scope("hist"):
-            h = hist_onehot(
-                bins, gh_b, pos_b, nn, nbt,
+            h = build_histogram(
+                bins, gh_b, pos_b, nn, nbt, impl="onehot",
                 chunk=cfg.hist_chunk, precision=cfg.hist_precision,
             )
         return zero_phantom_missing(hist_ar(h), fhm_local)

@@ -8,19 +8,15 @@ training: per boosting level we accumulate (grad, hess) sums into
 feature bin). The merged-across-shards histogram is obtained by ``psum`` in
 the shard_map round step (replacing the Rabit allreduce, SURVEY §5.8).
 
-Two implementations:
+Two implementations, chosen from the platform (``default_hist_impl``) or by
+name (params ``hist_impl``) in ``build_histogram``:
 
-* ``hist_scatter`` — one flat XLA scatter-add. Correct everywhere (CPU tests,
-  TPU), shape-static, reasonable on TPU for moderate fan-out.
+* ``hist_scatter`` — one flat XLA scatter-add. Correct everywhere; the CPU
+  default, and what the tests hold the dense build to.
 * ``hist_onehot`` — the dense MXU build: row-chunked ``onehot(bins)ᵀ @
   (gh ⊗ onehot(node))`` matmuls, one pass over all rows at every fan-out, no
-  row order; the scan over row chunks and feature tiles bounds the one-hot
-  transient. The TPU default while a level's columns fit the MXU.
-* ``hist_partition`` / ``hist_partition_presorted`` — node-uniform row blocks
-  from a (maintained) stable order; FLOPs independent of the fan-out, paid
-  for in row-indexed data movement. The TPU path past the crossover.
-
-Selection happens through ``ops/provider.py`` (params ``hist_impl``).
+  row order; the loop over row chunks and feature tiles bounds the one-hot
+  transient. The default on an accelerator.
 """
 
 import functools
@@ -125,12 +121,11 @@ class AllreduceBytes:
 
     Beside the bytes it counts what else only a mesh has, at the same call
     sites: ``calls``, the collectives a round (one per recorded op, a
-    multi-hop ring once per hop; 0 on a 1-device axis, where there is no
-    wire), and the sibling-subtraction builds of a shard whose rows may skew
-    with those among them that needed a further window
-    (``note_sibling_build``). They leave the
-    round program through ``mesh_stats``, which is ``None`` -- no output at
-    all -- where the round traced no collective and no such build, so a
+    multi-hop ring once per hop), and a shard's sibling-subtraction builds
+    with those among them that did not fit (``note_sibling_build``). Both are
+    0 on a 1-device axis, where there is no wire and no second shard to skew
+    against. They leave the round program through ``mesh_stats``, which is
+    ``None`` -- no output at all -- where the round traced neither, so a
     one-device program is the program it was."""
 
     def __init__(self, n_actors: int):
@@ -168,13 +163,13 @@ class AllreduceBytes:
         self._add(self._nbytes(arr) * int(hops), calls=int(hops))
 
     def note_sibling_build(self, fits) -> None:
-        """One sibling build on a shard whose rows may skew. ``fits`` is the
-        shard's predicate "this build needed no further window": traced for
-        a compacted build in the skew-tolerant loop ("one N // 2 window
-        holds my rows of the chosen children"; ``~fits`` is a build that
-        fell back to further windows, up to twice the rows, while the other
-        shards wait at the level's psum), plain ``True`` for a dense build,
-        which streams every row, compacts nothing and cannot overflow."""
+        """One sibling build of a shard of a mesh. ``fits`` says whether the
+        build held the shard's rows of the chosen children in one pass: the
+        dense build streams every row and always does (``True``), so
+        ``fallback_builds`` stays 0; the count is what the benchmark's
+        ``hist.skew_fallback_pct`` reads."""
+        if self.n == 1:
+            return
         self.sibling_builds += self._mult
         self.fallback_builds = self.fallback_builds + (
             jnp.logical_not(fits).astype(jnp.int32) * self._mult
@@ -465,15 +460,6 @@ def _acc_dtype(gh) -> jnp.dtype:
     )
 
 
-def _node_totals_from_blocks(
-    ghp: jnp.ndarray, node_of_block: jnp.ndarray, n_nodes: int
-) -> jnp.ndarray:
-    """[n_blocks, block, 2] node-uniform blocks -> [n_nodes + 1, 2] totals."""
-    acc = _acc_dtype(ghp)
-    block_sums = ghp.sum(axis=1, dtype=acc) if acc == jnp.int32 else ghp.sum(axis=1)
-    return jnp.zeros((n_nodes + 1, 2), acc).at[node_of_block].add(block_sums)
-
-
 def _chunk_node_sums(ghk: jnp.ndarray, pk: jnp.ndarray, n_nodes: int):
     """[n_nodes, 2] (grad, hess) totals of one row chunk as one-hot(node)ᵀ @ gh
     on the MXU (a [N]-row scatter here measured ~20 ms/1M rows on TPU): exact
@@ -647,268 +633,6 @@ def hist_onehot(
     return _append_missing(hist_reg, node_tot)
 
 
-def update_partition_order(
-    order: jnp.ndarray,  # [N] rows sorted stably by current pos
-    counts: jnp.ndarray,  # [n_nodes] rows per node at the current level
-    go_right: jnp.ndarray,  # [N] bool, indexed by ORIGINAL row id
-) -> tuple:
-    """O(N) stable segment split: maintain the sorted-by-node row order across
-    one level of tree growth without re-sorting (the XLA analog of gpu_hist's
-    incremental row partitioner). Returns (new_order, new_counts) for the
-    2*n_nodes children."""
-    n = order.shape[0]
-    n_nodes = counts.shape[0]
-    seg_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
-    )
-    seg_of_slot = jnp.searchsorted(
-        jnp.cumsum(counts), jnp.arange(n), side="right"
-    )
-    gr_s = go_right[order]
-    left_s = ~gr_s
-    # exclusive cumulative left/right counts, segment-relative
-    cum_left = jnp.cumsum(left_s) - left_s
-    cum_right = jnp.cumsum(gr_s) - gr_s
-    left_before = cum_left[seg_start]  # [n_nodes] lefts before each segment
-    right_before = cum_right[seg_start]
-    rank_left = cum_left - left_before[seg_of_slot]
-    rank_right = cum_right - right_before[seg_of_slot]
-    # child segment sizes
-    seg_end = jnp.cumsum(counts) - 1
-    total_left = jnp.where(
-        counts > 0, cum_left[jnp.maximum(seg_end, 0)] + left_s[jnp.maximum(seg_end, 0)]
-        - left_before, 0
-    )
-    left_count = total_left
-    right_count = counts - left_count
-    new_counts = jnp.stack([left_count, right_count], axis=1).reshape(-1)
-    new_start = jnp.concatenate(
-        [jnp.zeros((1,), new_counts.dtype), jnp.cumsum(new_counts)[:-1]]
-    )
-    child = 2 * seg_of_slot + gr_s.astype(seg_of_slot.dtype)
-    rank = jnp.where(gr_s, rank_right, rank_left)
-    dest = new_start[child] + rank
-    new_order = jnp.zeros_like(order).at[dest].set(order)
-    return new_order, new_counts
-
-
-def select_small_child_rows(
-    order: jnp.ndarray,  # [N] rows sorted stably by child node
-    counts: jnp.ndarray,  # [2 * n_par] rows per child node
-    small_is_right: jnp.ndarray,  # [n_par] bool
-    offset=None,  # traced int32: the window's first slot of the selection
-):
-    """Compact the rows of every parent's smaller child into [N // 2] slots.
-
-    The globally-smaller children hold at most half of all rows, so the
-    compacted layout has a STATIC capacity of N // 2 — this is what turns
-    sibling subtraction into a real 2x on row traffic (zeroing gh of the
-    bigger child still feeds its rows through the MXU; gathering the smaller
-    child's rows does not). Returns (rows [N//2] with sentinel N for unused
-    slots, parent index per slot [N//2], valid mask [N//2], counts_sel
-    [n_par]); rows come out sorted by parent, so they are directly a
-    presorted (order=arange, counts=counts_sel) layout.
-
-    On a mesh the child choice is global, so one shard's selection can be
-    longer than its N // 2 slots. ``offset`` then names a window of it:
-    slots ``[offset, offset + N // 2)`` of the selection, with counts_sel
-    the rows of each parent inside the window. Windows 0, N // 2, ... tile
-    the selection, and window 0 of a selection that fits is the whole of it.
-    """
-    n = order.shape[0]
-    n_par = small_is_right.shape[0]
-    n_half = max(n // 2, 1)
-    c_small = 2 * jnp.arange(n_par, dtype=jnp.int32) + small_is_right.astype(jnp.int32)
-    counts_sel = counts[c_small]
-    seg_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
-    )
-    cum_sel = jnp.cumsum(counts_sel)
-    start_sel = jnp.concatenate([jnp.zeros((1,), cum_sel.dtype), cum_sel[:-1]])
-    i = jnp.arange(n_half)
-    if offset is not None:
-        i = i + offset
-        counts_sel = (jnp.clip(cum_sel - offset, 0, n_half)
-                      - jnp.clip(start_sel - offset, 0, n_half))
-    p = jnp.searchsorted(cum_sel, i, side="right")
-    pc = jnp.clip(p, 0, n_par - 1).astype(jnp.int32)
-    src = seg_start[c_small[pc]] + (i - start_sel[pc])
-    valid = i < cum_sel[-1]
-    rows = jnp.where(valid, order[jnp.clip(src, 0, n - 1)], n).astype(jnp.int32)
-    return rows, pc, valid, counts_sel
-
-
-def presorted_block_layout(
-    bins: jnp.ndarray,
-    gh: jnp.ndarray,
-    order: jnp.ndarray,  # [N] rows sorted stably by node
-    counts: jnp.ndarray,  # [n_nodes]
-    n_nodes: int,
-    block: int,
-):
-    """Scatter presorted rows into node-uniform padded blocks.
-
-    Returns (bp [n_blocks, block, F], ghp [n_blocks, block, 2],
-    node_of_block [n_blocks]); padding slots carry zero gh. Shared by the XLA
-    blocked-einsum path and the Pallas kernel so the layout math has one
-    home.
-
-    ``order`` may be SHORTER than bins (a compacted selection, e.g. the
-    smaller-child rows under sibling subtraction): slots beyond
-    ``sum(counts)`` and entries holding the sentinel ``bins.shape[0]`` land
-    on the appended zero row and contribute nothing."""
-    sentinel, num_features = bins.shape
-    n_slots = order.shape[0]
-    seg_start = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]]
-    )
-    padded_counts = ((counts + block - 1) // block) * block
-    padded_cum = jnp.cumsum(padded_counts)
-    padded_start = jnp.concatenate(
-        [jnp.zeros((1,), padded_cum.dtype), padded_cum[:-1]]
-    )
-    seg_of_slot = jnp.searchsorted(
-        jnp.cumsum(counts), jnp.arange(n_slots), side="right"
-    )
-    seg_c = jnp.minimum(seg_of_slot, counts.shape[0] - 1)
-    rank_in_node = jnp.arange(n_slots) - seg_start[seg_c]
-    in_range = seg_of_slot < counts.shape[0]
-    dest = jnp.where(in_range, padded_start[seg_c] + rank_in_node, -1).astype(jnp.int32)
-
-    cap = (-(-n_slots // block) + n_nodes) * block
-    n_blocks = cap // block
-    # OOB dest (-1 slots beyond the selection) are dropped by the scatter
-    row_of_slot = jnp.full((cap,), sentinel, jnp.int32).at[dest].set(
-        order.astype(jnp.int32), mode="drop"
-    )
-    node_of_block = jnp.clip(
-        jnp.searchsorted(padded_cum, jnp.arange(n_blocks) * block, side="right"),
-        0,
-        n_nodes,
-    ).astype(jnp.int32)
-    # keep the bins gather in the storage dtype (uint8/int16): the padded
-    # block copy is the largest per-level buffer (11M x 28 would be 1.2 GB
-    # as int32 — enough to OOM an 11M-row training step on a 16 GB chip)
-    bins_ext = jnp.concatenate([bins, jnp.zeros((1, num_features), bins.dtype)])
-    gh_ext = jnp.concatenate([gh, jnp.zeros((1, 2), gh.dtype)])
-    bp = bins_ext[row_of_slot].reshape(n_blocks, block, num_features)
-    ghp = gh_ext[row_of_slot].reshape(n_blocks, block, 2)
-    return bp, ghp, node_of_block
-
-
-def hist_partition_presorted(
-    bins: jnp.ndarray,
-    gh: jnp.ndarray,
-    order: jnp.ndarray,  # [N] rows sorted stably by node
-    counts: jnp.ndarray,  # [n_nodes]
-    n_nodes: int,
-    n_bins_total: int,
-    block: int = 256,
-    block_chunk: int = 512,
-    precision: str = "highest",
-) -> jnp.ndarray:
-    """hist_partition with the sort/bincount already maintained by the caller
-    (see ``update_partition_order``)."""
-    num_features = bins.shape[1]
-    bp, ghp, node_of_block = presorted_block_layout(
-        bins, gh, order, counts, n_nodes, block
-    )
-    return _blocked_hist(
-        bp, ghp, node_of_block, n_nodes, n_bins_total, num_features,
-        block_chunk, precision,
-    )
-
-
-def _blocked_hist(bp, ghp, node_of_block, n_nodes, n_bins_total, num_features,
-                  block_chunk, precision: str = "highest"):
-    nb_reg = n_bins_total - 1  # regular bins; missing reconstructed after
-    prec = _einsum_precision(precision)
-    node_tot = _node_totals_from_blocks(ghp, node_of_block, n_nodes)
-    n_blocks = bp.shape[0]
-    n_chunks = -(-n_blocks // block_chunk)
-    pad_blocks = n_chunks * block_chunk - n_blocks
-    if pad_blocks:
-        bp = jnp.pad(bp, ((0, pad_blocks), (0, 0), (0, 0)))
-        ghp = jnp.pad(ghp, ((0, pad_blocks), (0, 0), (0, 0)))
-        node_of_block = jnp.pad(node_of_block, (0, pad_blocks), constant_values=n_nodes)
-    bp = bp.reshape(n_chunks, block_chunk, -1, num_features)
-    ghp = ghp.reshape(n_chunks, block_chunk, -1, 2)
-    nodes_c = node_of_block.reshape(n_chunks, block_chunk)
-
-    # quantized gradients: narrow-int one-hot x gh, exact int32 accumulation
-    # (see hist_onehot); the bf16 fast mode does not apply
-    acc_dt = _acc_dtype(ghp)
-    if acc_dt == jnp.int32:
-        oh_dtype = ghp.dtype
-    else:
-        oh_dtype = jnp.bfloat16 if precision == "fast" else jnp.float32
-    # tile features per sequential step (step count, not FLOPs, bounds this
-    # path on TPU — same treatment as hist_onehot)
-    ftile = min(4, num_features)
-    n_ftiles = -(-num_features // ftile)
-    f_pad = n_ftiles * ftile - num_features
-
-    def chunk_step(hist, args):
-        bc, gc, nodes = args
-        bc = bc.astype(jnp.int32)  # per-chunk transient upcast
-        if f_pad:
-            # missing-valued pad columns produce all-zero one-hot rows
-            bc = jnp.pad(bc, ((0, 0), (0, 0), (0, f_pad)), constant_values=nb_reg)
-        gc_c = gc.astype(oh_dtype)
-
-        def ftile_step(t, hist):
-            cols = jax.lax.dynamic_slice_in_dim(bc, t * ftile, ftile, axis=2)
-            # bins == nb_reg (missing) exceed the one-hot width -> zero rows
-            oh = jax.nn.one_hot(cols, nb_reg, dtype=oh_dtype)  # [C, b, T, nb]
-            contrib = jnp.einsum("cbtn,cbd->ctnd", oh, gc_c, precision=prec,
-                                 preferred_element_type=acc_dt)
-            # scatter the [C, T, nb, 2] tile contributions into the node rows
-            sl = jax.lax.dynamic_slice_in_dim(hist, t * ftile, ftile, axis=1)
-            sl = sl.at[nodes, :, :, :].add(contrib)
-            return jax.lax.dynamic_update_slice_in_dim(hist, sl, t * ftile, axis=1)
-
-        hist = jax.lax.fori_loop(0, n_ftiles, ftile_step, hist)
-        return hist, None
-
-    hist0 = jnp.zeros((n_nodes + 1, n_ftiles * ftile, nb_reg, 2), acc_dt)
-    hist, _ = jax.lax.scan(chunk_step, hist0, (bp, ghp, nodes_c))
-    hist = hist[:, :num_features]
-    return _append_missing(hist[:n_nodes], node_tot[:n_nodes])
-
-
-def hist_partition(
-    bins: jnp.ndarray,
-    gh: jnp.ndarray,
-    pos: jnp.ndarray,
-    n_nodes: int,
-    n_bins_total: int,
-    block: int = 256,
-    block_chunk: int = 512,
-    precision: str = "highest",
-) -> jnp.ndarray:
-    """Node-contiguous blocked histogram — the deep-level TPU workhorse.
-
-    The one-hot-matmul formulation costs rows x nodes x bins FLOPs (the node
-    axis rides in the one-hot width), which explodes at deep levels. This
-    variant first *partitions rows by node* (stable sort + padded segment
-    layout, the XLA analog of gpu_hist's row partitioner), so every
-    ``block``-row tile belongs to exactly one node and the per-tile matmul is
-    only [bins x block] @ [block x 2]: total FLOPs ~ rows x bins x features,
-    independent of the node count. The final per-block scatter touches
-    O(n_blocks) elements only.
-    """
-    num_features = bins.shape[1]
-    order = jnp.argsort(pos, stable=True)
-    counts = jnp.bincount(pos, length=n_nodes)
-    bp, ghp, node_of_block = presorted_block_layout(
-        bins, gh, order, counts, n_nodes, block
-    )
-    return _blocked_hist(
-        bp, ghp, node_of_block, n_nodes, n_bins_total, num_features,
-        block_chunk, precision,
-    )
-
-
 def node_sums(gh: jnp.ndarray, pos: jnp.ndarray, n_nodes: int) -> jnp.ndarray:
     """Per-node (grad, hess) totals: [n_nodes, 2] via segment-sum (exact
     int32 sums for quantized integer gh)."""
@@ -958,6 +682,17 @@ def zero_phantom_missing(h: jnp.ndarray, feat_has_missing) -> jnp.ndarray:
     return h.at[:, :, -1, :].multiply(keep)
 
 
+#: the builds ``hist_impl`` can name (beside "auto": ``default_hist_impl``)
+HIST_IMPLS = ("scatter", "onehot")
+
+
+def default_hist_impl() -> str:
+    """What ``hist_impl="auto"`` means here: the scatter-add on the CPU
+    backend (the only build that runs in reasonable time there), the dense
+    MXU build elsewhere."""
+    return "scatter" if jax.default_backend() == "cpu" else "onehot"
+
+
 def build_histogram(
     bins: jnp.ndarray,
     gh: jnp.ndarray,
@@ -968,13 +703,15 @@ def build_histogram(
     chunk: int = 8192,
     precision: str = "highest",
 ) -> jnp.ndarray:
-    """Back-compat shim over the histogram-provider registry: resolves
-    ``impl`` through ``ops.provider`` (the ONE string -> strategy point)
-    and builds with no maintained row layout. The growers dispatch through
-    a resolved :class:`~xgboost_ray_tpu.ops.provider.HistogramProvider`
-    directly; this entry point serves standalone callers (profiling,
-    micro-benchmarks, tests)."""
-    from xgboost_ray_tpu.ops.provider import resolve_hist_provider
-
-    provider = resolve_hist_provider(impl, precision=precision, chunk=chunk)
-    return provider.build(bins, gh, pos, n_nodes, n_bins_total)
+    """One level's ``[n_nodes, F, n_bins_total, 2]`` histogram by the build
+    ``impl`` names: the one place a name becomes a build, for both growers.
+    ``chunk`` and ``precision`` are the dense build's; the scatter-add has
+    neither."""
+    if impl == "scatter":
+        return hist_scatter(bins, gh, pos, n_nodes, n_bins_total)
+    if impl == "onehot":
+        return hist_onehot(bins, gh, pos, n_nodes, n_bins_total,
+                           chunk=chunk, precision=precision)
+    raise ValueError(
+        f"unknown histogram build {impl!r}; use one of {' | '.join(HIST_IMPLS)}"
+    )

@@ -13,10 +13,8 @@ The shape-static formulation: per tree, select a FIXED budget of
 budget is a trace-time constant derived from the shard's padded block
 size), then gather ``gh`` and the binned rows down to the M-row buffer.
 ``build_tree`` / ``build_tree_lossguide`` are row-count-blind — they derive
-N from ``bins.shape`` — so the whole level loop (histogram builds,
-partition updates, sibling-subtraction child compaction,
-``select_small_child_rows``'s M//2 buffer) runs over M rows with no grower
-changes. Full-row work remains only in the once-per-tree leaf-value margin
+N from ``bins.shape`` — so the whole level loop (histogram builds, row
+routing, sibling subtraction) runs over M rows with no grower changes. Full-row work remains only in the once-per-tree leaf-value margin
 update, which reuses the eval-set tree walk (``predict_tree_binned``).
 
 Two policies (``sampling_method`` in params):

@@ -90,7 +90,7 @@ class TrainParams:
     # reg:quantileerror target quantile(s): float or list of floats
     quantile_alpha: float = 0.5
     # tpu_hist internals
-    hist_impl: str = "auto"  # auto | scatter | onehot | partition | mixed
+    hist_impl: str = "auto"  # auto | scatter | onehot
     # histogram MXU precision: auto (fast on accelerators, highest on CPU) |
     # highest (f32-exact) | fast (single bf16 pass, ~0.2% bin-sum rounding)
     hist_precision: str = "auto"
@@ -336,22 +336,17 @@ def parse_params(params: Optional[Dict[str, Any]]) -> TrainParams:
                 pass
         setattr(out, name, value)
 
-    # hist_impl names resolve through the pluggable histogram-provider
-    # registry (ops/provider.py): built-ins plus anything registered via
-    # register_histogram_provider (the bench A/B hook). The import is
-    # function-level so this module stays importable pre-jax.
-    from xgboost_ray_tpu.ops.provider import available_hist_impls
+    # function-level import: this module stays importable pre-jax
+    from xgboost_ray_tpu.ops.histogram import HIST_IMPLS
 
-    known_impls = available_hist_impls()
+    known_impls = ("auto",) + HIST_IMPLS
     if out.hist_impl not in known_impls:
         extra = ""
-        if out.hist_impl == "pallas":
-            # removed in r5: on-chip measurement showed the hand-written
-            # kernel ~1.4x slower than the identical-layout XLA einsum —
-            # see ops/grow.py's module docstring for the full rationale
+        if out.hist_impl in ("partition", "mixed", "pallas"):
             extra = (
-                " The Pallas kernel was removed after losing to the XLA "
-                "formulation on-chip; 'mixed' covers its niche."
+                f" {out.hist_impl!r} built from node-sorted row blocks, lost "
+                "every on-chip reading to the dense build and was removed: "
+                "'onehot' (the chip's 'auto') takes its place at every depth."
             )
         raise ValueError(
             f"Unknown hist_impl {out.hist_impl!r}; use one of "
@@ -594,7 +589,7 @@ def parse_params(params: Optional[Dict[str, Any]]) -> TrainParams:
 # candidates ("lanes") through it under jax.vmap. A param can ride the lane
 # axis only if the round body consumes it ARITHMETICALLY (a traced scalar
 # works) — anything that changes trace-time structure (shapes, loop extents,
-# provider choice, objective kernel) forces a separate compile and is NOT
+# histogram build, objective kernel) forces a separate compile and is NOT
 # lane-vectorizable. The split is enforced loudly here (the repo's
 # no-silent-fallback invariant: a lane must never silently train with a
 # neighbor's params).
