@@ -51,6 +51,115 @@ def test_binning_device_matches_host():
     np.testing.assert_array_equal(host, dev)
 
 
+def _awkward_rows(max_bin, n=600):
+    """Rows and cuts that sit on every edge of ``bin(x) = #cuts <= x``:
+    column 0 continuous with NaN, +-inf, +-0.0, the first and last cut, a
+    value below the first and one above the last; 1 few distinct values
+    (the sketch repeats cuts); 2 constant; 3 categorical codes under code
+    cuts ``k + 0.5``; 4 every one of its own cuts, once each; 5 all NaN."""
+    rng = np.random.RandomState(max_bin)
+    x = rng.randn(n, 6).astype(np.float32)
+    x[:, 1] = np.round(x[:, 1])
+    x[:, 2] = 3.0
+    x[:, 3] = rng.randint(0, min(max_bin, 7), n)
+    cuts = binning.sketch_cuts_np(x, max_bin=max_bin)
+    cuts[3] = np.arange(max_bin - 1, dtype=np.float32) + 0.5
+    x[rng.rand(n, 6) < 0.05] = np.nan
+    first, last = cuts[0, 0], cuts[0, -1]
+    x[:10, 0] = [np.inf, -np.inf, np.nan, -0.0, 0.0, first, last,
+                 np.nextafter(first, -np.inf), np.nextafter(last, np.inf),
+                 np.nextafter(last, -np.inf)]
+    own = cuts[4][: n - 10]
+    x[10:10 + own.size, 4] = own
+    x[:, 5] = np.nan
+    return x, cuts
+
+
+@pytest.mark.parametrize("max_bin", [8, 255, 256, 512])
+def test_device_binning_counts_cuts_like_the_host_loop(max_bin):
+    """``bin_matrix`` against the per-feature ``np.searchsorted`` loop, bit
+    for bit and in the same dtype (uint8 through ``max_bin`` 255, int16
+    beyond), eagerly and under ``jit``."""
+    x, cuts = _awkward_rows(max_bin)
+    assert np.any(np.diff(cuts[1]) == 0), "column 1 should repeat cuts"
+    want = binning._bin_matrix_np_loop(x, cuts, max_bin)
+    assert want.dtype == (np.uint8 if max_bin <= 255 else np.int16)
+    assert want[2, 0] == max_bin and np.all(want[:, 5] == max_bin)
+    assert want[0, 0] == max_bin - 1 and want[1, 0] == 0  # +inf, -inf
+    for fn in (binning.bin_matrix, jax.jit(binning.bin_matrix, static_argnums=2)):
+        got = np.asarray(fn(jnp.asarray(x), jnp.asarray(cuts), max_bin))
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def test_device_binning_with_unordered_nan_cuts():
+    """A feature holding -inf gets NaN cuts from the device sketch (``mn +
+    edges * inf``) and one holding +inf gets +inf cuts: the count still
+    equals the host's search, which sorts NaN last."""
+    x = np.random.RandomState(5).randn(64, 2).astype(np.float32)
+    x[0, 0], x[1, 1] = -np.inf, np.inf
+    cuts = np.sort(np.random.RandomState(6).randn(2, 7).astype(np.float32), 1)
+    cuts[0], cuts[1, -3:] = np.nan, np.inf
+    got = np.asarray(binning.bin_matrix(jnp.asarray(x), jnp.asarray(cuts), 8))
+    np.testing.assert_array_equal(got, binning._bin_matrix_np_loop(x, cuts, 8))
+
+
+def _binning_programs(num_actors):
+    """The lowered-from records of ``engine.sketch_cuts`` and
+    ``engine.bin_matrix`` (an evaluation set's binning) of a small engine."""
+    from xgboost_ray_tpu import progreg
+    from xgboost_ray_tpu.engine import TpuEngine
+    from xgboost_ray_tpu.params import parse_params
+
+    rng = np.random.RandomState(7)
+    x = rng.randn(256, 5).astype(np.float32)
+    x[rng.rand(256, 5) < 0.05] = np.nan
+    y = (x[:, 0] > 0).astype(np.float32)
+    train_set = [{"data": x, "label": y}]
+    valid_set = [{"data": x[:64], "label": y[:64]}]
+    params = parse_params({"objective": "binary:logistic", "max_depth": 2,
+                           "max_bin": 256})
+    with progreg.capture():
+        progreg.clear()
+        TpuEngine(train_set, params, num_actors,
+                  evals=[(train_set, "train"), (valid_set, "valid")])
+        recs = {r.name: r for r in progreg.records()}
+    progreg.clear()
+    return recs["engine.sketch_cuts"], recs["engine.bin_matrix"]
+
+
+def _primitives_under(jaxpr, scope, inside=False):
+    """Primitive names of every equation whose name stack holds ``scope``
+    (equations of a sub-jaxpr inherit their caller's stack)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack).split("/")
+        if here:
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _primitives_under(sub, scope, here)
+    return found
+
+
+@pytest.mark.parametrize("num_actors", [1, 4])
+def test_the_binning_programs_search_nothing(num_actors):
+    """No per-value search: what ``engine.sketch_cuts`` runs under its ``bin``
+    scope, and the whole of ``engine.bin_matrix``, holds no gather, no loop
+    and no sort -- on one device and as the 4-device mesh's program (a
+    binary search is a ``while`` of gathers from the cut table, 25.8 of the
+    26.6 s the chip took to bin 11M x 28 values; PERF.md section 6, PR 32)."""
+    sketch_cuts, bin_alone = _binning_programs(num_actors)
+    searching = {"gather", "while", "scan", "sort", "cond"}
+    under_bin = _primitives_under(
+        jax.make_jaxpr(sketch_cuts.fn)(*sketch_cuts.abstract_args).jaxpr, "bin")
+    assert {"ge", "dot_general", "psum"} <= set(under_bin), under_bin
+    assert not searching & set(under_bin), sorted(searching & set(under_bin))
+    text = jax.jit(bin_alone.fn).lower(*bin_alone.abstract_args).as_text()
+    assert "stablehlo.compare" in text and "stablehlo.dot_general" in text
+    for op in ("gather", "while", "sort", "case"):
+        assert "stablehlo." + op not in text, op
+
+
 def test_device_sketch_close_to_exact_quantiles():
     rng = np.random.RandomState(2)
     x = rng.randn(20000, 2).astype(np.float32)

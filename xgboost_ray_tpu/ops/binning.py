@@ -344,10 +344,32 @@ def cuts_from_sketch(
 
 
 def bin_matrix(x: jnp.ndarray, cuts: jnp.ndarray, max_bin: int) -> jnp.ndarray:
-    """Device-side binning. x: [N, F] float, cuts: [F, max_bin-1] -> [N, F] ints."""
-    def one_feature(col, c):
-        b = jnp.searchsorted(c, col, side="right")
-        return jnp.where(jnp.isnan(col), max_bin, b)
+    """Device-side binning. x: [N, F] float, cuts: [F, max_bin-1] -> [N, F] ints.
 
-    bins = jax.vmap(one_feature, in_axes=(1, 0), out_axes=1)(x, cuts)
-    return bins.astype(jnp.uint8 if max_bin + 1 <= 256 else jnp.int16)
+    ``bin(x) = #cuts <= x``: ``searchsorted(side="right")`` on sorted cuts,
+    exactly -- duplicated cuts, infinities and NaN cuts (which no value
+    reaches) included --, NaN values -> ``max_bin``; in :func:`bin_dtype`
+    (uint8 where ``max_bin + 1 <= 256``, else int16), which the count is
+    also kept in.
+
+    The count is one compare of every value against every cut of its feature,
+    contracted over the cuts axis with a vector of ones: no per-value search.
+    ``jnp.searchsorted`` is a ``while`` of ``log2(max_bin)`` dependent steps
+    with a ``gather`` from the cut table in each, and on a v5e those 8 x 28
+    gathers over 11M rows were 25.8 of the 26.6 s that binning 11M x 28
+    values took (0.26 s in this form; PERF.md section 6, PR 32) -- seconds
+    the first round program's lowering waited for. Written as a contraction
+    and not as ``sum(x >= cuts)`` because the CPU backend fuses the compare
+    into a dot but materialises it in front of a reduce (2.9 GB at 200,000 x
+    28 x 255); the chip's compiler makes the same reduce fusion of both and
+    never holds the ``[N, F, max_bin - 1]`` predicate. The cost is linear in
+    ``max_bin`` where the search's was logarithmic: 1,024 bins are 4x this
+    count, 2,000 features 70x, each still far under one gather pass.
+    """
+    out_dtype = bin_dtype(max_bin)
+    below = (x[:, :, None] >= cuts[None, :, :]).astype(out_dtype)
+    bins = jnp.einsum(
+        "nfc,c->nf", below, jnp.ones((cuts.shape[1],), out_dtype),
+        preferred_element_type=out_dtype,
+    )
+    return jnp.where(jnp.isnan(x), jnp.asarray(max_bin, out_dtype), bins)
