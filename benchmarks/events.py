@@ -1,7 +1,7 @@
 """The program's own events, read from a run's timeline.
 
 ``xgboost_ray_tpu.obs`` records, once training has ended, what the round
-programs counted on the device (``allreduce.bytes``, ``hist.skew_builds``):
+programs counted on the device (``allreduce.bytes``):
 a reader takes the attributes of the last event of a name. A program that
 records no such event, or not that attribute, gives ``None``, and the line
 leaves the metric out.

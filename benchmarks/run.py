@@ -15,6 +15,7 @@ import time
 _PROCESS_START = time.time()
 
 import argparse  # noqa: E402
+import collections.abc  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
@@ -193,14 +194,22 @@ def dispatch_timeline(chunk_times, clock):
     return out
 
 
-def reduce_run_trace(clock, timeline, dump_path=""):
+def reduce_run_trace(clock, timeline, dump_path="", scope_names=()):
     """The run's trace, reduced; the host's spans between dispatches come
-    from the timeline. The trace's files are deleted once read."""
+    from the timeline. ``scope_names`` are the names the program gives its
+    device scopes: the reduced trace holds the device's seconds under each
+    scope path, per device (``scopes_by_device``) and over all of them
+    (``scopes``). The trace's files are deleted once both are read."""
     import trace_reduce
+    import trace_scopes
 
-    rows_ev = (trace_reduce.read_events(clock.trace_dir)
-               if clock.trace_dir else [])
+    rows_ev, by_device, scopes_read_s = [], {}, 0.0
     if clock.trace_dir:
+        rows_ev = trace_reduce.read_events(clock.trace_dir)
+        t_scopes = time.perf_counter()
+        by_device = trace_scopes.scope_times_by_device(clock.trace_dir,
+                                                       scope_names)
+        scopes_read_s = time.perf_counter() - t_scopes
         shutil.rmtree(clock.trace_dir, ignore_errors=True)
     traced = [d for d in timeline if d["end"] > clock.trace_open
               and d["start"] < clock.trace_close]
@@ -217,6 +226,10 @@ def reduce_run_trace(clock, timeline, dump_path=""):
             json.dump(rows_ev[:DUMP_TRACE_ROWS], f)
     reduced = trace_reduce.reduce_trace(rows_ev, host_spans)
     if reduced:
+        reduced["scopes_by_device"] = by_device
+        reduced["scopes"] = trace_scopes.summed(by_device)
+        # what the second pass over the trace's file cost the run
+        reduced["scopes_read_s"] = scopes_read_s
         # the rounds whose dispatches the traced window holds whole
         reduced["rounds"] = sum(
             d["rounds"] for d in traced
@@ -287,7 +300,11 @@ def run(args, program=None, limits=None):
     per_round = traffic["mode"] == "per_round"
     clock = Clock(warmup, args.seconds, trace_rounds if args.trace else 0,
                   per_round)
-    matrices = {name: RayDMatrix(x, y) for name, (x, y) in sets.items()}
+    # a generator returns the pair (x, y) or a mapping of RayDMatrix's own
+    # keyword names; the reference and the controls get ``sets`` as it is
+    matrices = {name: (RayDMatrix(**s)
+                       if isinstance(s, collections.abc.Mapping)
+                       else RayDMatrix(*s)) for name, s in sets.items()}
     ray_params = RayParams(num_actors=cell["chips"],
                            distributed_callbacks=[clock],
                            **traffic["ray_params"])
@@ -351,7 +368,8 @@ def run(args, program=None, limits=None):
 
     line = {"attempted": done, "failed": max(0, done - n_trees)}
     if args.trace:
-        reduced = reduce_run_trace(clock, timeline, args.dump_trace)
+        reduced = reduce_run_trace(clock, timeline, args.dump_trace,
+                                   xgboost_ray_tpu.obs.DEVICE_SCOPES)
         ctx = {
             "clock": clock, "timeline": timeline, "in_window": in_window,
             "window_s": steady_s, "window_rounds": window_rounds,
@@ -367,6 +385,7 @@ def run(args, program=None, limits=None):
                 values[m["name"]] = v
         if reduced:
             summary["traced_rounds"] = reduced["rounds"]
+            summary["scopes_read_s"] = reduced["scopes_read_s"]
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = reduced["window_s"]
             line["breakdown"] = {"device_ops": reduced["device_ops"],

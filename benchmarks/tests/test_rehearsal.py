@@ -268,7 +268,8 @@ def test_mfu_is_device_time_per_traced_round():
     ctx = {"peak": peak, "window_s": 1e9, "window_rounds": 1,
            "shapes": {"rows": 11_000_000, "features": 28, "depth": 6,
                       "trees": 1},
-           "trace": {"busy_s": 24.0, "window_s": 30.0, "rounds": 5}}
+           "trace": {"busy_s": 24.0, "window_s": 30.0, "rounds": 5,
+                     "devices": 1}}
     # 2.376 GB at 819 GB/s over 4.8 s of device time a round; host time
     # (the window's 30 s, the run's 1e9) moves nothing
     assert read(ctx) == pytest.approx(100 * (2.376e9 / 819e9) / 4.8)
