@@ -1,0 +1,200 @@
+"""What tier-1 holds of the benchmark's yardstick (``benchmarks/``): the
+shapes a cell's roofline is reckoned from, the per-layer readers on hand-made
+inputs, and the way a generator's output reaches ``RayDMatrix``. Copies of
+the hand-run ``benchmarks/tests/test_contract.py`` / ``test_mesh_cell.py``
+cases that need no training run (PERF.md section 7 row 27 (a)), and the
+readers of the leaf-wise cell."""
+
+import json
+import os
+import sys
+import types
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+FIXTURES = os.path.join(BENCH, "tests", "fixtures")
+PEAK = json.load(open(os.path.join(BENCH, "peaks.json")))["TPU v5 lite"]
+MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """``benchmarks/run.py`` and its neighbours, importable by their own
+    names for the length of this module."""
+    added = [p for p in (BENCH, FIXTURES) if p not in sys.path]
+    sys.path[:0] = added
+    import run as bench_run
+    import shapes
+    import trace_scopes
+
+    yield types.SimpleNamespace(run=bench_run, shapes=shapes,
+                                trace_scopes=trace_scopes)
+    for p in added:
+        sys.path.remove(p)
+
+
+def _read(bench, name, ctx):
+    return bench.run.load_metric_reader(name)(ctx)
+
+
+@pytest.mark.parametrize("config, want", [
+    ("higgs-d6", (11_000_000, 28, 6, 1)),
+    ("higgs-d8", (11_000_000, 28, 8, 1)),
+    ("higgs-d6-dp4", (44_000_000, 28, 6, 1)),
+    # 255 leaves and no depth bound: no tree of 255 leaves has fewer levels
+    ("higgs-l255", (11_000_000, 28, 8, 1)),
+])
+def test_cell_shapes_of_the_committed_configurations(bench, config, want):
+    s = bench.shapes.cell_shapes(
+        json.load(open(os.path.join(BENCH, "configs", config + ".json"))))
+    assert (s["rows"], s["features"], s["depth"], s["trees"]) == want
+
+
+@pytest.mark.parametrize("params, depth", [
+    ({"max_depth": 0, "max_leaves": 255}, 8),
+    ({"max_depth": 0, "max_leaves": 31}, 5),
+    ({"max_leaves": 257}, 9),
+    ({"max_depth": 4, "max_leaves": 255}, 4),   # a positive depth is the bound
+    ({"max_depth": 0}, 6), ({}, 6),             # xgboost's default
+])
+def test_a_tree_bounded_by_leaves_has_levels(bench, params, depth):
+    config = {"rows": 1000, "features": 4, "params": params}
+    assert bench.shapes.cell_shapes(config)["depth"] == depth
+
+
+def test_every_cell_finds_its_files_and_readers(bench):
+    for cell in MANIFEST["workloads"]:
+        spec = bench.run.load_cell(cell["name"])
+        for key in ("generator", "reference", "controls", "limits",
+                    "nominal_round_s"):
+            assert key in spec["config"], (cell["name"], key)
+        for m in spec["per_layer"]:
+            assert callable(bench.run.load_metric_reader(m["name"]))
+    l255 = bench.run.load_cell("higgs-l255.default")
+    assert l255["config"]["params"]["max_depth"] == 0
+    assert {"grow.passes_per_round", "grow.evaluated_per_kept",
+            "grow.select_pct", "hist_roofline", "round.mfu_pct"} <= {
+        m["name"] for m in l255["per_layer"]}
+    d6 = bench.run.load_cell("higgs-d6.default")
+    assert not any(m["name"].startswith("grow.") for m in d6["per_layer"])
+    assert set(l255["config"]["limits"]) == {
+        "loss", "leaf", "cover", "split", "split_deep", "order"}
+
+
+def _mfu_ctx(devices, **trace):
+    return {"peak": PEAK,
+            "shapes": {"rows": 44_000_000, "features": 28, "depth": 6,
+                       "trees": 1},
+            "trace": dict({"busy_s": 2.16, "window_s": 2.18, "rounds": 5,
+                           "devices": devices}, **trace)}
+
+
+def test_mfu_sets_one_devices_rows_against_one_chips_peak(bench):
+    one, four = (_read(bench, "round.mfu_pct", _mfu_ctx(n)) for n in (1, 4))
+    assert four == pytest.approx(one / 4)
+    # 11M rows a device: 2.376 GB at 819 GB/s over 0.432 s of device time
+    assert four == pytest.approx(100 * (2.376e9 / 819e9) / 0.432)
+
+
+def test_hist_roofline_and_collective_time_on_hand_made_scopes(bench):
+    level = {"tree/level0/hist": 0.30, "tree/level1/hist": 0.60,
+             "tree/level1/split": 0.02, "margin": 0.01}
+    ahead = dict(level, **{"tree/level0/allreduce": 0.05,
+                           "tree/level1/allreduce": 0.015,
+                           "tree/allreduce": 0.005})
+    by_device = {"/device:TPU:0": ahead, "/device:TPU:1": level}
+    ctx = _mfu_ctx(4, scopes_by_device=by_device)
+    assert _read(bench, "hist_roofline", ctx) == pytest.approx(
+        100 * (2.376e9 / 819e9) / (0.9 / 5))
+    assert _read(bench, "collective.time_pct", ctx) == pytest.approx(7.0)
+    for trace in (None, {"scopes_by_device": {}},
+                  {"scopes_by_device": {"/device:TPU:0": {"(unscoped)": 2.}}}):
+        none = (_mfu_ctx(1, **trace) if trace
+                else dict(_mfu_ctx(1), trace=None))
+        assert _read(bench, "hist_roofline", none) is None
+        assert _read(bench, "collective.time_pct", none) is None
+
+
+def _grow_ctx(*events):
+    return {"additional_results": {"obs": {"timeline": [
+        {"kind": "event", "name": name, "t0_s": 1.0, "attrs": attrs}
+        for name, attrs in events]}}}
+
+
+def test_the_leaf_wise_readers_on_hand_made_events(bench):
+    ctx = _grow_ctx(
+        ("allreduce.bytes", {"bytes_per_round": 0}),
+        ("lossguide.grow", {"passes_per_round": 18.4,
+                            "nodes_evaluated_per_round": 661.0,
+                            "splits_per_round": 254.0, "deepest_leaf": 15}))
+    assert _read(bench, "grow.passes_per_round", ctx) == 18.4
+    # 661 nodes evaluated for the 509 a tree of 254 splits keeps
+    assert _read(bench, "grow.evaluated_per_kept", ctx) == pytest.approx(
+        661.0 / 509.0)
+    # a pass of 9 leaves in which nothing was thrown away
+    assert _read(bench, "grow.evaluated_per_kept", _grow_ctx(
+        ("lossguide.grow", {"nodes_evaluated_per_round": 17.0,
+                            "splits_per_round": 8.0}))) == 1.0
+    # the parent's program, another grower, no timeline: nothing to read
+    for nothing in (_grow_ctx(("allreduce.bytes", {"bytes_per_round": 0})),
+                    _grow_ctx(), {"additional_results": None},
+                    _grow_ctx(("lossguide.grow", {}))):
+        assert _read(bench, "grow.passes_per_round", nothing) is None
+        assert _read(bench, "grow.evaluated_per_kept", nothing) is None
+
+
+def test_select_share_is_the_busiest_devices(bench):
+    one = {"tree/level0/hist": 0.06, "tree/level/hist": 1.50,
+           "tree/level/partition": 0.20, "tree/select": 0.10,
+           "tree": 0.04, "margin": 0.10}
+    ctx = {"trace": {"scopes_by_device": {
+        "/device:TPU:0": one,
+        "/device:TPU:1": dict(one, **{"tree/select": 0.05})}}}
+    assert _read(bench, "grow.select_pct", ctx) == pytest.approx(5.0)
+    for trace in (None, {"scopes_by_device": {}},
+                  {"scopes_by_device": {"/device:TPU:0": {
+                      "tree/level1/hist": 0.4, "(unscoped)": 0.1}}}):
+        assert _read(bench, "grow.select_pct", {"trace": trace}) is None
+
+
+class _Stop(Exception):
+    pass
+
+
+def _first_matrix_call(bench, argv):
+    import xgboost_ray_tpu as real
+
+    calls = []
+
+    def matrix(*args, **kwargs):
+        calls.append((len(args), sorted(kwargs)))
+        raise _Stop
+
+    program = types.SimpleNamespace(RayParams=real.RayParams, train=None,
+                                    RayDMatrix=matrix)
+    with pytest.raises(_Stop):
+        bench.run.run(bench.run.parse(argv), program=program)
+    return calls
+
+
+def test_the_pair_form_reaches_the_matrix_as_two_arguments(bench):
+    assert _first_matrix_call(bench, [
+        "--workload", "higgs-l255.default", "--seed", "5", "--seconds", "1",
+        "--trace", "0", "--rehearse-cpu"]) == [(2, [])]
+
+
+def test_the_mapping_form_reaches_the_matrix_by_keyword(bench, monkeypatch):
+    config = json.load(open(os.path.join(FIXTURES, "groups-l31.json")))
+    monkeypatch.setattr(bench.run, "load_cell", lambda name: {
+        "cell": {"name": name, "config": "groups-l31", "traffic": "default",
+                 "chips": 1, "why": "test fixture"},
+        "config": config,
+        "traffic": json.load(open(os.path.join(BENCH, "traffic",
+                                               "default.json"))),
+        "end_to_end": MANIFEST["end_to_end"], "per_layer": []})
+    assert _first_matrix_call(bench, [
+        "--workload", "groups-l31.default", "--seed", "5", "--seconds", "1",
+        "--trace", "0", "--rehearse-cpu"]) == [
+        (0, ["data", "label", "qid", "weight"])]

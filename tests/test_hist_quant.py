@@ -252,18 +252,29 @@ def test_block_allreduce_bytes_match_ring_formula():
     assert counters["int8_block"] < counters["none"]
 
 
-def test_add_ppermute_hops_and_repeated_scope():
-    """Unit contract of the new counter term: nbytes * hops, scaled by the
-    ``repeated`` scan multiplier like every other term."""
+def test_add_ppermute_hops_and_a_loop_of_traced_length():
+    """Unit contract of the counter's ring term: nbytes * hops; and of a
+    loop whose trips the device counts (the leaf-wise grower's passes): what
+    one trip records, once however often the body is traced, times the
+    trips, joins the scalar as a traced term."""
     c = AllreduceBytes(8)
     arr = np.zeros((100,), np.int8)
     c.add_ppermute(arr)
     assert c.total == 100
     c.add_ppermute(arr, hops=7)
     assert c.total == 800
-    with c.repeated(3):
+    mark = c.mark()
+    for _ in range(2):  # a body traced twice
+        c.rewind(mark)
         c.add_ppermute(arr, hops=2)
-    assert c.total == 800 + 600
+    one_trip = c.since(mark)
+    c.rewind(mark)
+    c.add_trips(one_trip, jnp.int32(3))
+    assert one_trip == (200, 2) and (c.total, c.calls) == (800, 8)
+    assert int(c.as_scalar()) == 800 + 600
+    assert int(c.mesh_stats()[0]) == 8 + 3 * 2
+    # no loop, no traced term: the scalar is the static total's constant
+    assert int(AllreduceBytes(8).as_scalar()) == 0
 
 
 # ---------------------------------------------------------------------------

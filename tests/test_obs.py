@@ -659,7 +659,7 @@ _SCOPE_CASES = {
     "int8_gh": (dict(_PARAMS, max_depth=2, gh_precision="int8"), 0,
                 {"quantize_gh"}),
     "lossguide": (dict(_PARAMS, max_depth=3, grow_policy="lossguide",
-                       max_leaves=4), 0, set()),
+                       max_leaves=4), 0, {"select", "level", "level0"}),
 }
 
 
@@ -690,6 +690,12 @@ def test_lowered_round_programs_name_their_phases(case):
         # phases nest: a level's histogram is tree/level{d}/hist
         if case != "lossguide":
             assert "tree/level1/hist/" in text and "tree/level1/split/" in text
+        else:
+            # the leaf-wise grower's levels are a loop: the root is level0,
+            # every later pass ``level``, the best-first replay ``select``
+            assert "tree/level0/hist/" in text and "tree/select/" in text
+            assert "tree/while/body/level/while/body/hist/" in text
+            assert "tree/while/body/select/" in text
 
 
 def test_the_binning_program_names_sketch_and_bin():

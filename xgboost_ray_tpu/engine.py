@@ -47,6 +47,7 @@ from xgboost_ray_tpu.constants import (
 from xgboost_ray_tpu.models.booster import RayXGBoostBooster, stack_trees
 from xgboost_ray_tpu.ops import binning
 from xgboost_ray_tpu.ops.histogram import (
+    LOSSGUIDE_STATS,
     MESH_STATS,
     AllreduceBytes,
     counting_psum,
@@ -61,6 +62,7 @@ from xgboost_ray_tpu.ops.grow import (
     GrowConfig,
     Tree,
     build_tree,
+    map_tree,
     predict_tree_binned,
     predict_tree_binned_fsharded,
     sample_feature_mask,
@@ -299,13 +301,16 @@ class TpuEngine:
             sibling_subtract=params.sibling_subtract,
             cat_features=self._cat_features,
             grow_policy=params.grow_policy,
-            # leaf budget: 0 means depth-bounded only; a budget beyond
-            # 2^max_depth is unreachable, so cap it (keeps the frontier
-            # table minimal)
+            # leaf budget: under a depth bound 0 means "what the depth
+            # allows" and a budget beyond 2^max_depth is unreachable, so cap
+            # it (keeps the grower's tables minimal); max_depth=0 is no
+            # depth bound and the budget stands as given (params.py
+            # requires one)
             max_leaves=(
-                min(params.max_leaves or (1 << params.max_depth),
-                    1 << params.max_depth)
-                if params.grow_policy == "lossguide" else 0
+                0 if params.grow_policy != "lossguide"
+                else params.max_leaves if params.max_depth == 0
+                else min(params.max_leaves or (1 << params.max_depth),
+                         1 << params.max_depth)
             ),
         )
 
@@ -994,7 +999,7 @@ class TpuEngine:
         depth = int(init_booster.max_depth)
         missing_bin = self.params.max_bin
         cats = self.cfg.cat_features
-        forest_dev = Tree(*[jnp.asarray(f) for f in forest])
+        forest_dev = map_tree(jnp.asarray, forest)
         w_dev = jnp.asarray(np.asarray(weights, np.float32))
         # round-major tree layout: tree t -> class (t // tp) % K (the
         # predict_ops.predict_margin mapping)
@@ -2119,7 +2124,7 @@ class TpuEngine:
         if not all_trees:
             raise ValueError("empty forest")
         if self._stack_entries == len(all_trees):
-            return Tree(*[f[: self._stack_rows] for f in self._stack_buf])
+            return map_tree(lambda f: f[: self._stack_rows], self._stack_buf)
         add = stack_trees(all_trees[self._stack_entries :])
         rows = add.feature.shape[0]
         need = self._stack_rows + rows
@@ -2131,12 +2136,12 @@ class TpuEngine:
                 if self._stack_rows:
                     buf[: self._stack_rows] = self._stack_buf[i][: self._stack_rows]
                 grown.append(buf)
-            self._stack_buf = Tree(*grown)
+            self._stack_buf = type(add)(*grown)
         for i, f in enumerate(add):
             self._stack_buf[i][self._stack_rows : need] = f
         self._stack_rows = need
         self._stack_entries = len(all_trees)
-        return Tree(*[f[: self._stack_rows] for f in self._stack_buf])
+        return map_tree(lambda f: f[: self._stack_rows], self._stack_buf)
 
     def _flush_trees(self) -> None:
         """Transfer pending device forests to host with batched reads.
@@ -2223,26 +2228,51 @@ class TpuEngine:
         must call it, and every process gets the world's numbers."""
         out = {"collectives_per_round": 0, "hist_skew_fallback_builds": 0,
                "hist_sibling_builds": 0}
-        if self._mesh_stats_dev is None:
+        total = self._mesh_stats_total()
+        if total is None:
             return out
-        # one [3] a row shard; a 2D mesh repeats it along the feature axis
-        total = np.zeros(len(MESH_STATS), np.int64)
+        calls, fallback, sibling = (int(v) for v in total[:len(MESH_STATS)])
+        out["collectives_per_round"] = calls // (
+            self._mesh_stats_rounds * int(self.mesh.shape[AXIS_ACTORS]))
+        out["hist_skew_fallback_builds"] = fallback
+        out["hist_sibling_builds"] = sibling
+        return out
+
+    def lossguide_round_stats(self) -> Optional[Dict[str, int]]:
+        """``LOSSGUIDE_STATS`` summed over every round dispatched since the
+        last reset, with ``rounds``: what the leaf-wise grower counted on
+        the device (full-row passes, nodes evaluated, splits kept, wanted
+        nodes its table could not take). ``None`` for another grower or
+        before the first round. A host read like ``mesh_round_stats``."""
+        total = self._mesh_stats_total()
+        if self.cfg.grow_policy != "lossguide" or total is None:
+            return None
+        # every row shard counts the same trees: one shard's share
+        shards = int(self.mesh.shape[AXIS_ACTORS])
+        out = {name: int(v) // shards
+               for name, v in zip(LOSSGUIDE_STATS, total[len(MESH_STATS):])}
+        out["rounds"] = self._mesh_stats_rounds
+        return out
+
+    def _mesh_stats_total(self):
+        """The running sum of the dispatches' ``mesh_stats`` over every row
+        shard (and process), or ``None`` where no program returned any."""
+        if self._mesh_stats_dev is None:
+            return None
+        # one vector a row shard; a 2D mesh repeats it along the feature axis
+        width = len(MESH_STATS) + (
+            len(LOSSGUIDE_STATS) if self.cfg.grow_policy == "lossguide" else 0)
+        total = np.zeros(width, np.int64)
         for s in self._mesh_stats_dev.addressable_shards:
             if s.replica_id == 0:
-                total += np.asarray(s.data).reshape(-1, len(MESH_STATS)).sum(axis=0)
+                total += np.asarray(s.data).reshape(-1, width).sum(axis=0)
         if jax.process_count() > 1:
             from jax.experimental import multihost_utils
 
             total = np.asarray(
                 multihost_utils.process_allgather(total)
-            ).reshape(-1, len(MESH_STATS)).sum(axis=0)
-        calls, fallback, sibling = (int(v) for v in total)
-        shards = int(self.mesh.shape[AXIS_ACTORS])
-        out["collectives_per_round"] = calls // (
-            self._mesh_stats_rounds * shards)
-        out["hist_skew_fallback_builds"] = fallback
-        out["hist_sibling_builds"] = sibling
-        return out
+            ).reshape(-1, width).sum(axis=0)
+        return total
 
     def placement_record(self) -> Dict[str, Any]:
         """Where this engine runs and which chip-or-CPU defaults it
@@ -3004,6 +3034,7 @@ class TpuEngine:
             init = self._init_trees[0]
             for name in Tree._fields:
                 bufs[name][:n_init] = getattr(init, name)
+        # padded-heap trees only (params.py refuses dart with max_depth=0)
         self.dart_forest_dev = Tree(
             **{name: jnp.asarray(bufs[name]) for name in Tree._fields}
         )

@@ -489,7 +489,7 @@ def _shard_attrs(dtrain) -> Dict:
             "shard_bytes": [sizes[r] for r in sorted(sizes)]}
 
 
-def _record_engine_readouts(state, engine) -> None:
+def _record_engine_readouts(state, engine, booster) -> None:
     """Surface the engine's measured per-round collective payload bytes
     (the ``hist_quant`` traffic metric) and where the run ran in
     additional_results. Host reads, after training only — never on the
@@ -539,6 +539,37 @@ def _record_engine_readouts(state, engine) -> None:
         mesh["hist_skew_fallback_builds"])
     registry.counter("rxgb_hist_sibling_builds_total").inc(
         mesh["hist_sibling_builds"])
+    grown = engine.lossguide_round_stats()
+    if grown is None:
+        return
+    # what the leaf-wise grower counted on the device: the host sees chunk
+    # ends only, and a tree's passes are the tree's own
+    rounds = max(1, grown["rounds"])
+    attrs = {
+        "passes_per_round": grown["lossguide_passes"] / rounds,
+        "nodes_evaluated_per_round":
+            grown["lossguide_nodes_evaluated"] / rounds,
+        "splits_per_round": grown["lossguide_splits"] / rounds,
+        "deepest_leaf": int(booster.node_depths()[
+            np.asarray(booster.forest.is_leaf)].max()),
+        "table_overflows": grown["lossguide_table_overflows"],
+        "rounds": grown["rounds"],
+    }
+    tracer.event("lossguide.grow", attrs=attrs)
+    registry.counter("rxgb_lossguide_passes_total").inc(
+        grown["lossguide_passes"])
+    registry.counter("rxgb_lossguide_nodes_evaluated_total").inc(
+        grown["lossguide_nodes_evaluated"])
+    state.additional_results["lossguide_passes_per_round"] = (
+        attrs["passes_per_round"])
+    if grown["lossguide_table_overflows"]:
+        warnings.warn(
+            f"grow_policy='lossguide': {grown['lossguide_table_overflows']} "
+            f"node(s) the best-first order wanted to split found no room in "
+            f"the grower's table of evaluated nodes "
+            f"(ops/grow_lossguide.TABLE_FACTOR per leaf) and stayed leaves: "
+            f"those trees are not exactly best-first."
+        )
 
 
 def _stop_profile_if_running():
@@ -1429,7 +1460,7 @@ def _train(
             )
         _handle_queue(state.queue, state.checkpoint, callback_returns)
         state.additional_results["callback_returns"] = callback_returns
-        _record_engine_readouts(state, engine)
+        _record_engine_readouts(state, engine, booster)
         _stop_profile_if_running()
         train_time = time.time() - train_started
         return booster, evals_result, {
@@ -1612,7 +1643,7 @@ def _train(
 
     _handle_queue(state.queue, state.checkpoint, callback_returns)
     state.additional_results["callback_returns"] = callback_returns
-    _record_engine_readouts(state, engine)
+    _record_engine_readouts(state, engine, booster)
     _stop_profile_if_running()
 
     train_time = time.time() - train_started

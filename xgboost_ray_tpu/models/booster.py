@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from xgboost_ray_tpu import progreg
 from xgboost_ray_tpu.constants import AXIS_ACTORS
-from xgboost_ray_tpu.ops.grow import Tree
+from xgboost_ray_tpu.ops.grow import LinkedTree, Tree, map_tree
 from xgboost_ray_tpu.ops.objectives import get_objective
 from xgboost_ray_tpu.ops import predict as predict_ops
 from xgboost_ray_tpu.params import TrainParams
@@ -74,17 +74,20 @@ def _spmd_margin_fn(devices, k, max_depth, npt, ntree_limit, has_tw,
 
 
 def _forest_to_np(forest: Tree) -> Tree:
-    return Tree(*[np.asarray(f) for f in forest])
+    return map_tree(np.asarray, forest)
 
 
 def stack_trees(trees: List[Tree]) -> Tree:
     """Stack per-round Tree pytrees ([k, heap] each) into one [T, heap] forest."""
     if not trees:
         raise ValueError("empty forest")
-    fields = []
-    for i in range(len(trees[0])):
-        fields.append(np.concatenate([np.asarray(t[i]) for t in trees], axis=0))
-    return Tree(*fields)
+    layout = type(trees[0])
+    if any(type(t) is not layout for t in trees):
+        raise ValueError(
+            "cannot stack padded-heap trees with linked ones (a forest "
+            "grown with max_depth=0 continues with max_depth=0)")
+    return layout(*[np.concatenate([np.asarray(t[i]) for t in trees], axis=0)
+                    for i in range(len(trees[0]))])
 
 
 class RayXGBoostBooster:
@@ -126,6 +129,7 @@ class RayXGBoostBooster:
         # False only for models loaded from pre-stats serializations, whose
         # cover/base_weight were zero-filled (contributions would be garbage)
         self._has_node_stats: bool = True
+        self._linked_depth: Optional[int] = None  # cache of max_depth
 
     # -- introspection -----------------------------------------------------
 
@@ -149,8 +153,40 @@ class RayXGBoostBooster:
 
     @property
     def max_depth(self) -> int:
-        heap = self.forest.feature.shape[1]
-        return int(np.log2(heap + 1)) - 1
+        """Steps a walk needs: the heap's depth, or for a linked forest
+        (``grow_policy=lossguide, max_depth=0``) its deepest leaf."""
+        if self.forest.left is None:
+            heap = self.forest.feature.shape[1]
+            return int(np.log2(heap + 1)) - 1
+        if self._linked_depth is None:
+            self._linked_depth = max(1, int(self.node_depths().max()))
+        return self._linked_depth
+
+    def node_depths(self) -> np.ndarray:
+        """``[T, n_slots]`` depth of every node a tree has (-1: unused
+        slot), whichever layout holds it."""
+        f = self.forest
+        t, n = f.feature.shape
+        depth = np.full((t, n), -1, np.int64)
+        depth[:, 0] = 0
+        trees = np.arange(t)
+        internal = (~f.is_leaf) & (f.feature >= 0)
+        for i in range(n):  # a node's slot lies after its parent's
+            on = trees[internal[:, i] & (depth[:, i] >= 0)]
+            if not on.size:
+                continue
+            first = (np.full(on.size, 2 * i + 1) if f.left is None
+                     else f.left[on, i])
+            held = first + 1 < n
+            on, first = on[held], first[held]
+            depth[on, first] = depth[on, first + 1] = depth[on, i] + 1
+        return depth
+
+    def _children(self, t: int, idx: int) -> Tuple[int, int]:
+        """Slots of node ``idx``'s children in tree ``t``."""
+        first = (2 * idx + 1 if self.forest.left is None
+                 else int(self.forest.left[t, idx]))
+        return first, first + 1
 
     def signature(self) -> tuple:
         """Structural identity for compiled-program caching (the serve
@@ -163,7 +199,8 @@ class RayXGBoostBooster:
         return (
             "gbtree",
             int(self.forest.feature.shape[0]),  # trees
-            int(self.forest.feature.shape[1]),  # heap slots
+            int(self.forest.feature.shape[1]),  # slots a tree
+            self.forest.left is not None,  # linked layout
             self.num_features,
             self.num_outputs,
             self.max_depth,
@@ -254,7 +291,7 @@ class RayXGBoostBooster:
         """Sub-forest covering boosting rounds [begin, end)."""
         per_round = self.num_outputs * self.params.num_parallel_tree
         sl = slice(begin * per_round, end * per_round)
-        sub = Tree(*[f[sl] for f in self.forest])
+        sub = map_tree(lambda f: f[sl], self.forest)
         out = RayXGBoostBooster(
             sub, self.cuts, self.params, self.base_score, self.feature_names,
             self.feature_types,
@@ -286,7 +323,7 @@ class RayXGBoostBooster:
         )
         m0 = obj.base_score_to_margin(self.base_score)
         out = np.empty((n, k), np.float32)
-        forest_dev = Tree(*[jnp.asarray(f) for f in self.forest])
+        forest_dev = map_tree(jnp.asarray, self.forest)
         for lo in range(0, n, _PREDICT_CHUNK):
             hi = min(lo + _PREDICT_CHUNK, n)
             base = jnp.full((hi - lo, k), m0, jnp.float32)
@@ -353,7 +390,7 @@ class RayXGBoostBooster:
         mesh = Mesh(np.asarray(devices), (AXIS_ACTORS,))
         repl = NamedSharding(mesh, P())
         rows = NamedSharding(mesh, P(AXIS_ACTORS))
-        forest_dev = Tree(*[jax.device_put(np.asarray(f), repl) for f in self.forest])
+        forest_dev = map_tree(lambda f: jax.device_put(np.asarray(f), repl), self.forest)
         has_tw = self.tree_weights is not None
         tw_dev = jax.device_put(
             np.asarray(self.tree_weights, np.float32)
@@ -442,7 +479,7 @@ class RayXGBoostBooster:
                 arr.shape, repl, lambda idx: arr[idx]
             )
 
-        forest_dev = Tree(*[put_repl(np.asarray(f_)) for f_ in self.forest])
+        forest_dev = map_tree(lambda f_: put_repl(np.asarray(f_)), self.forest)
         has_tw = self.tree_weights is not None
         tw_dev = put_repl(
             np.asarray(self.tree_weights, np.float32)
@@ -523,8 +560,8 @@ class RayXGBoostBooster:
         mesh = Mesh(np.asarray(devices), (AXIS_ACTORS,))
         repl = NamedSharding(mesh, P())
         rows = NamedSharding(mesh, P(AXIS_ACTORS))
-        forest_dev = Tree(*[jax.device_put(np.asarray(f), repl)
-                            for f in self.forest])
+        forest_dev = map_tree(
+            lambda f: jax.device_put(np.asarray(f), repl), self.forest)
         tw_dev = (
             None if self.tree_weights is None
             else jax.device_put(np.asarray(self.tree_weights, np.float32),
@@ -615,7 +652,7 @@ class RayXGBoostBooster:
         self._assert_node_stats()
         n = x.shape[0]
         k = self.num_outputs
-        forest_dev = Tree(*[jnp.asarray(f) for f in self.forest])
+        forest_dev = map_tree(jnp.asarray, self.forest)
         kernel = (
             predict_ops.predict_contribs
             if approx
@@ -654,7 +691,7 @@ class RayXGBoostBooster:
         n = x.shape[0]
         k = self.num_outputs
         f1 = self.num_features + 1
-        forest_dev = Tree(*[jnp.asarray(f) for f in self.forest])
+        forest_dev = map_tree(jnp.asarray, self.forest)
         out = np.empty((n, k, f1, f1), np.float32)
         for lo in range(0, n, _SHAP_CHUNK):
             hi = min(lo + _SHAP_CHUNK, n)
@@ -715,7 +752,7 @@ class RayXGBoostBooster:
             booster = self
             if iteration_range is not None and iteration_range != (0, 0):
                 booster = self.slice_rounds(iteration_range[0], iteration_range[1])
-            forest_dev = Tree(*[jnp.asarray(f) for f in booster.forest])
+            forest_dev = map_tree(jnp.asarray, booster.forest)
             return np.asarray(
                 predict_ops.predict_leaf_index(
                     forest_dev, jnp.asarray(x), booster.max_depth,
@@ -766,7 +803,8 @@ class RayXGBoostBooster:
                 if self.tree_weights is not None
                 else np.zeros((0,), np.float32)
             ),
-            **{name: getattr(self.forest, name) for name in Tree._fields},
+            **{name: getattr(self.forest, name)
+               for name in self.forest._fields},
         )
         import dataclasses as dc
 
@@ -797,10 +835,11 @@ class RayXGBoostBooster:
             # existed; such models cannot produce contributions (see
             # _has_node_stats guard) but predict/resume normally
             has_stats = bool(d.get("has_node_stats", "base_weight" in z))
-            forest = Tree(
+            layout = LinkedTree if "left" in z else Tree
+            forest = layout(
                 **{
                     name: (z[name] if name in z else np.zeros_like(z["value"]))
-                    for name in Tree._fields
+                    for name in layout._fields
                 }
             )
             cuts = z["cuts"]
@@ -870,7 +909,8 @@ class RayXGBoostBooster:
                 if f < 0:
                     return  # unused slot
                 thr = self.forest.threshold[t, idx]
-                miss = 2 * idx + 1 if self.forest.default_left[t, idx] else 2 * idx + 2
+                yes, no = self._children(t, idx)
+                miss = yes if self.forest.default_left[t, idx] else no
                 stats = (
                     f",gain={self.forest.gain[t, idx]:.6g}"
                     f",cover={self.forest.cover[t, idx]:.6g}"
@@ -879,10 +919,10 @@ class RayXGBoostBooster:
                 )
                 lines.append(
                     f"{indent}{idx}:[f{f}<{thr:.6g}] "
-                    f"yes={2*idx+1},no={2*idx+2},missing={miss}{stats}"
+                    f"yes={yes},no={no},missing={miss}{stats}"
                 )
-                rec(2 * idx + 1, depth + 1)
-                rec(2 * idx + 2, depth + 1)
+                rec(yes, depth + 1)
+                rec(no, depth + 1)
 
             rec(0, 0)
             dumps.append("\n".join(lines) + "\n")
@@ -905,22 +945,21 @@ class RayXGBoostBooster:
                 f = int(self.forest.feature[t, idx])
                 if f < 0:
                     return None  # unused slot
-                miss = 2 * idx + 1 if bool(self.forest.default_left[t, idx]) else 2 * idx + 2
+                yes, no = self._children(t, idx)
+                miss = yes if bool(self.forest.default_left[t, idx]) else no
                 node = {
                     "nodeid": idx,
                     "depth": depth,
                     "split": f"f{f}",
                     "split_condition": float(self.forest.threshold[t, idx]),
-                    "yes": 2 * idx + 1,
-                    "no": 2 * idx + 2,
+                    "yes": yes,
+                    "no": no,
                     "missing": miss,
                 }
                 if with_stats:
                     node["gain"] = float(self.forest.gain[t, idx])
                     node["cover"] = float(self.forest.cover[t, idx])
-                children = [
-                    rec(2 * idx + 1, depth + 1), rec(2 * idx + 2, depth + 1)
-                ]
+                children = [rec(yes, depth + 1), rec(no, depth + 1)]
                 node["children"] = [c for c in children if c is not None]
                 return node
 
@@ -941,6 +980,7 @@ class RayXGBoostBooster:
                 feat = int(self.forest.feature[t, idx])
                 if not is_leaf and feat < 0:
                     continue  # unused slot
+                yes, no = self._children(t, idx)
                 rows.append({
                     "Tree": t,
                     "Node": idx,
@@ -951,12 +991,11 @@ class RayXGBoostBooster:
                         else f"f{feat}"
                     ),
                     "Split": None if is_leaf else float(self.forest.threshold[t, idx]),
-                    "Yes": None if is_leaf else f"{t}-{2 * idx + 1}",
-                    "No": None if is_leaf else f"{t}-{2 * idx + 2}",
+                    "Yes": None if is_leaf else f"{t}-{yes}",
+                    "No": None if is_leaf else f"{t}-{no}",
                     "Missing": None if is_leaf else (
-                        f"{t}-{2 * idx + 1}"
-                        if self.forest.default_left[t, idx]
-                        else f"{t}-{2 * idx + 2}"
+                        f"{t}-{yes}" if self.forest.default_left[t, idx]
+                        else f"{t}-{no}"
                     ),
                     "Gain": float(self.forest.gain[t, idx]),
                     "IsLeaf": is_leaf,

@@ -27,7 +27,8 @@ _INT_MAX = 2147483647
 def _tree_to_xgb(tree_np, t_id: int, num_feature: int,
                  learning_rate: float = 1.0,
                  leaf_scale: float = 1.0) -> Dict[str, Any]:
-    """One padded-heap tree -> xgboost compact node-array dict (BFS ids).
+    """One tree (padded heap or linked) -> xgboost compact node-array dict
+    (BFS ids).
 
     ``base_weights`` convention: xgboost stores PRE-learning-rate node
     weights (leaf value = eta * base_weight); this repo's Tree.base_weight is
@@ -50,29 +51,37 @@ def _tree_to_xgb(tree_np, t_id: int, num_feature: int,
     base_weight = np.asarray(tree_np.base_weight)
 
     heap = len(feature)
+    linked = getattr(tree_np, "left", None)
+
+    def _kids(i):
+        first = 2 * i + 1 if linked is None else int(linked[i])
+        return first, first + 1
 
     def _internal(i):
-        return (not bool(is_leaf[i])) and int(feature[i]) >= 0 and 2 * i + 2 < heap
+        return (not bool(is_leaf[i])) and int(feature[i]) >= 0 \
+            and _kids(i)[1] < heap
 
-    # BFS over reachable heap slots; compact ids in visit order (root = 0)
+    # BFS over reachable slots; compact ids in visit order (root = 0)
     ids: Dict[int, int] = {}
     order: List[int] = []
+    parent_of: Dict[int, int] = {}
     queue = deque([0])
     while queue:
         h = queue.popleft()
         ids[h] = len(order)
         order.append(h)
         if _internal(h):
-            queue.append(2 * h + 1)
-            queue.append(2 * h + 2)
+            for kid in _kids(h):
+                parent_of[kid] = h
+                queue.append(kid)
 
     n = len(order)
     left, right, parents = [], [], []
     split_idx, split_cond, dleft, losses, hess, bw = [], [], [], [], [], []
     for cid, h in enumerate(order):
         if _internal(h):
-            left.append(ids[2 * h + 1])
-            right.append(ids[2 * h + 2])
+            left.append(ids[_kids(h)[0]])
+            right.append(ids[_kids(h)[1]])
             split_idx.append(int(feature[h]))
             split_cond.append(float(threshold[h]))
             dleft.append(1 if bool(default_left[h]) else 0)
@@ -89,7 +98,7 @@ def _tree_to_xgb(tree_np, t_id: int, num_feature: int,
         if h == 0:
             parents.append(_INT_MAX)
         else:
-            parents.append(ids[(h - 1) // 2])
+            parents.append(ids[parent_of[h]])
 
     return {
         "base_weights": bw,
@@ -162,6 +171,8 @@ def objective_param_entry(params) -> Tuple[str, str, Dict[str, str]]:
 def export_xgboost_json(booster, fname: Optional[str] = None) -> str:
     """Serialize ``booster`` in the xgboost JSON model schema. Returns the
     JSON string; also writes it to ``fname`` when given."""
+    from xgboost_ray_tpu.ops.grow import map_tree
+
     booster._assert_node_stats()
     forest = booster.forest
     num_feature = booster.num_features
@@ -175,7 +186,7 @@ def export_xgboost_json(booster, fname: Optional[str] = None) -> str:
     trees = []
     tree_info = []
     for t in range(n_trees):
-        tree_np = type(forest)(*[np.asarray(f)[t] for f in forest])
+        tree_np = map_tree(lambda f: np.asarray(f)[t], forest)
         trees.append(_tree_to_xgb(tree_np, t, num_feature, learning_rate=lr,
                                   leaf_scale=1.0 / npt))
         tree_info.append((t % per_round) // npt if k > 1 else 0)
@@ -229,20 +240,16 @@ def export_xgboost_json(booster, fname: Optional[str] = None) -> str:
     return out
 
 
-def _xgb_tree_to_heap(t: Dict[str, Any],
-                      leaf_scale: float = 1.0) -> Tuple[Dict[str, np.ndarray], int]:
-    """One xgboost node-array tree -> padded-heap field dict + depth.
+#: deepest imported tree the padded heap still holds (2^(d+1) slots a
+#: tree); a deeper forest, as xgboost's lossguide grows them, is imported in
+#: the linked layout
+_HEAP_IMPORT_DEPTH = 16
 
-    ``leaf_scale`` is ``num_parallel_tree`` on import: xgboost files store
-    sum-convention leaves (core sums all trees), while this repo's predictor
-    divides each round's trees by npt — multiplying the stored values back
-    up makes both conventions produce the same margin."""
-    left = t["left_children"]
-    right = t["right_children"]
-    n = len(left)
 
-    # depth of the compact tree: node order in xgboost dumps is not
-    # guaranteed parent-before-child, so walk from the root
+def _xgb_tree_depth(t: Dict[str, Any]) -> int:
+    """Depth of one xgboost node-array tree: node order in xgboost dumps is
+    not guaranteed parent-before-child, so walk from the root."""
+    left, right = t["left_children"], t["right_children"]
     max_depth = 0
     stack = [(0, 0)]
     while stack:
@@ -251,28 +258,36 @@ def _xgb_tree_to_heap(t: Dict[str, Any],
         if left[nid] != -1:
             stack.append((left[nid], d + 1))
             stack.append((right[nid], d + 1))
-    if max_depth > 16:
-        # the padded heap is 2^(depth+1) slots per tree: a lossguide-grown
-        # xgboost model with depth 25-60 would allocate GBs/TBs — fail with
-        # the reason instead of a MemoryError deep in the allocator
-        raise ValueError(
-            f"imported tree has depth {max_depth}; the padded-heap layout "
-            f"supports depth <= 16 (2^(d+1) slots/tree). Re-train with "
-            f"bounded depth (e.g. grow_policy='depthwise', max_depth<=16)."
-        )
-    heap = (1 << (max_depth + 1)) - 1
+    return max_depth
 
+
+def _xgb_tree_to_fields(t: Dict[str, Any], n_slots: int, linked: bool,
+                        leaf_scale: float = 1.0) -> Dict[str, np.ndarray]:
+    """One xgboost node-array tree -> field dict of ``n_slots`` slots: the
+    padded heap, or (``linked``) those of ``ops.grow.LinkedTree``,
+    slots handed out breadth-first so that a node lies after its parent and
+    siblings are adjacent.
+
+    ``leaf_scale`` is ``num_parallel_tree`` on import: xgboost files store
+    sum-convention leaves (core sums all trees), while this repo's predictor
+    divides each round's trees by npt — multiplying the stored values back
+    up makes both conventions produce the same margin."""
+    left = t["left_children"]
+    right = t["right_children"]
+    n = len(left)
     fields = {
-        "feature": np.full(heap, -1, np.int32),
-        "split_bin": np.zeros(heap, np.int32),
-        "threshold": np.zeros(heap, np.float32),
-        "default_left": np.zeros(heap, bool),
-        "is_leaf": np.zeros(heap, bool),
-        "value": np.zeros(heap, np.float32),
-        "gain": np.zeros(heap, np.float32),
-        "cover": np.zeros(heap, np.float32),
-        "base_weight": np.zeros(heap, np.float32),
+        "feature": np.full(n_slots, -1, np.int32),
+        "split_bin": np.zeros(n_slots, np.int32),
+        "threshold": np.zeros(n_slots, np.float32),
+        "default_left": np.zeros(n_slots, bool),
+        "is_leaf": np.zeros(n_slots, bool),
+        "value": np.zeros(n_slots, np.float32),
+        "gain": np.zeros(n_slots, np.float32),
+        "cover": np.zeros(n_slots, np.float32),
+        "base_weight": np.zeros(n_slots, np.float32),
     }
+    if linked:
+        fields["left"] = np.zeros(n_slots, np.int32)
     sc = t["split_conditions"]
     si = t["split_indices"]
     dl = t["default_left"]
@@ -294,9 +309,10 @@ def _xgb_tree_to_heap(t: Dict[str, Any],
     if not np.isfinite(eta_scale) or eta_scale <= 0:
         eta_scale = 1.0
 
-    stack = [(0, 0)]  # (compact id, heap slot)
-    while stack:
-        nid, h = stack.pop()
+    queue = deque([(0, 0)])  # (compact id, slot)
+    handed = 1  # linked: slots handed out so far
+    while queue:
+        nid, h = queue.popleft()
         fields["cover"][h] = sh[nid]
         fields["base_weight"][h] = bw[nid] * eta_scale * leaf_scale
         if left[nid] == -1:
@@ -309,9 +325,13 @@ def _xgb_tree_to_heap(t: Dict[str, Any],
             fields["threshold"][h] = sc[nid]
             fields["default_left"][h] = bool(dl[nid])
             fields["gain"][h] = lc[nid]
-            stack.append((left[nid], 2 * h + 1))
-            stack.append((right[nid], 2 * h + 2))
-    return fields, max_depth
+            first = handed if linked else 2 * h + 1
+            if linked:
+                fields["left"][h] = first
+                handed += 2
+            queue.append((left[nid], first))
+            queue.append((right[nid], first + 1))
+    return fields
 
 
 def import_xgboost_json(data) -> "RayXGBoostBooster":
@@ -319,7 +339,7 @@ def import_xgboost_json(data) -> "RayXGBoostBooster":
     RayXGBoostBooster. Works for models written by ``export_xgboost_json``
     AND by real xgboost (gbtree/dart, numeric splits)."""
     from xgboost_ray_tpu.models.booster import RayXGBoostBooster
-    from xgboost_ray_tpu.ops.grow import Tree
+    from xgboost_ray_tpu.ops.grow import LinkedTree, Tree
     from xgboost_ray_tpu.params import TrainParams
 
     if isinstance(data, dict):
@@ -348,34 +368,22 @@ def import_xgboost_json(data) -> "RayXGBoostBooster":
 
     npt = max(1, int(
         model.get("gbtree_model_param", {}).get("num_parallel_tree", "1") or 1))
-    per_tree = [_xgb_tree_to_heap(t, leaf_scale=float(npt)) for t in trees_json]
-    max_depth = max((d for _, d in per_tree), default=1)
-    max_depth = max(max_depth, 1)
-    heap = (1 << (max_depth + 1)) - 1
-
-    def _pad(fields):
-        out = {}
-        for k, v in fields.items():
-            if len(v) < heap:
-                pad_val = -1 if k == "feature" else 0
-                padded = np.full(heap, pad_val, v.dtype)
-                # heap layout is depth-contiguous: smaller heaps are prefixes
-                padded[: len(v)] = v
-                out[k] = padded
-            else:
-                out[k] = v
-        return out
-
-    padded = [_pad(f) for f, _ in per_tree]
-    stacked = {
-        k: np.stack([p[k] for p in padded])
-        for k in per_tree[0][0]
-    } if per_tree else {
-        k: np.zeros((0, heap), np.float32) for k in (
-            "feature", "split_bin", "threshold", "default_left", "is_leaf",
-            "value", "gain", "cover", "base_weight")
-    }
-    forest = Tree(**{k: stacked[k] for k in Tree._fields})
+    depths = [_xgb_tree_depth(t) for t in trees_json]
+    max_depth = max(max(depths, default=1), 1)
+    # the padded heap is 2^(depth+1) slots a tree: a lossguide-grown xgboost
+    # model of depth 25-60 would take GBs/TBs, so a deeper forest takes the
+    # linked layout, whose slots are the nodes the widest tree has
+    linked = max_depth > _HEAP_IMPORT_DEPTH
+    n_slots = (max(len(t["left_children"]) for t in trees_json) if linked
+               else (1 << (max_depth + 1)) - 1)
+    per_tree = [_xgb_tree_to_fields(t, n_slots, linked, leaf_scale=float(npt))
+                for t in trees_json]
+    layout = LinkedTree if linked else Tree
+    forest = layout(**{
+        k: (np.stack([f[k] for f in per_tree]) if per_tree
+            else np.zeros((0, n_slots), np.float32))
+        for k in layout._fields
+    })
 
     lmp = learner["learner_model_param"]
     obj = learner.get("objective", {}).get("name", "reg:squarederror")
@@ -383,6 +391,11 @@ def import_xgboost_json(data) -> "RayXGBoostBooster":
     params.objective = obj
     params.num_class = int(lmp.get("num_class", "0") or 0)
     params.max_depth = max_depth
+    if linked:
+        # what grows such a forest here: leaf-wise, no depth bound
+        params.grow_policy = "lossguide"
+        params.max_depth = 0
+        params.max_leaves = (n_slots + 1) // 2
     params.num_parallel_tree = npt
     if weight_drop is not None:
         params.booster = "dart"

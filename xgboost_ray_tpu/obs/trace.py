@@ -108,6 +108,11 @@ TRACE_NAMES = frozenset({
     # after training, beside allreduce.bytes: the sibling builds that sat
     # in the skew fallback's window loop, and how many needed a second window
     "hist.skew_builds",
+    # after training with grow_policy=lossguide: what the leaf-wise grower
+    # counted on the device a round (full-row passes, nodes evaluated,
+    # splits kept), the forest's deepest leaf, and the wanted nodes its
+    # table had no room for
+    "lossguide.grow",
     # failure domains (main.py): domain_down when a failure takes a whole
     # domain's last alive rank (one per lost domain, beside the single
     # coalesced world.shrink), deaths_coalesced when one shrink absorbed
@@ -149,11 +154,14 @@ TRACE_NAMES = frozenset({
 #: names the device's operations by phase whatever XLA numbers its fusions.
 #: A round nests ``tree`` > ``level{d}`` > {``hist``, ``allreduce``,
 #: ``split``, ``partition``}; ``level`` stands for ``level0``, ``level1`` ...
+#: The leaf-wise grower's levels are a loop: its root is ``level0``, every
+#: later pass ``level`` (no number), and ``tree`` > ``select`` is the
+#: best-first replay between levels.
 #: :func:`xgboost_ray_tpu.obs.device.scope_times` reads them back.
 DEVICE_SCOPES = frozenset({
     "objective", "quantize_gh", "sample", "tree", "level", "hist",
-    "allreduce", "split", "partition", "margin", "eval_walk", "metrics",
-    "sketch", "bin",
+    "allreduce", "split", "partition", "select", "margin", "eval_walk",
+    "metrics", "sketch", "bin",
 })
 
 

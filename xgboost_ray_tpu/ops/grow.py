@@ -79,12 +79,14 @@ def route_right_binned(bin_vals, split_bin, default_left, is_cat, missing_bin):
     return jnp.where(bin_vals == missing_bin, ~default_left, present_right)
 
 
-def lookup_by_node(pos: jnp.ndarray, *tables: jnp.ndarray) -> list:
+def lookup_by_node(pos: jnp.ndarray, *tables: jnp.ndarray, keys=None) -> list:
     """``[t[pos] for t in tables]`` for node-sized tables (each ``[n_nodes]``:
     bool, int32 or float32) without a per-row gather: ``pos`` is compared with
     the node slots once, each table's words are selected under the hit and one
     variadic reduce over the slots yields every table's row vector, so the
-    ``[n_nodes, N]`` hit mask lives only inside the fusion. Values travel as
+    ``[n_nodes, N]`` hit mask lives only inside the fusion. With ``keys``
+    (``[n_nodes]`` distinct int32) slot ``j`` is the one whose key equals
+    ``pos``, not ``j`` itself (the leaf-wise grower's node ids of one pass). Values travel as
     int32 bit patterns and exactly one slot hits a row, so the result is
     bitwise ``t[pos]`` (no float add touches an f32 value). XLA's TPU gather
     moves one element at a time; at 11M rows on a v5e the five lookups of a
@@ -92,7 +94,9 @@ def lookup_by_node(pos: jnp.ndarray, *tables: jnp.ndarray) -> list:
     against 170-560 ms as gathers, so every fan-out takes this form. A
     ``pos`` outside ``[0, n_nodes)`` reads 0 / False."""
     n_nodes = tables[0].shape[0]
-    hit = jnp.arange(n_nodes, dtype=pos.dtype)[:, None] == pos[None, :]
+    if keys is None:
+        keys = jnp.arange(n_nodes, dtype=pos.dtype)
+    hit = keys[:, None] == pos[None, :]
     words = tuple(
         jnp.where(hit, _as_word(t)[:, None], jnp.int32(0)) for t in tables
     )
@@ -272,7 +276,10 @@ class GrowConfig:
 
 
 class Tree(NamedTuple):
-    """One decision tree in padded-heap layout; all arrays [heap_size]."""
+    """One decision tree in padded-heap layout; all arrays ``[heap_size]``
+    (``2^(max_depth+1) - 1`` slots, the children of ``i`` at ``2i + 1`` /
+    ``2i + 2``): every depth-bounded tree. ``LinkedTree`` is the layout of a
+    tree no depth bounds; every walk takes both (``child_index``)."""
 
     feature: jnp.ndarray  # int32, -1 if leaf/unused
     split_bin: jnp.ndarray  # int32, rows with bin <= split_bin go left
@@ -285,6 +292,64 @@ class Tree(NamedTuple):
     base_weight: jnp.ndarray  # float32 lr-scaled leaf_weight of EVERY node
     #   (internal nodes included) — the E[f(x)|node] estimate Saabas/SHAP
     #   path attribution needs; equals `value` at real leaves
+
+    @property
+    def left(self):
+        """A heap's children need no pointer (``LinkedTree.left``)."""
+        return None
+
+
+class LinkedTree(NamedTuple):
+    """One decision tree in linked layout: ``Tree``'s fields over ``2 *
+    max_leaves - 1`` slots and ``left``, the slot of a node's left child, its
+    right child the next one, a node always after its parent. What a
+    leaf-wise tree that no depth bounds comes back in
+    (``grow_policy=lossguide, max_depth=0``), its slots in the order
+    best-first growth made them: the ``t``-th split's children are ``1 + 2t``
+    and ``2 + 2t``, as xgboost numbers them."""
+
+    feature: jnp.ndarray
+    split_bin: jnp.ndarray
+    threshold: jnp.ndarray
+    default_left: jnp.ndarray
+    is_leaf: jnp.ndarray
+    value: jnp.ndarray
+    gain: jnp.ndarray
+    cover: jnp.ndarray
+    base_weight: jnp.ndarray
+    left: jnp.ndarray  # int32 slot of the left child (0 at a leaf)
+
+
+def map_tree(fn, tree):
+    """``fn`` over the arrays of a tree or forest, in its own layout."""
+    return type(tree)(*map(fn, tree))
+
+
+def child_index(tree: Tree, idx, go_right):
+    """Slot of the child a row at slot ``idx`` steps to: the one walk rule of
+    both layouts."""
+    first = 2 * idx + 1 if tree.left is None else tree.left[idx]
+    return first + go_right.astype(jnp.int32)
+
+
+def walk_to_leaf(tree: Tree, idx, max_depth: int, go_right_at):
+    """Leaf slot of every row from its slot ``idx`` (the root's zeros);
+    ``go_right_at(idx)`` routes the rows at their current slots.
+    ``max_depth`` steps where the depth is known (any heap tree; a linked
+    forest whose depth the caller measured), else (0) steps until every row
+    sits in a leaf."""
+
+    def step(idx):
+        nxt = child_index(tree, idx, go_right_at(idx))
+        return jnp.where(tree.is_leaf[idx], idx, nxt)
+
+    if max_depth > 0:
+        for _ in range(max_depth):
+            idx = step(idx)
+        return idx
+    return jax.lax.while_loop(
+        lambda i: ~jnp.all(tree.is_leaf[i]), step, idx
+    )
 
 
 def empty_tree(heap_size: int) -> Tree:
@@ -368,9 +433,10 @@ def build_tree(
     hist_ar = _in_scope("allreduce", hist_ar)
     if cfg.grow_policy == "lossguide":
         if depth_limit is not None:
-            # lossguide's frontier scan has no per-level structure to mask;
-            # vmapped-K lanes must share max_depth under lossguide (the
-            # engine/params validation names the key before tracing)
+            # lossguide's levels are a loop of traced length with no
+            # per-level structure to mask; vmapped-K lanes must share
+            # max_depth under lossguide (the engine/params validation names
+            # the key before tracing)
             raise NotImplementedError(
                 "depth_limit (per-lane max_depth) is not supported with "
                 "grow_policy='lossguide'"
@@ -801,22 +867,23 @@ def predict_tree_binned(
     """Walk one tree over pre-binned rows; returns leaf value per row [N].
 
     Used during training to update eval-set margins with each new tree
-    without leaving the device.
+    without leaving the device. ``max_depth`` 0 (a linked tree no depth
+    bounds) walks until every row sits in a leaf.
     """
     n, num_features = bins.shape
-    idx = jnp.zeros((n,), jnp.int32)
+    root = jnp.zeros((n,), jnp.int32)
     b32 = bins.astype(jnp.int32)
     cat_mask = cat_mask_const(cat_features, num_features)
-    for _ in range(max_depth):
+
+    def go_right_at(idx):
         f = jnp.clip(tree.feature[idx], 0, num_features - 1)
         bv = jnp.take_along_axis(b32, f[:, None], axis=1)[:, 0]
-        go_right = route_right_binned(
+        return route_right_binned(
             bv, tree.split_bin[idx], tree.default_left[idx],
             None if cat_mask is None else cat_mask[f], missing_bin,
         )
-        nxt = 2 * idx + 1 + go_right.astype(jnp.int32)
-        idx = jnp.where(tree.is_leaf[idx], idx, nxt)
-    return tree.value[idx]
+
+    return tree.value[walk_to_leaf(tree, root, max_depth, go_right_at)]
 
 
 def predict_tree_binned_fsharded(
@@ -831,16 +898,15 @@ def predict_tree_binned_fsharded(
     for eval-set / sampled-build margin walks instead of replicating F).
     Routing state (idx) stays identical on every feature shard.
     """
-    n = bins.shape[0]
-    idx = jnp.zeros((n,), jnp.int32)
     cat_mask = cat_mask_const(cat_features, fshard.f_padded)
-    for _ in range(max_depth):
+
+    def go_right_at(idx):
         f = jnp.clip(tree.feature[idx], 0, fshard.f_padded - 1)
         bv = fshard.bin_column(bins, f)
-        go_right = route_right_binned(
+        return route_right_binned(
             bv, tree.split_bin[idx], tree.default_left[idx],
             None if cat_mask is None else cat_mask[f], missing_bin,
         )
-        nxt = 2 * idx + 1 + go_right.astype(jnp.int32)
-        idx = jnp.where(tree.is_leaf[idx], idx, nxt)
-    return tree.value[idx]
+
+    root = jnp.zeros((bins.shape[0],), jnp.int32)
+    return tree.value[walk_to_leaf(tree, root, max_depth, go_right_at)]

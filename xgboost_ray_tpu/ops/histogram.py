@@ -95,6 +95,13 @@ HIST_QUANT_MIN_BYTES = 32768
 
 #: what ``AllreduceBytes.mesh_stats`` holds, in order
 MESH_STATS = ("collectives", "skew_fallback_builds", "sibling_builds")
+#: what follows them where the round grew leaf-wise trees
+#: (``note_lossguide``): full-row passes, nodes whose split was evaluated,
+#: splits kept, and wanted nodes the node table had no room to expand (0
+#: unless a tree evaluates more than ``ops.grow_lossguide.TABLE_FACTOR``
+#: nodes a leaf; such a tree is no longer exactly best-first)
+LOSSGUIDE_STATS = ("lossguide_passes", "lossguide_nodes_evaluated",
+                   "lossguide_splits", "lossguide_table_overflows")
 
 
 class AllreduceBytes:
@@ -115,9 +122,7 @@ class AllreduceBytes:
     exactly the traffic of the compiled collectives; the total is emitted
     as a device scalar next to the metrics, so the reduction of a quantized
     mode is *measured from the program that ran*, not asserted. On a
-    1-device mesh every term is zero — there is no wire. ``lax.scan``
-    bodies trace once but execute per step: growers wrap such regions in
-    ``repeated(n_steps)``.
+    1-device mesh every term is zero — there is no wire.
 
     Beside the bytes it counts what else only a mesh has, at the same call
     sites: ``calls``, the collectives a round (one per recorded op, a
@@ -126,7 +131,12 @@ class AllreduceBytes:
     0 on a 1-device axis, where there is no wire and no second shard to skew
     against. They leave the round program through ``mesh_stats``, which is
     ``None`` -- no output at all -- where the round traced neither, so a
-    one-device program is the program it was."""
+    one-device program is the program it was.
+
+    A loop of traced length (the leaf-wise grower's passes) counts through
+    ``mark`` / ``rewind`` / ``since`` / ``add_trips``: what one trip
+    records, times the trips the program counted, joins the totals as a
+    traced term."""
 
     def __init__(self, n_actors: int):
         self.n = max(1, int(n_actors))
@@ -134,16 +144,20 @@ class AllreduceBytes:
         self.calls = 0
         self.sibling_builds = 0
         self.fallback_builds = 0  # a traced int32 once a build was noted
-        self._mult = 1
+        # traced int32 terms of loops whose trip count the device decides
+        self.dyn_total = 0
+        self.dyn_calls = 0
+        self.dyn_sibling_builds = 0
+        self.lossguide = None  # traced int32 [len(LOSSGUIDE_STATS)]
 
     @staticmethod
     def _nbytes(arr) -> int:
         return int(arr.size) * arr.dtype.itemsize
 
     def _add(self, nbytes: float, calls: int = 1) -> None:
-        self.total += int(nbytes) * self._mult
+        self.total += int(nbytes)
         if self.n > 1:
-            self.calls += calls * self._mult
+            self.calls += calls
 
     def add_allreduce(self, arr) -> None:
         self._add(2 * (self.n - 1) * self._nbytes(arr) / self.n)
@@ -162,34 +176,52 @@ class AllreduceBytes:
         ring and would silently charge it as an allreduce."""
         self._add(self._nbytes(arr) * int(hops), calls=int(hops))
 
-    def note_sibling_build(self, fits) -> None:
-        """One sibling build of a shard of a mesh. ``fits`` says whether the
-        build held the shard's rows of the chosen children in one pass: the
-        dense build streams every row and always does (``True``), so
-        ``fallback_builds`` stays 0; the count is what the benchmark's
-        ``hist.skew_fallback_pct`` reads."""
+    def note_sibling_build(self, fits, times=None) -> None:
+        """One sibling build of a shard of a mesh, or ``times`` of them (a
+        traced count: the builds of a loop of traced length). ``fits`` says
+        whether the build held the shard's rows of the chosen children in
+        one pass: the dense build streams every row and always does
+        (``True``), so ``fallback_builds`` stays 0; the count is what the
+        benchmark's ``hist.skew_fallback_pct`` reads."""
         if self.n == 1:
             return
-        self.sibling_builds += self._mult
+        if times is None:
+            self.sibling_builds += 1
+        else:
+            self.dyn_sibling_builds = self.dyn_sibling_builds + times
         self.fallback_builds = self.fallback_builds + (
-            jnp.logical_not(fits).astype(jnp.int32) * self._mult
+            jnp.logical_not(fits).astype(jnp.int32)
+            * (1 if times is None else times)
         )
 
-    def repeated(self, n: int):
-        """Context manager: collectives traced inside run ``n`` times."""
-        import contextlib
+    def mark(self):
+        """The static totals now, for ``rewind`` and ``since``."""
+        return self.total, self.calls
 
-        counter = self
+    def rewind(self, mark) -> None:
+        """Forget what was recorded since ``mark``: the first line of a loop
+        body, so that a body traced twice still counts one trip."""
+        self.total, self.calls = mark
 
-        @contextlib.contextmanager
-        def scope():
-            counter._mult *= n
-            try:
-                yield
-            finally:
-                counter._mult //= n
+    def since(self, mark):
+        """``(bytes, calls)`` recorded since ``mark``: one trip's worth where
+        the loop's body began with ``rewind(mark)``."""
+        return self.total - mark[0], self.calls - mark[1]
 
-        return scope()
+    def add_trips(self, one_trip, trips) -> None:
+        """What ``one_trip`` (``since``) recorded, ``trips`` (traced) times."""
+        per_total, per_calls = one_trip
+        if per_total:
+            self.dyn_total = self.dyn_total + trips * jnp.int32(per_total)
+        if per_calls:
+            self.dyn_calls = self.dyn_calls + trips * jnp.int32(per_calls)
+
+    def note_lossguide(self, *stats) -> None:
+        """One leaf-wise tree's ``LOSSGUIDE_STATS`` (traced int32 each, in
+        that order), added to the round's."""
+        stats = jnp.stack([jnp.asarray(v, jnp.int32) for v in stats])
+        self.lossguide = stats if self.lossguide is None else (
+            self.lossguide + stats)
 
     def absorb(self, other: Optional["AllreduceBytes"]) -> None:
         """Fold another counter's total into this one (e.g. the feature
@@ -198,22 +230,36 @@ class AllreduceBytes:
         if other is not None:
             self.total += int(other.total)
             self.calls += int(other.calls)
+            self.dyn_total = self.dyn_total + other.dyn_total
+            self.dyn_calls = self.dyn_calls + other.dyn_calls
 
     def as_scalar(self) -> jnp.ndarray:
         """The total as a device int32 (clamped; ~2 GB/round is beyond any
         real per-round payload)."""
-        return jnp.int32(min(self.total, 2**31 - 1))
+        return _plus(jnp.int32(min(self.total, 2**31 - 1)), self.dyn_total)
 
     def mesh_stats(self) -> Optional[jnp.ndarray]:
-        """``MESH_STATS`` of this shard's round as int32 ``[3]``, or ``None``
+        """``MESH_STATS`` of this shard's round as int32 ``[3]``, followed by
+        its ``LOSSGUIDE_STATS`` where it grew leaf-wise trees, or ``None``
         where there is nothing to say (see the class's text)."""
-        if not self.calls and not self.sibling_builds:
+        if (not self.calls and not self.sibling_builds
+                and self.lossguide is None):
             return None
-        return jnp.stack([
-            jnp.int32(self.calls),
+        stats = jnp.stack([
+            _plus(jnp.int32(self.calls), self.dyn_calls),
             jnp.asarray(self.fallback_builds, jnp.int32),
-            jnp.int32(self.sibling_builds),
+            _plus(jnp.int32(self.sibling_builds), self.dyn_sibling_builds),
         ])
+        if self.lossguide is None:
+            return stats
+        return jnp.concatenate([stats, self.lossguide])
+
+
+def _plus(static, traced):
+    """``static + traced``, and ``static`` itself where no loop of traced
+    length added a term (a program without one lowers as it did)."""
+    return static if isinstance(traced, int) and traced == 0 else (
+        static + traced)
 
 
 def counting_psum(axis_name: str, counter: Optional[AllreduceBytes]):

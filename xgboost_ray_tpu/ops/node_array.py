@@ -60,6 +60,14 @@ def forest_to_node_array(forest: Tree, max_depth: int) -> NodeForest:
     """Permute a stacked padded-heap forest (fields ``[T, heap]``) into the
     level-major node-array layout. Host-side numpy; called once per model
     at predictor construction."""
+    if forest.left is not None:
+        raise NotImplementedError(
+            "the node_array serving layout is the level-major permutation "
+            "of a padded heap; a forest in the linked layout "
+            "(grow_policy='lossguide', max_depth=0: no depth bound) has no "
+            "such heap. Serve it with layout='heap' (the walk takes both "
+            "tree layouts) or train with a positive max_depth."
+        )
     feature = np.asarray(forest.feature)
     t, heap = feature.shape
     if heap != (1 << (max_depth + 1)) - 1:

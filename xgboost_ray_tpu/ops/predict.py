@@ -4,11 +4,17 @@ TPU-native replacement for xgboost's C++ prediction kernel
 (``model.predict(local_data)`` in the reference actor,
 ``xgboost_ray/main.py:795-810``).
 
-The padded-heap tree layout (see ``grow.py``) makes prediction a fixed-length
-gather walk: ``max_depth`` steps of (feature gather, compare, child index),
-identical for every row — no data-dependent control flow, so the whole
-ensemble walk jits into one fused XLA program. Trees are vmapped; per-class
-routing for multiclass sums tree outputs round-robin into K margins.
+The tree layouts of ``grow.py`` (padded heap, or linked for a leaf-wise
+forest no depth bounds) make prediction a fixed-length gather walk:
+``max_depth`` steps of (feature gather, compare, child index), identical for
+every row — no data-dependent control flow, so the whole ensemble walk jits
+into one fused XLA program. Trees are vmapped; per-class routing for
+multiclass sums tree outputs round-robin into K margins.
+
+``predict_margin``, ``predict_leaf_index`` and ``predict_contribs`` (Saabas)
+walk both layouts through ``child_index``; exact TreeSHAP
+(``predict_contribs_exact``, ``predict_interactions``) enumerates the bottom
+slots of a padded heap and refuses a linked forest (``require_heap``).
 """
 
 import functools
@@ -17,7 +23,23 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from xgboost_ray_tpu.ops.grow import Tree, cat_mask_const as _cat_mask_const
+from xgboost_ray_tpu.ops.grow import (
+    Tree,
+    cat_mask_const as _cat_mask_const,
+    child_index,
+    walk_to_leaf,
+)
+
+
+def require_heap(forest: Tree, what: str) -> None:
+    """Refuse a linked forest where ``what`` needs the padded heap."""
+    if forest.left is not None:
+        raise NotImplementedError(
+            f"{what} is not implemented for the linked tree layout of a "
+            f"forest grown with grow_policy='lossguide', max_depth=0 (no "
+            f"depth bound): it enumerates the slots of a depth-bounded "
+            f"padded heap. Train with a positive max_depth for it."
+        )
 
 
 def _step_right(tree, idx, xv, f, cat_mask):
@@ -37,15 +59,20 @@ def _walk_one_tree(
     tree: Tree, x: jnp.ndarray, max_depth: int, cat_mask=None
 ) -> jnp.ndarray:
     """x: [N, F] raw (may contain NaN). Returns leaf values [N]."""
+    return tree.value[_leaf_of_rows(tree, x, max_depth, cat_mask)]
+
+
+def _leaf_of_rows(tree: Tree, x: jnp.ndarray, max_depth: int, cat_mask):
+    """Leaf slot of every row of raw ``x`` in one tree."""
     n, num_features = x.shape
-    idx = jnp.zeros((n,), jnp.int32)
-    for _ in range(max_depth):
+
+    def go_right_at(idx):
         f = jnp.clip(tree.feature[idx], 0, num_features - 1)
         xv = jnp.take_along_axis(x, f[:, None], axis=1)[:, 0]
-        go_right = _step_right(tree, idx, xv, f, cat_mask)
-        nxt = 2 * idx + 1 + go_right.astype(jnp.int32)
-        idx = jnp.where(tree.is_leaf[idx], idx, nxt)
-    return tree.value[idx]
+        return _step_right(tree, idx, xv, f, cat_mask)
+
+    return walk_to_leaf(tree, jnp.zeros((n,), jnp.int32), max_depth,
+                        go_right_at)
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth", "num_outputs", "num_parallel_tree", "ntree_limit", "cat_features"))
@@ -127,7 +154,7 @@ def predict_contribs(
             f = jnp.clip(tree.feature[idx], 0, num_features - 1)
             xv = jnp.take_along_axis(x, f[:, None], axis=1)[:, 0]
             go_right = _step_right(tree, idx, xv, f, cat_mask)
-            nxt = jnp.where(stepped, 2 * idx + 1 + go_right.astype(jnp.int32), idx)
+            nxt = jnp.where(stepped, child_index(tree, idx, go_right), idx)
             delta = jnp.where(
                 stepped, tree.base_weight[nxt] - tree.base_weight[idx], 0.0
             )
@@ -317,6 +344,8 @@ def predict_contribs_exact(
 
     Returns [N, K, F+1] (bias last), trees accumulated with ``lax.scan``.
     """
+    require_heap(forest, "Exact TreeSHAP (pred_contribs; approx_contribs="
+                         "True walks any layout)")
     n, num_features = x.shape
     t = forest.feature.shape[0]
     cat_mask = _cat_mask_const(cat_features, num_features)
@@ -463,6 +492,7 @@ def predict_interactions(
     contribution, the bias-bias cell absorbs the remainder of the tree
     expectation, and the grand total equals the margin.
     """
+    require_heap(forest, "SHAP interaction values (pred_interactions)")
     n, num_features = x.shape
     t = forest.feature.shape[0]
     cat_mask = _cat_mask_const(cat_features, num_features)
@@ -513,17 +543,7 @@ def predict_leaf_index(
     forest: Tree, x: jnp.ndarray, max_depth: int, cat_features: tuple = ()
 ) -> jnp.ndarray:
     """Per-tree leaf heap index for each row (xgboost pred_leaf analog). [N, T]."""
-    n, num_features = x.shape
-    cat_mask = _cat_mask_const(cat_features, num_features)
-
-    def walk(tree):
-        idx = jnp.zeros((n,), jnp.int32)
-        for _ in range(max_depth):
-            f = jnp.clip(tree.feature[idx], 0, num_features - 1)
-            xv = jnp.take_along_axis(x, f[:, None], axis=1)[:, 0]
-            go_right = _step_right(tree, idx, xv, f, cat_mask)
-            nxt = 2 * idx + 1 + go_right.astype(jnp.int32)
-            idx = jnp.where(tree.is_leaf[idx], idx, nxt)
-        return idx
-
-    return jax.vmap(walk)(forest).T
+    cat_mask = _cat_mask_const(cat_features, x.shape[1])
+    return jax.vmap(
+        lambda tree: _leaf_of_rows(tree, x, max_depth, cat_mask)
+    )(forest).T

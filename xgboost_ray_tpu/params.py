@@ -127,7 +127,10 @@ class TrainParams:
     sibling_subtract: bool = True
     # depthwise (level-wise) or lossguide (leaf-wise best-first growth)
     grow_policy: str = "depthwise"
-    # lossguide leaf budget; 0 = bounded only by max_depth (2^max_depth)
+    # lossguide leaf budget; 0 = bounded only by max_depth (2^max_depth).
+    # With lossguide, max_depth=0 means no depth bound (xgboost's meaning):
+    # the budget alone bounds the tree, which is then held in the linked
+    # layout (ops/grow.py LinkedTree)
     max_leaves: int = 0
     # per-feature monotone constraints (-1/0/+1), padded with 0 to the
     # feature count at engine time; xgboost accepts "(1,-1)" strings too
@@ -525,9 +528,9 @@ def parse_params(params: Optional[Dict[str, Any]]) -> TrainParams:
             (bool(out.monotone_constraints)
              and any(out.monotone_constraints), "monotone_constraints"),
             (bool(out.interaction_constraints), "interaction_constraints"),
-            # the lossguide grower's per-step 2-node histogram is always the
-            # one-hot MXU pass; an explicit different impl must not be
-            # silently dropped (the repo's no-silent-fallback invariant)
+            # the lossguide grower's passes are always the dense one-hot
+            # build; an explicit different impl must not be silently
+            # dropped (the repo's no-silent-fallback invariant)
             (out.hist_impl not in ("auto", "onehot"),
              f"hist_impl={out.hist_impl!r}"),
         ):
@@ -537,8 +540,24 @@ def parse_params(params: Optional[Dict[str, Any]]) -> TrainParams:
                     f"yet (level-wise only); silently ignoring it would "
                     f"change model semantics."
                 )
-    if out.max_depth < 1:
-        raise ValueError("max_depth must be >= 1 for tpu_hist")
+    if out.max_depth == 0 and out.grow_policy == "lossguide":
+        # xgboost's "no depth bound": the leaf budget alone bounds the tree,
+        # which comes back as an ``ops.grow.LinkedTree``
+        if out.max_leaves == 0:
+            raise ValueError(
+                "max_depth=0 (no depth bound) needs max_leaves > 0 with "
+                "grow_policy='lossguide': nothing else bounds the tree."
+            )
+        if out.booster == "dart":
+            raise NotImplementedError(
+                "booster='dart' with max_depth=0: the DART forest buffer "
+                "holds padded-heap trees only; give lossguide a max_depth."
+            )
+    elif out.max_depth < 1:
+        raise ValueError(
+            "max_depth must be >= 1 for tpu_hist (0, no depth bound, only "
+            "with grow_policy='lossguide' and max_leaves > 0)"
+        )
     if out.max_depth > 14:
         raise ValueError(
             f"max_depth={out.max_depth} too large for the padded-heap tpu_hist "
@@ -678,7 +697,7 @@ def vectorize_params(configs: Sequence[Dict[str, Any]]) -> LaneParams:
             len({p.max_depth for p in parsed}) > 1:
         raise NotImplementedError(
             "param 'max_depth' cannot vary across vmapped-K lanes with "
-            "grow_policy='lossguide' (the frontier scan has no per-level "
+            "grow_policy='lossguide' (its loop of levels has no per-level "
             "structure to mask); use equal depths or sequential trials."
         )
     if base0.sampling_method == "gradient_based" and \

@@ -43,7 +43,7 @@ from xgboost_ray_tpu import progreg
 from xgboost_ray_tpu.constants import AXIS_ACTORS
 from xgboost_ray_tpu.ops import node_array as node_array_ops
 from xgboost_ray_tpu.ops import predict as predict_ops
-from xgboost_ray_tpu.ops.grow import Tree
+from xgboost_ray_tpu.ops.grow import Tree, map_tree
 
 #: output kinds this layer can serve (each has a batch-path ``predict()``
 #: flag it is checked against, see the parity contract above)
@@ -165,7 +165,7 @@ class CompiledPredictor:
         else:
             dev = self.devices[0]
             put = lambda a: jax.device_put(a, dev)  # noqa: E731
-        self.forest_dev = Tree(*[put(np.asarray(f)) for f in booster.forest])
+        self.forest_dev = map_tree(lambda f: put(np.asarray(f)), booster.forest)
         if layout == "node_array":
             # the level-major permutation of the same heap; forest_dev is
             # kept alongside because contribs stays on the heap program
