@@ -1,21 +1,27 @@
 """The dense, order-free histogram build (``hist_onehot``: the node rides the
-matmul's right-hand side) against ``hist_scatter`` at every fan-out a tree of
-``max_depth <= 14`` asks for, the structural test that keeps a row order, a
-compaction and block copies out of ``build_tree``, and ``onehot`` forests
-against ``scatter``'s on one device and on the 4-device CPU mesh."""
+matmul's right-hand side, and under 64 columns the low bits of the bin index
+beside it) against ``hist_scatter`` at every fan-out a tree of
+``max_depth <= 14`` asks for and at every radix the rule can return, the rule
+itself, the structural test that keeps a row order, a compaction and block
+copies out of ``build_tree``, and ``onehot`` forests against ``scatter``'s on
+one device and on the 4-device CPU mesh."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from xgboost_ray_tpu import RayDMatrix, RayParams, train
+from xgboost_ray_tpu import RayDMatrix, RayParams, obs, train
 from xgboost_ray_tpu.ops import binning
+from xgboost_ray_tpu.ops import histogram as histogram_ops
 from xgboost_ray_tpu.ops.grow import GrowConfig, build_tree
 from xgboost_ray_tpu.ops.histogram import (
+    ONEHOT_RADICES,
     AllreduceBytes,
+    _hist_onehot,
     hist_onehot,
     hist_scatter,
+    onehot_radix,
 )
 from xgboost_ray_tpu.ops.split import SplitParams
 
@@ -25,12 +31,13 @@ N_BINS = 32
 NBT = N_BINS + 1
 
 
-def _rows(n_nodes, gh_dtype="float32", n=6000, features=7, seed=0):
+def _rows(n_nodes, gh_dtype="float32", n=6000, features=7, seed=0,
+          n_bins=N_BINS):
     """Bins with missing values, gh, and a ``pos`` that leaves a fifth of the
     rows outside ``[0, n_nodes)`` (finished rows, the bigger sibling)."""
     rng = np.random.RandomState(seed + n_nodes)
-    bins = rng.randint(0, N_BINS, size=(n, features)).astype(np.int16)
-    bins[rng.rand(n, features) < 0.1] = N_BINS  # the missing bucket
+    bins = rng.randint(0, n_bins, size=(n, features)).astype(np.int16)
+    bins[rng.rand(n, features) < 0.1] = n_bins  # the missing bucket
     if gh_dtype == "float32":
         gh = np.stack([rng.standard_normal(n) * 0.5,
                        rng.uniform(0.0, 0.25, n)], axis=1).astype(np.float32)
@@ -50,17 +57,18 @@ def _rows(n_nodes, gh_dtype="float32", n=6000, features=7, seed=0):
 FAN_OUTS = [1, 2, 16, 64, 256, 1024, 2048, 4096]
 
 
-@pytest.mark.parametrize("n_nodes", FAN_OUTS)
-@pytest.mark.parametrize("precision", ["highest", "fast"])
-def test_dense_build_matches_scatter(precision, n_nodes):
-    bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes)
-    # three scan chunks and a ragged tail
-    got = np.asarray(jax.jit(
-        lambda b, g, p: hist_onehot(b, g, p, n_nodes, NBT, chunk=2048,
-                                    precision=precision))(bins, gh, pos))
-    scatter = jax.jit(lambda b, g, p: hist_scatter(b, g, p, n_nodes, NBT))
+def _assert_matches_scatter(build, precision, n_nodes, n_bins=N_BINS,
+                            features=7):
+    """``build(bins, gh, pos)`` against the scatter-add's histogram of the
+    same rows, a bucket's error measured against the |gh| that went into
+    it."""
+    nbt = n_bins + 1
+    bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes, n_bins=n_bins,
+                                           features=features)
+    got = np.asarray(jax.jit(build)(bins, gh, pos))
+    scatter = jax.jit(lambda b, g, p: hist_scatter(b, g, p, n_nodes, nbt))
     want = np.asarray(scatter(bins, gh_ref, pos_ref))
-    assert got.shape == want.shape == (n_nodes, bins.shape[1], NBT, 2)
+    assert got.shape == want.shape == (n_nodes, bins.shape[1], nbt, 2)
     assert got.dtype == np.float32
     # a bucket's error against the |gh| that went into it; the missing
     # bucket is rebuilt by subtraction from the node total, so it carries
@@ -72,15 +80,190 @@ def test_dense_build_matches_scatter(precision, n_nodes):
     assert np.abs(want[:, :, -1, :]).max() > 0  # missing values were there
 
 
-@pytest.mark.parametrize("n_nodes", FAN_OUTS)
-def test_dense_build_is_exact_for_int8_gh(n_nodes):
-    bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes, gh_dtype="int8")
-    got = jax.jit(lambda b, g, p: hist_onehot(b, g, p, n_nodes, NBT,
-                                              chunk=2048))(bins, gh, pos)
+def _assert_exact_for_int8_gh(build, n_nodes, n_bins=N_BINS):
+    bins, gh, pos, gh_ref, pos_ref = _rows(n_nodes, gh_dtype="int8",
+                                           n_bins=n_bins)
+    got = jax.jit(build)(bins, gh, pos)
     want = hist_scatter(jnp.asarray(bins), jnp.asarray(gh_ref),
-                        jnp.asarray(pos_ref), n_nodes, NBT)
+                        jnp.asarray(pos_ref), n_nodes, n_bins + 1)
     assert got.dtype == want.dtype == jnp.int32
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n_nodes", FAN_OUTS)
+@pytest.mark.parametrize("precision", ["highest", "fast"])
+def test_dense_build_matches_scatter(precision, n_nodes):
+    # three scan chunks and a ragged tail
+    _assert_matches_scatter(
+        lambda b, g, p: hist_onehot(b, g, p, n_nodes, NBT, chunk=2048,
+                                    precision=precision),
+        precision, n_nodes)
+
+
+@pytest.mark.parametrize("n_nodes", FAN_OUTS)
+def test_dense_build_is_exact_for_int8_gh(n_nodes):
+    _assert_exact_for_int8_gh(
+        lambda b, g, p: hist_onehot(b, g, p, n_nodes, NBT, chunk=2048),
+        n_nodes)
+
+
+#: node slots under 64 matmul columns, where the rule may factor the bin
+#: index, and the regular bins: a multiple of every radix and one of none
+NARROW_FAN_OUTS = [1, 2, 4, 8, 16]
+RADIX_BINS = [N_BINS, 37]
+
+
+@pytest.mark.parametrize("n_bins", RADIX_BINS)
+@pytest.mark.parametrize("n_nodes", NARROW_FAN_OUTS)
+@pytest.mark.parametrize("radix", ONEHOT_RADICES)
+@pytest.mark.parametrize("precision", ["highest", "fast"])
+def test_dense_build_matches_scatter_at_every_radix(precision, radix, n_nodes,
+                                                    n_bins):
+    """The radix forced through the private build the rule calls: the
+    missing bucket's ``hi`` lies outside the one-hot (32 bins) or in a padded
+    ``lo`` slot that is cut off (37)."""
+    _assert_matches_scatter(
+        lambda b, g, p: _hist_onehot(b, g, p, n_nodes, n_bins + 1, 2048,
+                                     precision, radix),
+        precision, n_nodes, n_bins=n_bins)
+
+
+@pytest.mark.parametrize("n_bins", RADIX_BINS)
+@pytest.mark.parametrize("n_nodes", NARROW_FAN_OUTS)
+@pytest.mark.parametrize("radix", ONEHOT_RADICES)
+def test_dense_build_is_exact_for_int8_gh_at_every_radix(radix, n_nodes,
+                                                         n_bins):
+    _assert_exact_for_int8_gh(
+        lambda b, g, p: _hist_onehot(b, g, p, n_nodes, n_bins + 1, 2048,
+                                     "highest", radix),
+        n_nodes, n_bins=n_bins)
+
+
+@pytest.mark.parametrize("radix", [r for r in ONEHOT_RADICES if r > 1])
+def test_factored_build_tiles_wide_data(radix):
+    """More features than one batched step takes (no multiple of the tile):
+    the inner loop over feature tiles and its padded features."""
+    _assert_matches_scatter(
+        lambda b, g, p: _hist_onehot(b, g, p, 2, NBT, 2048, "highest", radix),
+        "highest", 2, features=histogram_ops._RADIX_FTILE_MAX + 5)
+
+
+def test_radix_rule_is_a_function_of_the_builds_shape():
+    """``onehot_radix`` reads (node slots, regular bins) and nothing else:
+    a power of two the build knows, 1 from 64 matmul columns on and under 64
+    bins, never past 64 columns with ``lo`` beside the node, 1 at the widths
+    nobody measured (neither 2, 4 nor a multiple of 8), and never wider with
+    more columns among the others."""
+    for nb_reg in (16, 32, 37, 64, 100, 128, 255, 256, 512, 1024):
+        last = max(ONEHOT_RADICES)
+        for n_nodes in (1, 2, 3, 4, 8, 9, 12, 16, 31, 32, 64, 128, 4096):
+            radix = onehot_radix(n_nodes, nb_reg)
+            assert radix == onehot_radix(n_nodes, nb_reg)
+            assert radix in ONEHOT_RADICES
+            assert radix * 2 * n_nodes <= 64 or radix == 1
+            if 2 * n_nodes >= 64 or nb_reg < 64:
+                assert radix == 1
+            if n_nodes > 2 and n_nodes % 4:
+                assert radix == 1
+                continue
+            assert radix <= last
+            last = radix
+    # the benchmark's shape: 256 bins, a depth-6 tree's builds and wider
+    assert [onehot_radix(n, 256) for n in (1, 2, 4, 8, 16, 32, 64)] == [
+        8, 8, 8, 4, 2, 1, 1]
+
+
+def _hist_onehot_pr35(bins, gh, pos, n_nodes, n_bins_total, chunk=8192,
+                      precision="highest"):
+    """``hist_onehot`` as it stood before the bin index was factored (PR 35),
+    kept verbatim: what radix 1 has to lower to."""
+    from xgboost_ray_tpu.ops.histogram import (
+        _append_missing, _chunk_node_sums, _einsum_precision,
+        _for_row_chunks)
+
+    _ONEHOT_FTILE_MAX = 8
+    n, num_features = bins.shape
+    nb_reg = n_bins_total - 1
+    width = 2 * n_nodes
+    prec = _einsum_precision(precision)
+    int_gh = jnp.issubdtype(gh.dtype, jnp.integer)
+    acc_dt = jnp.int32 if int_gh else jnp.float32
+    if int_gh:
+        oh_dtype = gh.dtype
+    else:
+        oh_dtype = jnp.bfloat16 if precision == "fast" else jnp.float32
+    n_ftiles = -(-num_features // _ONEHOT_FTILE_MAX)
+    ftile = -(-num_features // n_ftiles)
+    f_pad = n_ftiles * ftile - num_features
+    bin_ids = jnp.arange(nb_reg, dtype=jnp.int32)
+    node_of_col = jnp.arange(width, dtype=jnp.int32) // 2
+    col_is_hess = (jnp.arange(width, dtype=jnp.int32) % 2).astype(bool)
+
+    def chunk_step(carry, pk, bc, ghk):
+        acc, tot = carry
+        rows = pk.shape[0]
+        bct = bc.T.astype(jnp.int32)
+        if f_pad:
+            bct = jnp.pad(bct, ((0, f_pad), (0, 0)), constant_values=nb_reg)
+        ghc = ghk.astype(oh_dtype)
+        of_col = jnp.where(col_is_hess[None, :], ghc[:, 1:2], ghc[:, 0:1])
+        rhs = jnp.where(
+            pk[:, None] == node_of_col[None, :], of_col, jnp.zeros((), oh_dtype)
+        )
+
+        def ftile_step(t, acc):
+            cols = jax.lax.dynamic_slice_in_dim(bct, t * ftile, ftile, axis=0)
+            oh = (cols[:, None, :] == bin_ids[None, :, None]).astype(oh_dtype)
+            contrib = jax.lax.dot_general(
+                oh.reshape(ftile * nb_reg, rows), rhs, (((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=acc_dt,
+            )
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc,
+                jax.lax.dynamic_slice_in_dim(acc, t * ftile, ftile, axis=0)
+                + contrib.reshape(ftile, nb_reg, width),
+                t * ftile,
+                axis=0,
+            )
+
+        acc = jax.lax.fori_loop(0, n_ftiles, ftile_step, acc)
+        tot = tot + _chunk_node_sums(ghk, pk, n_nodes)
+        return acc, tot
+
+    acc0 = (
+        jnp.zeros((n_ftiles * ftile, nb_reg, width), acc_dt),
+        jnp.zeros((n_nodes, 2), acc_dt),
+    )
+    acc, node_tot = _for_row_chunks(chunk_step, acc0, chunk, pos, bins, gh)
+    hist_reg = acc[:num_features].reshape(
+        num_features, nb_reg, n_nodes, 2
+    ).transpose(2, 0, 1, 3)
+    return _append_missing(hist_reg, node_tot)
+
+
+@pytest.mark.parametrize("gh_dtype,precision", [
+    ("float32", "fast"), ("float32", "highest"), ("int8", "highest")])
+def test_radix_one_lowers_to_the_unfactored_build(gh_dtype, precision):
+    """A wide build (64 node slots: radix 1 by the rule) is the program it
+    was: the same StableHLO text, through the public entry too."""
+    n_nodes, nbt = 64, 257
+    shapes = (jax.ShapeDtypeStruct((20_000, 28), jnp.int16),
+              jax.ShapeDtypeStruct((20_000, 2), jnp.dtype(gh_dtype)),
+              jax.ShapeDtypeStruct((20_000,), jnp.int32))
+    assert onehot_radix(n_nodes, nbt - 1) == 1
+    want = jax.jit(lambda b, g, p: _hist_onehot_pr35(
+        b, g, p, n_nodes, nbt, precision=precision)).lower(*shapes).as_text()
+    for build in (
+        lambda b, g, p: hist_onehot(b, g, p, n_nodes, nbt,
+                                    precision=precision),
+        lambda b, g, p: _hist_onehot(b, g, p, n_nodes, nbt, 8192, precision,
+                                     1),
+    ):
+        assert jax.jit(build).lower(*shapes).as_text() == want
+    narrow = jax.jit(lambda b, g, p: hist_onehot(
+        b, g, p, 4, nbt, precision=precision)).lower(*shapes).as_text()
+    assert narrow != jax.jit(lambda b, g, p: _hist_onehot_pr35(
+        b, g, p, 4, nbt, precision=precision)).lower(*shapes).as_text()
 
 
 def _tree_inputs(n=40_000, features=6, seed=3):
@@ -146,6 +329,63 @@ def test_a_tree_moves_no_row(depth, sibling_subtract, shards):
     # a mesh's shard notes one sibling build a level >= 1, a lone device none
     noted = depth - 1 if sibling_subtract and shards > 1 else 0
     assert counter.sibling_builds == noted
+
+
+def _builds_by_radix():
+    reg = obs.get_registry()
+    return {r: reg.counter(f'rxgb_hist_builds_total{{radix="{r}"}}').value
+            for r in ONEHOT_RADICES}
+
+
+def test_a_traced_tree_counts_its_builds_by_radix():
+    """A depth-6 ``build_tree`` at 256 bins builds 2, 2, 4, 8, 16 and 32
+    columns (sibling subtraction halves levels 1-5): one count a level under
+    ``rxgb_hist_builds_total`` by the radix the rule gave it, and the widths
+    noted for the ``hist.builds`` event."""
+    rng = np.random.RandomState(0)
+    bins = jnp.asarray(rng.randint(0, 257, size=(512, 5)).astype(np.int16))
+    gh = jnp.asarray(rng.randn(512, 2).astype(np.float32))
+    cuts = jnp.zeros((5, 255), jnp.float32)
+    cfg = GrowConfig(max_depth=6, max_bin=256, split=SplitParams(),
+                     hist_impl="onehot", hist_precision="fast")
+    histogram_ops.pop_traced_radix()
+    before = _builds_by_radix()
+    jax.make_jaxpr(lambda *a: build_tree(*a, cfg))(bins, gh, cuts)
+    after = _builds_by_radix()
+    assert {r: after[r] - before[r] for r in ONEHOT_RADICES} == {
+        1: 0, 2: 1, 4: 1, 8: 4}
+    assert histogram_ops.pop_traced_radix() == {2: 8, 4: 8, 8: 8, 16: 4,
+                                                32: 2}
+    assert histogram_ops.pop_traced_radix() == {}
+    assert 'rxgb_hist_builds_total{radix="8"}' in (
+        obs.get_registry().prometheus_text())
+
+
+def test_train_reports_the_radix_of_every_width():
+    """After ``train()`` the timeline's ``hist.builds`` event carries the
+    radix of every width its round programs built; a deep tree's wide levels
+    took radix 1."""
+    rng = np.random.RandomState(0)
+    x = rng.randn(800, 5).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    res = {}
+    before = _builds_by_radix()
+    train({"objective": "binary:logistic", "max_depth": 8, "max_bin": 256,
+           "hist_impl": "onehot"},
+          RayDMatrix(x, y), num_boost_round=2, additional_results=res,
+          ray_params=RayParams(num_actors=1))
+    events = [r for r in res["obs"]["timeline"] if r["name"] == "hist.builds"]
+    assert len(events) == 1
+    assert events[0]["attrs"]["radix_by_width"] == {
+        "2": 8, "4": 8, "8": 8, "16": 4, "32": 2, "64": 1, "128": 1}
+    after = _builds_by_radix()
+    programs = sum(1 for r in res["obs"]["timeline"]
+                   if r["name"] == "dispatch" and r["attrs"]["first"])
+    assert programs >= 1
+    assert [after[r] - before[r] for r in (1, 2, 4, 8)] == [
+        2 * programs, programs, programs, 4 * programs]
+    assert obs.validate_trace_records(
+        res["obs"]["timeline"], known_names=obs.TRACE_NAMES) == []
 
 
 def _forest_fields(bst):

@@ -51,6 +51,7 @@ from xgboost_ray_tpu.matrix import (
     translate_shard_categories,
 )
 from xgboost_ray_tpu.models.booster import RayXGBoostBooster
+from xgboost_ray_tpu.ops import histogram as hist_ops
 from xgboost_ray_tpu.params import parse_params
 from xgboost_ray_tpu import session as session_mod
 from xgboost_ray_tpu.util import Event, Queue, restart_backoff_s
@@ -534,6 +535,17 @@ def _record_engine_readouts(state, engine, booster) -> None:
             "sibling_builds": mesh["hist_sibling_builds"],
         },
     )
+    # what ops.histogram.onehot_radix chose where this run's round programs
+    # traced a dense build (empty under hist_impl=scatter): the counters
+    # rxgb_hist_builds_total{radix=...} hold how many builds took each
+    radix_by_width = hist_ops.pop_traced_radix()
+    if radix_by_width:
+        tracer.event(
+            "hist.builds",
+            attrs={"radix_by_width": {
+                str(width): radix for width, radix in radix_by_width.items()
+            }},
+        )
     registry = obs.get_registry()
     registry.counter("rxgb_hist_skew_fallback_builds_total").inc(
         mesh["hist_skew_fallback_builds"])
@@ -733,6 +745,8 @@ def _train(
     num_actors = ray_params.num_actors
     tracer = obs.get_tracer()
     obs.watch_compiles()  # every compile of the process, on the timeline
+    # the hist.builds event reports the builds this attempt's programs trace
+    hist_ops.pop_traced_radix()
 
     # 1) create (or re-create) missing actors (mirror main.py:1129-1149)
     newly_created = 0

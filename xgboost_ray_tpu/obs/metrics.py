@@ -41,7 +41,14 @@ BUCKET_BOUNDS_MS = [
     _BUCKET_BASE_MS * _BUCKET_FACTOR ** i for i in range(_N_BUCKETS)
 ]
 
-_NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
+# a metric family's name, optionally one sample of it by its label set:
+# ``rxgb_hist_builds_total{radix="8"}`` is its own Counter in the registry
+# and one line of the family in the exposition
+_NAME_RE = re.compile(
+    r'^[a-zA-Z_:][a-zA-Z0-9_:]*'
+    r'(\{[a-zA-Z_][a-zA-Z0-9_]*="[^"\\\n]*"'
+    r'(,[a-zA-Z_][a-zA-Z0-9_]*="[^"\\\n]*")*\})?$'
+)
 
 
 def _check_name(name: str) -> str:
@@ -279,10 +286,13 @@ class MetricsRegistry:
         with self._lock:
             metrics = sorted(self._metrics.values(), key=lambda m: m.name)
         lines: List[str] = []
+        family = None
         for m in metrics:
-            if m.help:
-                lines.append(f"# HELP {m.name} {m.help}")
-            lines.append(f"# TYPE {m.name} {m.kind}")
+            if m.name.partition("{")[0] != family:  # once a labelled family
+                family = m.name.partition("{")[0]
+                if m.help:
+                    lines.append(f"# HELP {family} {m.help}")
+                lines.append(f"# TYPE {family} {m.kind}")
             if m.kind == "histogram":
                 snap = m.snapshot()
                 counts = snap["counts"]

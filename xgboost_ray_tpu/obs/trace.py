@@ -108,6 +108,9 @@ TRACE_NAMES = frozenset({
     # after training, beside allreduce.bytes: the sibling builds that sat
     # in the skew fallback's window loop, and how many needed a second window
     "hist.skew_builds",
+    # after training: the radix the dense build factored its bin index by
+    # at every width (2 * node slots) its round programs traced
+    "hist.builds",
     # after training with grow_policy=lossguide: what the leaf-wise grower
     # counted on the device a round (full-row passes, nodes evaluated,
     # splits kept), the forest's deepest leaf, and the wanted nodes its

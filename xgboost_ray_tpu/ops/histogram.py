@@ -16,7 +16,12 @@ name (params ``hist_impl``) in ``build_histogram``:
 * ``hist_onehot`` — the dense MXU build: row-chunked ``onehot(bins)ᵀ @
   (gh ⊗ onehot(node))`` matmuls, one pass over all rows at every fan-out, no
   row order; the loop over row chunks and feature tiles bounds the one-hot
-  transient. The default on an accelerator.
+  transient. Under 64 right-hand-side columns the bin index is factored,
+  ``b = hi * L + lo``: the one-hot is of ``hi`` alone (``n_bins / L`` wide)
+  and ``lo`` rides the right-hand side beside the node, so a narrow level
+  makes 48-192 elements a (row, feature) where it made 256; ``L`` comes
+  from the build's shape (``onehot_radix``), and from 64 columns on the
+  build is the unfactored one. The default on an accelerator.
 """
 
 import functools
@@ -24,6 +29,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from xgboost_ray_tpu.obs import get_registry
 
 # ---------------------------------------------------------------------------
 # Quantized histogram allreduce (``hist_quant`` in params).
@@ -570,10 +577,75 @@ def hist_scatter(
     return out.reshape(n_nodes, num_features, n_bins_total, 2)
 
 
-# most feature columns per sequential matmul step of ``hist_onehot``: a
-# [7 x 256 bins, 8192 rows] one-hot is the tile a v5e builds fastest (PERF.md
-# §6, PR 30); tiles are sized evenly so that 28 features make four of 7
+# most feature columns per sequential matmul step of ``hist_onehot`` at
+# radix 1: a [7 x 256 bins, 8192 rows] one-hot is the tile a v5e builds
+# fastest there (PERF.md §6, PR 30); tiles are sized evenly so that 28
+# features make four of 7
 _ONEHOT_FTILE_MAX = 8
+# the same at a radix L > 1, where a step is one matmul batched over its
+# features, [256 / L, 8192] x [8192, L * C] each: all 28 features in one step
+# ran 7-13% faster than tiles of 14 and 13-23% faster than tiles of 7 at the
+# widths the rule takes, and no tile up to 44M elements fell off a cliff
+# (PERF.md §6, PR 36)
+_RADIX_FTILE_MAX = 32
+#: the radices ``onehot_radix`` can return
+ONEHOT_RADICES = (1, 2, 4, 8)
+
+# width (2 * n_nodes) -> radix of every ``hist_onehot`` build traced since
+# the last ``pop_traced_radix``: trace-time bookkeeping for the ``hist.builds``
+# event, not state any program reads. One dict a process: two ``train()``
+# calls running in threads at once report each other's widths
+_traced_radix = {}
+
+
+def onehot_radix(n_nodes: int, nb_reg: int) -> int:
+    """The radix L ``hist_onehot`` factors the bin index by (``b = hi * L +
+    lo``) at a build of ``n_nodes`` node slots over ``nb_reg`` regular bins,
+    from the build's shape alone: the L of 1, 2, 4, 8 with at most 64
+    right-hand-side columns (``L * C``, C = 2 * n_nodes) that makes the
+    fewest elements a (row, feature), ``nb_reg / L`` one-hot rows and
+    ``L * C`` columns at 0.8 of a row's cost each; 1 from 64 columns on,
+    under 64 bins, and at widths other than 2, 4 and the multiples of 8
+    (a leaf-wise pass of ``max_leaves - 1`` slots): none was measured, and
+    the forms whose column blocks were no whole sublane tiles were the slow
+    ones.
+
+    Measured on a v5e at 11M x 28 x 256 bins, bf16, 8192-row chunks, ms a
+    build by L (rows) and C (``tools/bench_hist.py --radix-sweep --stages``,
+    PERF.md §6, PR 36; the rule's choice starred):
+
+        L / C      2      4      8     16     32     64    128
+        1       67.5   67.6   67.6   69.1   73.2  *81.8 *143.1
+        2       34.4   33.9   35.7   38.2  *42.4  189.6
+        4       21.4   21.9   24.1  *35.7  180.8  184.6
+        8      *15.8  *20.5  *22.5  181.0  184.0  189.0
+
+    which is about 4 + 0.25 ms a one-hot row + 0.15-0.27 ms a column: the
+    VPU makes ``nb_reg / L + L * C`` elements a (row, feature) where radix 1
+    makes ``nb_reg``. Past 64 columns this form falls off a cliff (181-190 ms
+    at 128), and the matmul's own cost would take over anyway. Under 64 bins
+    nothing was measured. With an earlier form of the right-hand side (a
+    product with the 0/1 ``lo`` compare) 64 / 1,024 bins read 12.8 / 39.9 ms
+    at 2 columns and L = 8 against 20.3 / 250.5 at L = 1.
+    """
+    width = 2 * n_nodes
+    best, least = 1, float(nb_reg)
+    if nb_reg < 64 or not (width in (2, 4) or width % 8 == 0):
+        return best
+    for radix in ONEHOT_RADICES[1:]:
+        made = nb_reg / radix + 0.8 * radix * width
+        if radix * width <= 64 and made < least:
+            best, least = radix, made
+    return best
+
+
+def pop_traced_radix() -> dict:
+    """``{width: radix}`` of the dense builds traced since the last call
+    (``main.py`` empties it when an attempt starts and reports it after
+    training as the ``hist.builds`` event)."""
+    traced = dict(sorted(_traced_radix.items()))
+    _traced_radix.clear()
+    return traced
 
 
 def hist_onehot(
@@ -586,23 +658,60 @@ def hist_onehot(
     precision: str = "highest",
 ) -> jnp.ndarray:
     """Dense, order-free MXU histogram: one pass over the rows whatever the
-    fan-out, ``hist[f, b, (node, c)] = onehot(bins[:, f])ᵀ @ (gh ⊗ onehot(pos))``.
+    fan-out, ``hist[f, b, (node, c)] = onehot(bins[:, f])ᵀ @ (gh ⊗ onehot(pos))``,
+    with the bin index factored by the radix ``onehot_radix`` gives the
+    build's shape (``_hist_onehot``). Counts the build, by radix, under
+    ``rxgb_hist_builds_total`` as it is traced."""
+    radix = onehot_radix(n_nodes, n_bins_total - 1)
+    get_registry().counter(
+        f'rxgb_hist_builds_total{{radix="{radix}"}}',
+        "dense histogram builds traced, by the radix of the bin index",
+    ).inc()
+    _traced_radix[2 * n_nodes] = radix
+    return _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk,
+                        precision, radix)
+
+
+def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
+                 radix):
+    """``hist_onehot`` at a given radix L (a power of two).
 
     Loops over row chunks (outer, ``_for_row_chunks``) and feature tiles
-    (inner). Each inner step builds the ``[ftile * n_bins, chunk]`` one-hot of
-    the REGULAR bins only -- the same at every level; missing rows match no
-    bin and are reconstructed by subtraction, see ``_append_missing`` -- and
-    contracts it over the rows against the chunk's ``[chunk, 2 * n_nodes]``
-    right-hand side, where a row's (grad, hess) sits in its node's two
-    columns. The node therefore costs matmul columns, not one-hot width:
-    compares and one-hot traffic do not grow with ``n_nodes``. The one-hot
-    has the bins leading and the rows along the minor axis: the chip keeps
-    ``bins`` feature-major, so a chunk's ``[F, chunk]`` view is free, the
-    compare broadcasts along the major axis, and the contraction is the
-    matmul's natural ``(M, K) @ (K, N)`` (3x faster on a v5e than the
-    ``[chunk, ftile * n_bins]`` orientation, PERF.md §6, PR 30).
-    Rows whose ``pos`` lies outside ``[0, n_nodes)`` and rows whose gh the
-    caller zeroed (padding, the bigger sibling) add nothing.
+    (inner). A chunk's ``[chunk, 2 * n_nodes]`` right-hand side holds a row's
+    (grad, hess) in its node's two columns: the node costs matmul columns,
+    not one-hot width, so compares and one-hot traffic do not grow with
+    ``n_nodes``. The one-hots have the bins leading and the rows along the
+    minor axis: the chip keeps ``bins`` feature-major, so a chunk's
+    ``[F, chunk]`` view is free, the compare broadcasts along the major axis,
+    and the contraction is the matmul's natural ``(M, K) @ (K, N)`` (3x
+    faster on a v5e than the ``[chunk, ftile * n_bins]`` orientation,
+    PERF.md §6, PR 30).
+
+    **L = 1** (64 columns and more): each inner step builds the
+    ``[ftile * n_bins, chunk]`` one-hot of the REGULAR bins -- the same at
+    every level -- and contracts it over the rows against the right-hand
+    side. **L > 1**: with ``b = hi * L + lo`` the low bits ride the
+    right-hand side beside the node,
+
+        hist[f, hi, lo, (node, c)] = Σ_r [hi(r, f) = hi] · [lo(r, f) = lo] · [pos(r) = node] · gh(r, c)
+
+    so a step is one matmul batched over its features: the one-hot of ``hi``
+    alone, ``[ftile, ceil(n_bins / L), chunk]``, against each feature's own
+    ``[chunk, L * C]`` right-hand side, where column ``lo * C + 2 * node + c``
+    holds a row's g or h if the row's key ``lo(r, f) * n_nodes + pos(r)`` is
+    the column's and 0 otherwise: one compare and one select an element, no
+    product of two broadcasts (which the compiler materialised at 16 columns
+    and more, 10 ms a level; PERF.md §6, PR 36). Every element is gh or 0 as
+    before, every sum over the same rows in the same dtype. The VPU then
+    makes ``n_bins / L + L * C`` elements a (row, feature) for ``n_bins``,
+    and the MXU takes L times fewer one-hot tiles.
+
+    Missing rows (bin ``n_bins``) match no regular bin -- their ``hi`` lies
+    outside the one-hot, or, where L does not divide ``n_bins``, in the last
+    ``hi``'s padded ``lo`` slots, which are cut off -- and are reconstructed
+    by subtraction, see ``_append_missing``. Rows whose ``pos`` lies outside
+    ``[0, n_nodes)`` and rows whose gh the caller zeroed (padding, the bigger
+    sibling) add nothing.
     """
     n, num_features = bins.shape
     nb_reg = n_bins_total - 1  # regular bins; bucket nb_reg == missing
@@ -623,12 +732,21 @@ def hist_onehot(
     else:
         oh_dtype = jnp.bfloat16 if precision == "fast" else jnp.float32
 
-    n_ftiles = -(-num_features // _ONEHOT_FTILE_MAX)
+    shift = radix.bit_length() - 1
+    n_hi = -(-nb_reg // radix)  # one-hot rows a feature; nb_reg at radix 1
+    n_ftiles = -(-num_features // (
+        _ONEHOT_FTILE_MAX if radix == 1 else _RADIX_FTILE_MAX))
     ftile = -(-num_features // n_ftiles)
     f_pad = n_ftiles * ftile - num_features
-    bin_ids = jnp.arange(nb_reg, dtype=jnp.int32)
+    bin_ids = jnp.arange(n_hi, dtype=jnp.int32)
     node_of_col = jnp.arange(width, dtype=jnp.int32) // 2
-    col_is_hess = (jnp.arange(width, dtype=jnp.int32) % 2).astype(bool)
+    col_is_hess = (jnp.arange(radix * width, dtype=jnp.int32) % 2).astype(bool)
+    if radix > 1:
+        # column lo * C + 2 * node + c of a feature's right-hand side
+        key_of_col = (
+            jnp.arange(radix, dtype=jnp.int32)[:, None] * n_nodes
+            + node_of_col[None, :]
+        ).reshape(-1)
 
     def chunk_step(carry, pk, bc, ghk):
         acc, tot = carry  # pk [rows], bc [rows, F] in the storage dtype, ghk [rows, 2]
@@ -641,9 +759,15 @@ def hist_onehot(
         # row's g (c = 0) or h (c = 1) where the row sits in that node
         ghc = ghk.astype(oh_dtype)
         of_col = jnp.where(col_is_hess[None, :], ghc[:, 1:2], ghc[:, 0:1])
-        rhs = jnp.where(
-            pk[:, None] == node_of_col[None, :], of_col, jnp.zeros((), oh_dtype)
-        )
+        if radix == 1:
+            rhs = jnp.where(
+                pk[:, None] == node_of_col[None, :], of_col,
+                jnp.zeros((), oh_dtype),
+            )
+        else:
+            # a row in no node slot gets a key no column has
+            node_key = jnp.where(
+                (pk >= 0) & (pk < n_nodes), pk, -radix * n_nodes)
 
         def ftile_step(t, acc):
             cols = jax.lax.dynamic_slice_in_dim(bct, t * ftile, ftile, axis=0)
@@ -661,19 +785,52 @@ def hist_onehot(
                 axis=0,
             )
 
-        acc = jax.lax.fori_loop(0, n_ftiles, ftile_step, acc)
+        def radix_step(t, acc):
+            cols = bct if n_ftiles == 1 else jax.lax.dynamic_slice_in_dim(
+                bct, t * ftile, ftile, axis=0)
+            oh = ((cols >> shift)[:, None, :] == bin_ids[None, :, None]
+                  ).astype(oh_dtype)  # [ftile, n_hi, rows]
+            # a feature's right-hand side: column lo * C + 2 * node + c holds
+            # a row's g or h where the row's own (lo, node) is the column's
+            key = (cols & (radix - 1)) * n_nodes + node_key[None, :]
+            of_f = jnp.where(
+                key[:, :, None] == key_of_col[None, None, :], of_col[None],
+                jnp.zeros((), oh_dtype),
+            )  # [ftile, rows, L * C]
+            contrib = jax.lax.dot_general(
+                oh, of_f, (((2,), (1,)), ((0,), (0,))),
+                precision=prec, preferred_element_type=acc_dt,
+            )  # [ftile, n_hi, L * C]
+            if n_ftiles == 1:  # all features in one step: no slice of acc
+                return acc + contrib
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc,
+                jax.lax.dynamic_slice_in_dim(acc, t * ftile, ftile, axis=0)
+                + contrib,
+                t * ftile,
+                axis=0,
+            )
+
+        if radix > 1 and n_ftiles == 1:
+            acc = radix_step(0, acc)
+        else:
+            acc = jax.lax.fori_loop(
+                0, n_ftiles, ftile_step if radix == 1 else radix_step, acc)
         # node totals ride the loop as one extra tiny matmul per chunk
         tot = tot + _chunk_node_sums(ghk, pk, n_nodes)
         return acc, tot
 
     acc0 = (
-        jnp.zeros((n_ftiles * ftile, nb_reg, width), acc_dt),
+        jnp.zeros((n_ftiles * ftile, n_hi, radix * width), acc_dt),
         jnp.zeros((n_nodes, 2), acc_dt),
     )
     # bins stay in the storage dtype (uint8/int16) until a chunk is read
     acc, node_tot = _for_row_chunks(chunk_step, acc0, chunk, pos, bins, gh)
+    acc = acc[:num_features]
+    if radix > 1:  # [F, hi, lo * C + col] -> [F, hi * L + lo, col]
+        acc = acc.reshape(num_features, n_hi * radix, width)[:, :nb_reg]
     # [F, nb_reg, n_nodes * 2] -> [n_nodes, F, nb_reg, 2]
-    hist_reg = acc[:num_features].reshape(
+    hist_reg = acc.reshape(
         num_features, nb_reg, n_nodes, 2
     ).transpose(2, 0, 1, 3)
     return _append_missing(hist_reg, node_tot)
