@@ -2,14 +2,17 @@
 shapes a cell's roofline is reckoned from, the per-layer readers on hand-made
 inputs, and the way a generator's output reaches ``RayDMatrix``. Copies of
 the hand-run ``benchmarks/tests/test_contract.py`` / ``test_mesh_cell.py``
-cases that need no training run (PERF.md section 7 row 27 (a)), and the
-readers of the leaf-wise cell."""
+cases that need no training run (PERF.md section 7 row 27 (a)), the
+readers of the leaf-wise cell, and what the wide cell (``epsilon-d8``)
+brought: its generator's seeding beside ``datagen``'s, its three readers, and
+``reference_wide`` / ``controls_wide`` held to ``reference`` / ``controls``."""
 
 import json
 import os
 import sys
 import types
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -45,6 +48,7 @@ def _read(bench, name, ctx):
     ("higgs-d6-dp4", (44_000_000, 28, 6, 1)),
     # 255 leaves and no depth bound: no tree of 255 leaves has fewer levels
     ("higgs-l255", (11_000_000, 28, 8, 1)),
+    ("epsilon-d8", (400_000, 2_000, 8, 1)),
 ])
 def test_cell_shapes_of_the_committed_configurations(bench, config, want):
     s = bench.shapes.cell_shapes(
@@ -198,3 +202,161 @@ def test_the_mapping_form_reaches_the_matrix_by_keyword(bench, monkeypatch):
         "--workload", "groups-l31.default", "--seed", "5", "--seconds", "1",
         "--trace", "0", "--rehearse-cpu"]) == [
         (0, ["data", "label", "qid", "weight"])]
+
+
+@pytest.mark.parametrize("module, features", [("datagen", 28),
+                                              ("datagen_wide", 210)])
+def test_a_generator_is_seeded_by_seed_and_stream(bench, module, features):
+    """The same seed gives the same rows, another stream or seed other
+    rows; a seed past 32 signed bits is taken; on the grid every value is
+    one of ``datagen.grid``'s."""
+    import importlib
+
+    import datagen
+
+    make = importlib.import_module(module).make
+    seed = 2**31 + 12
+    x, y = make(300, features, seed, stream=0, levels=257)
+    again = make(300, features, seed, stream=0, levels=257)
+    assert x.shape == (300, features) and x.dtype == np.float32
+    assert y.shape == (300,) and set(np.unique(y)) == {0.0, 1.0}
+    assert np.array_equal(x, again[0]) and np.array_equal(y, again[1])
+    assert np.isin(x, datagen.grid(257)).all()
+    for other in (make(300, features, seed, stream=1, levels=257),
+                  make(300, features, seed + 1, stream=0, levels=257),
+                  make(300, features, seed % (2**31 - 1), stream=0,
+                       levels=257)):
+        assert not np.array_equal(x, other[0])
+    # the first rows do not depend on how many are asked for
+    assert np.array_equal(make(120, features, seed, levels=257)[0], x[:120])
+    free = make(300, features, seed, stream=0, levels=None)[0]
+    assert not np.isin(free, datagen.grid(257)).all()
+
+
+def test_the_wide_label_spreads_over_its_columns(bench):
+    import datagen_wide
+
+    with pytest.raises(ValueError):
+        datagen_wide.make(10, datagen_wide.MIN_FEATURES - 1, 1)
+    x, y = datagen_wide.make(6000, datagen_wide.MIN_FEATURES, 7)
+    w = datagen_wide.linear_weights()
+    assert w.shape == (datagen_wide.LINEAR,) and w[0] > 0 > w[1]
+    # no column carries the label: the heaviest explains a few percent
+    corr = [abs(np.corrcoef(x[:, j], y)[0, 1]) for j in range(x.shape[1])]
+    assert 0.05 < max(corr) < 0.3 and np.argmax(corr) < 4
+    assert 0.4 < y.mean() < 0.6
+
+
+def _scope_ctx(*devices):
+    return {"trace": {"scopes_by_device": {
+        f"/device:TPU:{i}": times for i, times in enumerate(devices)}}}
+
+
+def test_the_wide_cells_readers_on_hand_made_inputs(bench):
+    wide = bench.run.load_cell("epsilon-d8.default")
+    assert {"hist.tile_steps_per_round", "split.time_pct",
+            "partition.time_pct", "hist_roofline", "round.mfu_pct"} <= {
+        m["name"] for m in wide["per_layer"]}
+    d8 = bench.run.load_cell("higgs-d8.default")
+    assert not {"hist.tile_steps_per_round", "split.time_pct",
+                "partition.time_pct"} & {m["name"] for m in d8["per_layer"]}
+
+    builds = ("hist.builds", {"radix_by_width": {"2": 8, "64": 1},
+                              "ftiles_by_width": {"2": 63, "64": 250},
+                              "tile_steps_per_round": 50470})
+    assert _read(bench, "hist.tile_steps_per_round",
+                 _grow_ctx(("allreduce.bytes", {}), builds)) == 50470
+    # the parent's event has the radix alone; the CPU's build records none
+    for nothing in (_grow_ctx(("hist.builds", {"radix_by_width": {"2": 8}})),
+                    _grow_ctx(), {"additional_results": None}):
+        assert _read(bench, "hist.tile_steps_per_round", nothing) is None
+
+    one = {"tree/level0/hist": 0.50, "tree/level0/split": 0.02,
+           "tree/level7/split": 0.10, "tree/level7/split/hist": 0.30,
+           "tree/level0/partition": 0.03, "tree/level7/partition": 0.03,
+           "tree": 0.01, "margin": 0.01}
+    other = dict(one, **{"tree/level7/split": 0.05,
+                         "tree/level7/partition": 0.08})
+    ctx = _scope_ctx(one, other)
+    # device 0: 0.12 of 1.00 s under split; device 1: 0.11 of 1.00 under
+    # partition: each reader gives the device where its share is largest
+    assert _read(bench, "split.time_pct", ctx) == pytest.approx(12.0)
+    assert _read(bench, "partition.time_pct", ctx) == pytest.approx(11.0)
+    for trace in (None, {"scopes_by_device": {}},
+                  {"scopes_by_device": {"/device:TPU:0": {
+                      "tree/level1/hist": 0.4, "(unscoped)": 0.1}}}):
+        assert _read(bench, "split.time_pct", {"trace": trace}) is None
+        assert _read(bench, "partition.time_pct", {"trace": trace}) is None
+
+
+def _small_wide_forest(rng, x, trees, depth):
+    """A forest over ``x``'s columns with thresholds on its values: full
+    but for one early leaf in tree 1."""
+    heap = 2 ** (depth + 1) - 1
+    inner = 2 ** depth - 1
+    forest = {k: np.zeros((trees, heap), dt) for k, dt in (
+        ("feature", np.int32), ("threshold", np.float32),
+        ("default_left", bool), ("is_leaf", bool), ("value", np.float32),
+        ("cover", np.float32))}
+    forest["feature"][:, :inner] = rng.integers(0, x.shape[1],
+                                                (trees, inner))
+    forest["threshold"][:, :inner] = np.quantile(
+        x[:, 0], rng.uniform(0.3, 0.7, (trees, inner)))
+    forest["feature"][:, inner:] = -1
+    forest["is_leaf"][:, inner:] = True
+    forest["feature"][1, 4], forest["is_leaf"][1, 4] = -1, True
+    forest["feature"][1, [9, 10]] = -1
+    forest["is_leaf"][1, 19:23] = False
+    forest["value"] = np.where(forest["is_leaf"],
+                               rng.normal(0, 0.1, (trees, heap)),
+                               0).astype(np.float32)
+    return forest
+
+
+def test_the_wide_reference_is_the_plain_reference(bench):
+    """``reference_wide.follow`` (bins through a table, a level's gains at
+    once, feature blocks in threads) against ``reference.follow`` on 5,000
+    rows x 210 columns, some of them off the grid: values, covers and losses
+    to the bit, split gaps to 1e-9 (the gain's parent term is subtracted
+    from the sum, not inside it); ``controls_wide`` likewise."""
+    import controls
+    import controls_wide
+    import datagen_wide
+    import reference
+    import reference_wide
+
+    rng = np.random.default_rng(0)
+    x, y = datagen_wide.make(5000, 210, 11, levels=257)
+    x[:60, :7] = rng.normal(size=(60, 7))
+    sets = {"train": (x, y), "valid": (x[:900] + 0, y[:900])}
+    params = {"max_depth": 4, "eta": 0.1, "min_child_weight": 1}
+    forest = _small_wide_forest(rng, x, 3, 4)
+    for kwargs in ({"split_trees": range(3)},
+                   {"split_trees": [1], "row_share": 0.5,
+                    "own_values": True},
+                   {"real": np.float32, "own_values": True}):
+        want = reference.follow(sets, forest, params, **kwargs)
+        got = reference_wide.follow(sets, forest, params, **kwargs)
+        assert np.array_equal(got["value"], want["value"])
+        assert np.array_equal(got["cover"], want["cover"])
+        assert got["loss"] == want["loss"]
+        assert got["split_gap"].keys() == want["split_gap"].keys()
+        for node, gap in want["split_gap"].items():
+            assert got["split_gap"][node] == pytest.approx(gap, rel=1e-9)
+    assert len(want["split_gap"]) == 0 and len(got["loss"]["valid"]) == 3
+
+    limits = {"loss": 1e-4, "leaf": 8e-3, "cover": 4.5e-3, "split": 1e-3,
+              "split_deep": 8e-3}
+    said = {k: list(v) for k, v in
+            reference.follow(sets, forest, params)["loss"].items()}
+    want = controls.readings(sets, forest, said, params, limits)
+    got = controls_wide.readings(sets, forest, said, params, limits)
+    assert got.keys() == want.keys() == {
+        "lowprec", "half_batch", "state_unchanged", "answer_altered"}
+    for case, numbers in want.items():
+        for name, c in numbers.items():
+            assert got[case][name]["value"] == pytest.approx(
+                c["value"], rel=1e-9), (case, name)
+    every = controls_wide.every_tree_splits(sets, forest, params)
+    assert every["numbers"].keys() == controls.every_tree_splits(
+        sets, forest, params)["numbers"].keys() == {0, 1, 2}

@@ -348,15 +348,18 @@ def test_a_traced_tree_counts_its_builds_by_radix():
     cuts = jnp.zeros((5, 255), jnp.float32)
     cfg = GrowConfig(max_depth=6, max_bin=256, split=SplitParams(),
                      hist_impl="onehot", hist_precision="fast")
-    histogram_ops.pop_traced_radix()
+    histogram_ops.pop_traced_builds()
     before = _builds_by_radix()
     jax.make_jaxpr(lambda *a: build_tree(*a, cfg))(bins, gh, cuts)
     after = _builds_by_radix()
     assert {r: after[r] - before[r] for r in ONEHOT_RADICES} == {
         1: 0, 2: 1, 4: 1, 8: 4}
-    assert histogram_ops.pop_traced_radix() == {2: 8, 4: 8, 8: 8, 16: 4,
-                                                32: 2}
-    assert histogram_ops.pop_traced_radix() == {}
+    # 512 rows are one row chunk and 5 features one tile: a step a build
+    assert histogram_ops.pop_traced_builds() == {
+        "radix_by_width": {2: 8, 4: 8, 8: 8, 16: 4, 32: 2},
+        "ftiles_by_width": {2: 1, 4: 1, 8: 1, 16: 1, 32: 1},
+        "tile_steps_per_tree": 6}
+    assert histogram_ops.pop_traced_builds() == {}
     assert 'rxgb_hist_builds_total{radix="8"}' in (
         obs.get_registry().prometheus_text())
 

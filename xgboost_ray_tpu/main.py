@@ -535,17 +535,22 @@ def _record_engine_readouts(state, engine, booster) -> None:
             "sibling_builds": mesh["hist_sibling_builds"],
         },
     )
-    # what ops.histogram.onehot_radix chose where this run's round programs
-    # traced a dense build (empty under hist_impl=scatter): the counters
-    # rxgb_hist_builds_total{radix=...} hold how many builds took each
-    radix_by_width = hist_ops.pop_traced_radix()
-    if radix_by_width:
-        tracer.event(
-            "hist.builds",
-            attrs={"radix_by_width": {
-                str(width): radix for width, radix in radix_by_width.items()
-            }},
-        )
+    # what ops.histogram.onehot_radix and onehot_ftiles chose where this
+    # run's round programs traced a dense build (empty under
+    # hist_impl=scatter): the counters rxgb_hist_builds_total{radix=...} and
+    # rxgb_hist_tile_steps_total hold how many builds took each radix and
+    # the tile steps they traced
+    builds = hist_ops.pop_traced_builds()
+    if builds:
+        attrs = {
+            key: {str(width): v for width, v in builds[key].items()}
+            for key in ("radix_by_width", "ftiles_by_width")
+        }
+        if engine.cfg.grow_policy != "lossguide":
+            # a level-wise tree's builds are its levels', each traced once
+            attrs["tile_steps_per_round"] = builds["tile_steps_per_tree"] * (
+                engine.n_outputs * max(1, engine.params.num_parallel_tree))
+        tracer.event("hist.builds", attrs=attrs)
     registry = obs.get_registry()
     registry.counter("rxgb_hist_skew_fallback_builds_total").inc(
         mesh["hist_skew_fallback_builds"])
@@ -746,7 +751,7 @@ def _train(
     tracer = obs.get_tracer()
     obs.watch_compiles()  # every compile of the process, on the timeline
     # the hist.builds event reports the builds this attempt's programs trace
-    hist_ops.pop_traced_radix()
+    hist_ops.pop_traced_builds()
 
     # 1) create (or re-create) missing actors (mirror main.py:1129-1149)
     newly_created = 0

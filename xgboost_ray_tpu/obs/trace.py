@@ -109,7 +109,8 @@ TRACE_NAMES = frozenset({
     # in the skew fallback's window loop, and how many needed a second window
     "hist.skew_builds",
     # after training: the radix the dense build factored its bin index by
-    # at every width (2 * node slots) its round programs traced
+    # and the feature tiles a row chunk took at every width (2 * node slots)
+    # its round programs traced, and a level-wise round's tile steps
     "hist.builds",
     # after training with grow_policy=lossguide: what the leaf-wise grower
     # counted on the device a round (full-row passes, nodes evaluated,

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from xgboost_ray_tpu.obs import get_registry
 from xgboost_ray_tpu.ops.histogram import (
+    begin_traced_tree,
     build_histogram,
     node_counts_dense,
     node_sums_dense,
@@ -428,6 +429,7 @@ def build_tree(
     actors axis only), the per-node winner is elected across the feature
     axis (``elect_across_feature_shards``), and the winning feature's bin
     column is owner-broadcast so row routing stays O(rows)."""
+    begin_traced_tree()
     hist_ar = hist_allreduce if hist_allreduce is not None else allreduce
     allreduce = _in_scope("allreduce", allreduce)
     hist_ar = _in_scope("allreduce", hist_ar)

@@ -591,11 +591,13 @@ _RADIX_FTILE_MAX = 32
 #: the radices ``onehot_radix`` can return
 ONEHOT_RADICES = (1, 2, 4, 8)
 
-# width (2 * n_nodes) -> radix of every ``hist_onehot`` build traced since
-# the last ``pop_traced_radix``: trace-time bookkeeping for the ``hist.builds``
-# event, not state any program reads. One dict a process: two ``train()``
-# calls running in threads at once report each other's widths
-_traced_radix = {}
+# ``builds``: width (2 * n_nodes) -> (radix, feature tiles a row chunk) of
+# every ``hist_onehot`` build traced since the last ``pop_traced_builds``;
+# ``tree_steps``: the tile steps (row chunks x feature tiles) of the builds
+# traced since a grower last began a tree. Trace-time bookkeeping for the
+# ``hist.builds`` event, not state any program reads. One record a process:
+# two ``train()`` calls running in threads at once report each other's widths
+_traced = {"builds": {}, "tree_steps": 0}
 
 
 def onehot_radix(n_nodes: int, nb_reg: int) -> int:
@@ -639,13 +641,43 @@ def onehot_radix(n_nodes: int, nb_reg: int) -> int:
     return best
 
 
-def pop_traced_radix() -> dict:
-    """``{width: radix}`` of the dense builds traced since the last call
-    (``main.py`` empties it when an attempt starts and reports it after
-    training as the ``hist.builds`` event)."""
-    traced = dict(sorted(_traced_radix.items()))
-    _traced_radix.clear()
-    return traced
+def onehot_ftiles(num_features: int, radix: int) -> int:
+    """Feature tiles a row chunk of ``hist_onehot`` takes at a radix: the
+    fewest of at most ``_ONEHOT_FTILE_MAX`` (radix 1) or ``_RADIX_FTILE_MAX``
+    features, sized evenly. 28 features make four tiles of 7 at radix 1 and
+    one of 28 over it; 2,000 make 250 of 8 and 63 of 32, and a v5e builds
+    them in the time 28 features take, feature for feature (31-143 ms a
+    level at 400,000 x 2,000 against 14-43 at 11M x 28 through 32 columns,
+    231 / 392 against 82 / 144 at 64 / 128: 0.75-1.28 of the same work;
+    PERF.md section 6, PR 38), so the maxima stand as read at 28."""
+    return -(-num_features // (
+        _ONEHOT_FTILE_MAX if radix == 1 else _RADIX_FTILE_MAX))
+
+
+def begin_traced_tree() -> None:
+    """A grower starts tracing a tree: the tile steps counted from here on
+    are that tree's (``pop_traced_builds``)."""
+    _traced["tree_steps"] = 0
+
+
+def pop_traced_builds() -> dict:
+    """What the dense builds traced since the last call were, for the
+    ``hist.builds`` event (``main.py`` empties it when an attempt starts and
+    reports it after training): ``radix_by_width`` and ``ftiles_by_width``
+    (``{width: ...}``), and ``tile_steps_per_tree``, the row chunks x
+    feature tiles of every build of the tree traced last (one device's; a
+    level-wise tree's builds are its levels', a leaf-wise tree's passes are
+    a loop of traced length, whose body counts once). ``{}`` where no build
+    was traced."""
+    if not _traced["builds"]:
+        return {}
+    traced = dict(sorted(_traced["builds"].items()))
+    _traced["builds"].clear()
+    return {
+        "radix_by_width": {w: b[0] for w, b in traced.items()},
+        "ftiles_by_width": {w: b[1] for w, b in traced.items()},
+        "tile_steps_per_tree": _traced["tree_steps"],
+    }
 
 
 def hist_onehot(
@@ -661,13 +693,23 @@ def hist_onehot(
     fan-out, ``hist[f, b, (node, c)] = onehot(bins[:, f])ᵀ @ (gh ⊗ onehot(pos))``,
     with the bin index factored by the radix ``onehot_radix`` gives the
     build's shape (``_hist_onehot``). Counts the build, by radix, under
-    ``rxgb_hist_builds_total`` as it is traced."""
+    ``rxgb_hist_builds_total`` and its tile steps (row chunks x feature
+    tiles) under ``rxgb_hist_tile_steps_total`` as it is traced."""
     radix = onehot_radix(n_nodes, n_bins_total - 1)
+    n, num_features = bins.shape
+    ftiles = onehot_ftiles(num_features, radix)
+    steps = ftiles * -(-n // max(1, min(chunk, n)))
     get_registry().counter(
         f'rxgb_hist_builds_total{{radix="{radix}"}}',
         "dense histogram builds traced, by the radix of the bin index",
     ).inc()
-    _traced_radix[2 * n_nodes] = radix
+    get_registry().counter(
+        "rxgb_hist_tile_steps_total",
+        "tile steps (row chunks x feature tiles) of the dense histogram "
+        "builds traced",
+    ).inc(steps)
+    _traced["builds"][2 * n_nodes] = (radix, ftiles)
+    _traced["tree_steps"] += steps
     return _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk,
                         precision, radix)
 
@@ -734,8 +776,7 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
 
     shift = radix.bit_length() - 1
     n_hi = -(-nb_reg // radix)  # one-hot rows a feature; nb_reg at radix 1
-    n_ftiles = -(-num_features // (
-        _ONEHOT_FTILE_MAX if radix == 1 else _RADIX_FTILE_MAX))
+    n_ftiles = onehot_ftiles(num_features, radix)
     ftile = -(-num_features // n_ftiles)
     f_pad = n_ftiles * ftile - num_features
     bin_ids = jnp.arange(n_hi, dtype=jnp.int32)
