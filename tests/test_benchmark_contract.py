@@ -289,6 +289,26 @@ def test_the_wide_cells_readers_on_hand_made_inputs(bench):
         assert _read(bench, "partition.time_pct", {"trace": trace}) is None
 
 
+def test_the_eval_walk_reader_on_hand_made_inputs(bench):
+    """``eval_walk.time_pct``: the per-round job's walk of each new tree over
+    its validation rows, read in that cell alone."""
+    assert "eval_walk.time_pct" in {
+        m["name"] for m in bench.run.load_cell("higgs-d6.earlystop")["per_layer"]}
+    assert "eval_walk.time_pct" not in {
+        m["name"] for m in bench.run.load_cell("higgs-d6.default")["per_layer"]}
+    one = {"tree/level0/hist": 0.40, "tree/level5/partition": 0.05,
+           "margin": 0.01, "eval_walk": 0.30, "metrics": 0.04,
+           "(unscoped)": 0.20}
+    other = dict(one, eval_walk=0.10, **{"(unscoped)": 0.40})
+    # device 0: 0.30 of 1.00 s; device 1: 0.10 of 1.00 s
+    assert _read(bench, "eval_walk.time_pct",
+                 _scope_ctx(one, other)) == pytest.approx(30.0)
+    for trace in (None, {"scopes_by_device": {}},
+                  {"scopes_by_device": {"/device:TPU:0": {
+                      "tree/level1/hist": 0.4, "margin": 0.02}}}):
+        assert _read(bench, "eval_walk.time_pct", {"trace": trace}) is None
+
+
 def _small_wide_forest(rng, x, trees, depth):
     """A forest over ``x``'s columns with thresholds on its values: full
     but for one early leaf in tree 1."""

@@ -16,6 +16,7 @@ from xgboost_ray_tpu.ops.grow import (
     bin_of_feature,
     build_tree,
     lookup_by_node,
+    predict_tree_binned,
 )
 from xgboost_ray_tpu.ops.histogram import node_counts_dense, node_sums_dense
 from xgboost_ray_tpu.ops.objectives import quantize_gh
@@ -134,15 +135,15 @@ def _assert_same_tree(got, want):
     assert int(np.asarray(tree.is_leaf).sum()) > 2
 
 
-def _both_forms(fn, *args):
+def _both_forms(fn, *args, forms=("lookup_by_node", "node_sums_dense")):
     """``fn`` traced and run with the dense forms, then with the reference
     gathers. The forms are picked as the tree is traced and jax caches a
     trace by the function's identity, so each side gets a function of its
-    own, and the reference side has to have used its forms."""
+    own, and the reference side has to have used its ``forms``."""
     got = jax.jit(lambda *a: fn(*a))(*args)
     with ref.gather_form() as used:
         want = jax.jit(lambda *a: fn(*a))(*args)
-    assert {"lookup_by_node", "node_sums_dense"} <= used, used
+    assert set(forms) <= used, used
     return got, want
 
 
@@ -310,3 +311,132 @@ def test_train_counts_the_dense_levels_it_traces():
     assert dense.value - before == 6 * programs
     assert reg.counter("rxgb_route_gather_levels_total").value == 0
     assert "rxgb_route_gather_levels_total 0" in reg.prometheus_text()
+
+
+WALK_CASES = {
+    "heap-early-leaves": dict(depth=5, min_child_weight=300.0),
+    "missing": dict(depth=4, missing=True),
+    "categorical": dict(depth=4, categorical=True, missing=True),
+    "linked": dict(depth=0, leaves=11),
+    "uint8-bins": dict(depth=3, bins_dtype="uint8"),
+    "int16-bins": dict(depth=3, bins_dtype="int16"),
+    "vmapped-forest": dict(depth=3, trees=3, missing=True),
+}
+
+
+def _walk_case(case):
+    """(tree or stacked forest, bins, walk depth, missing bin, cat features)
+    of one ``WALK_CASES`` entry, its trees grown by ``build_tree``."""
+    kw = WALK_CASES[case]
+    bins, gh, cuts, fhm, cat, max_bin = _tree_data(
+        categorical=kw.get("categorical", False),
+        missing=kw.get("missing", False), seed=len(case),
+    )
+    cfg = GrowConfig(
+        max_depth=kw["depth"], max_bin=max_bin,
+        split=SplitParams(learning_rate=0.3,
+                          min_child_weight=kw.get("min_child_weight", 1.0)),
+        hist_impl="onehot", cat_features=cat,
+        **({"grow_policy": "lossguide", "max_leaves": kw["leaves"]}
+           if "leaves" in kw else {}),
+    )
+    grow = jax.jit(lambda g: build_tree(bins, g, cuts, cfg,
+                                        feat_has_missing=fhm)[0])
+    scales = [1.0, -2.0, 0.5][: kw.get("trees", 1)]
+    trees = [grow(gh * jnp.asarray([s, 1.0])) for s in scales]
+    tree = (jax.tree.map(lambda *t: jnp.stack(t), *trees)
+            if "trees" in kw else trees[0])
+    if "bins_dtype" in kw:
+        bins = bins.astype(kw["bins_dtype"])
+    return tree, bins, cfg.max_depth, max_bin, cat
+
+
+def _walk(tree, bins, depth, missing_bin, cat):
+    walk = lambda tr: predict_tree_binned(tr, bins, depth, missing_bin, cat)
+    return jax.vmap(walk)(tree) if tree.feature.ndim == 2 else walk(tree)
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_predict_tree_binned_is_bitwise_the_gather_walk(case):
+    """The eval-set / margin walk reads its node tables by ``lookup_by_node``
+    and the split feature's bin by ``bin_of_feature``: every leaf value it
+    returns is bitwise the one the per-row gathers read, over every layout
+    and bin dtype it meets, and under ``jax.vmap`` over a forest."""
+    tree, bins, depth, missing_bin, cat = _walk_case(case)
+    layout = "heap" if tree.left is None else "linked"
+    steps = obs.get_registry().counter(
+        f'rxgb_walk_dense_steps_total{{layout="{layout}"}}')
+    before = steps.value
+    got, want = _both_forms(
+        lambda tr, b: _walk(tr, b, depth, missing_bin, cat), tree, bins,
+        forms=("lookup_by_node", "bin_of_feature"),
+    )
+    # a step per level as each side is traced, the while loop's body once
+    assert steps.value - before == 2 * (depth or 1)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    is_leaf = np.asarray(tree.is_leaf)
+    assert np.isin(_bits(got), _bits(np.asarray(tree.value)[is_leaf])).all()
+    if case == "heap-early-leaves":
+        # a leaf above the last level: some rows stop before the last step
+        assert is_leaf[: 2 ** depth - 1].any()
+    if cat:
+        assert (np.asarray(tree.feature)[~is_leaf] == cat[0]).any()
+    if "vmapped" in case:
+        assert got.shape == (3, N_ROWS)
+        assert not np.array_equal(got[0], got[1])
+
+
+@pytest.mark.parametrize("case", ["categorical", "linked"])
+def test_no_row_keyed_gather_in_the_binned_walk(case):
+    """No gather in ``predict_tree_binned``'s jaxpr has a row-sized index:
+    node tables and bins are read densely, the leaf value too; traced with
+    the reference gathers the same walk shows them."""
+    tree, bins, depth, missing_bin, cat = _walk_case(case)
+
+    def jaxpr():
+        return jax.make_jaxpr(
+            lambda tr, b: _walk(tr, b, depth, missing_bin, cat)
+        )(tree, bins).jaxpr
+
+    assert _row_indexed_eqns(jaxpr(), N_ROWS) == []
+    with ref.gather_form():
+        gathered = {fn for _, fn in _row_indexed_eqns(jaxpr(), N_ROWS)}
+    assert {"lookup_by_node_gather", "bin_of_feature_gather"} <= gathered
+
+
+@pytest.mark.parametrize("layout,evals", [
+    ("heap", ("train", "valid")), ("linked", ("train", "valid")),
+    ("heap", ("train",)),
+])
+def test_train_with_an_eval_set_counts_the_dense_walk(layout, evals):
+    """``train()`` with a validation set walks every new tree over it in the
+    dense form, counted by the tree's layout and by no other; with the
+    training set alone (the benchmark's ``default`` traffic) the margin
+    comes from the grower and no walk is traced."""
+    from xgboost_ray_tpu import RayDMatrix, RayParams, train
+
+    reg = obs.get_registry()
+    counters = {lay: reg.counter(f'rxgb_walk_dense_steps_total{{layout="{lay}"}}')
+                for lay in ("heap", "linked")}
+    before = {lay: c.value for lay, c in counters.items()}
+    rng = np.random.RandomState(1)
+    x = rng.randn(800, 5).astype(np.float32)
+    y = (x[:, 0] + 0.3 * rng.randn(800) > 0).astype(np.float32)
+    params = {"objective": "binary:logistic", "max_depth": 4, "max_bin": 16}
+    if layout == "linked":
+        params.update(grow_policy="lossguide", max_depth=0, max_leaves=6)
+    sets = {"train": RayDMatrix(x[:600], y[:600]),
+            "valid": RayDMatrix(x[600:], y[600:])}
+    res = {}
+    train(params, sets["train"], num_boost_round=2, evals_result=res,
+          evals=[(sets[name], name) for name in evals],
+          ray_params=RayParams(num_actors=2))
+    assert all(len(res[name]["logloss"]) == 2 for name in evals)
+    counted = {lay: c.value - before[lay] for lay, c in counters.items()}
+    if evals == ("train",):
+        assert counted == {"heap": 0, "linked": 0}
+        return
+    other = "linked" if layout == "heap" else "heap"
+    assert counted[layout] > 0 and counted[other] == 0, counted
+    if layout == "heap":
+        assert counted[layout] % 4 == 0

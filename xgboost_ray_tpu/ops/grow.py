@@ -280,7 +280,7 @@ class Tree(NamedTuple):
     """One decision tree in padded-heap layout; all arrays ``[heap_size]``
     (``2^(max_depth+1) - 1`` slots, the children of ``i`` at ``2i + 1`` /
     ``2i + 2``): every depth-bounded tree. ``LinkedTree`` is the layout of a
-    tree no depth bounds; every walk takes both (``child_index``)."""
+    tree no depth bounds; every walk takes both (``walk_to_leaf``)."""
 
     feature: jnp.ndarray  # int32, -1 if leaf/unused
     split_bin: jnp.ndarray  # int32, rows with bin <= split_bin go left
@@ -333,23 +333,50 @@ def child_index(tree: Tree, idx, go_right):
     return first + go_right.astype(jnp.int32)
 
 
-def walk_to_leaf(tree: Tree, idx, max_depth: int, go_right_at):
-    """Leaf slot of every row from its slot ``idx`` (the root's zeros);
-    ``go_right_at(idx)`` routes the rows at their current slots.
-    ``max_depth`` steps where the depth is known (any heap tree; a linked
-    forest whose depth the caller measured), else (0) steps until every row
-    sits in a leaf."""
+def gather_rows(idx, *tables):
+    """``[t[idx] for t in tables]``: each node table read at the rows' slots
+    by a per-row gather (``walk_to_leaf``'s read by default)."""
+    return [t[idx] for t in tables]
 
-    def step(idx):
-        nxt = child_index(tree, idx, go_right_at(idx))
-        return jnp.where(tree.is_leaf[idx], idx, nxt)
+
+def slots_reached(tree: Tree, steps: int):
+    """Slots a row can sit in after ``steps`` steps from the root: a heap
+    tree's first ``2^(steps+1) - 1``; every slot (``None``) of a linked tree
+    or where the steps are not counted (``None``)."""
+    if tree.left is not None or steps is None:
+        return None
+    return 2 ** (steps + 1) - 1
+
+
+def walk_to_leaf(tree: Tree, idx, max_depth: int, go_right_at,
+                 read=gather_rows, route_tables=()):
+    """Leaf slot of every row from its slot ``idx`` (the root's zeros).
+    Each step reads ``is_leaf`` (a linked tree's ``left`` too) and
+    ``route_tables`` at the rows' slots in one ``read(idx, *tables)``, the
+    tables cut to ``slots_reached``, and ``go_right_at(idx, *route)`` routes
+    the rows from what it read of ``route_tables``. ``max_depth`` steps
+    where the depth is known (any heap tree; a linked forest whose depth the
+    caller measured), else (0) steps until every row sits in a leaf."""
+    tables = (tree.is_leaf,) + (() if tree.left is None else (tree.left,))
+
+    def step(idx, n_slots):
+        leaf, *read_at = read(
+            idx, *(t[:n_slots] for t in tables + tuple(route_tables))
+        )
+        if tree.left is None:
+            first = 2 * idx + 1
+        else:
+            first, *read_at = read_at
+        nxt = first + go_right_at(idx, *read_at).astype(jnp.int32)
+        return jnp.where(leaf, idx, nxt)
 
     if max_depth > 0:
-        for _ in range(max_depth):
-            idx = step(idx)
+        for s in range(max_depth):
+            idx = step(idx, slots_reached(tree, s))
         return idx
     return jax.lax.while_loop(
-        lambda i: ~jnp.all(tree.is_leaf[i]), step, idx
+        lambda i: ~jnp.all(read(i, tree.is_leaf)[0]),
+        lambda i: step(i, None), idx,
     )
 
 
@@ -872,20 +899,10 @@ def predict_tree_binned(
     without leaving the device. ``max_depth`` 0 (a linked tree no depth
     bounds) walks until every row sits in a leaf.
     """
-    n, num_features = bins.shape
-    root = jnp.zeros((n,), jnp.int32)
-    b32 = bins.astype(jnp.int32)
-    cat_mask = cat_mask_const(cat_features, num_features)
-
-    def go_right_at(idx):
-        f = jnp.clip(tree.feature[idx], 0, num_features - 1)
-        bv = jnp.take_along_axis(b32, f[:, None], axis=1)[:, 0]
-        return route_right_binned(
-            bv, tree.split_bin[idx], tree.default_left[idx],
-            None if cat_mask is None else cat_mask[f], missing_bin,
-        )
-
-    return tree.value[walk_to_leaf(tree, root, max_depth, go_right_at)]
+    return _walk_binned(
+        tree, bins, max_depth, missing_bin, bins.shape[1], cat_features,
+        bin_of_feature,
+    )
 
 
 def predict_tree_binned_fsharded(
@@ -900,15 +917,38 @@ def predict_tree_binned_fsharded(
     for eval-set / sampled-build margin walks instead of replicating F).
     Routing state (idx) stays identical on every feature shard.
     """
-    cat_mask = cat_mask_const(cat_features, fshard.f_padded)
+    return _walk_binned(
+        tree, bins, max_depth, missing_bin, fshard.f_padded, cat_features,
+        fshard.bin_column,
+    )
 
-    def go_right_at(idx):
-        f = jnp.clip(tree.feature[idx], 0, fshard.f_padded - 1)
-        bv = fshard.bin_column(bins, f)
+
+def _walk_binned(tree, bins, max_depth, missing_bin, num_features,
+                 cat_features, column):
+    """The binned walk of both bin layouts: the node tables a step needs
+    read in one ``lookup_by_node`` (no per-row gather; heap steps compare
+    only the slots ``slots_reached``), the split feature's bin by
+    ``column(bins, f_of_row)``, the leaf value by the same lookup. Counts
+    each step under ``rxgb_walk_dense_steps_total`` as it is traced."""
+    layout = "heap" if tree.left is None else "linked"
+    cat_mask = cat_mask_const(cat_features, num_features)
+    f = jnp.clip(tree.feature, 0, num_features - 1)
+    route = (f, tree.split_bin, tree.default_left) + (
+        () if cat_mask is None else (cat_mask[f],)
+    )
+
+    read = lookup_by_node
+
+    def go_right_at(idx, f_of_row, split_bin, default_left, *is_cat):
+        get_registry().counter(
+            f'rxgb_walk_dense_steps_total{{layout="{layout}"}}',
+            "steps of the binned tree walk traced, by the tree's layout",
+        ).inc()
         return route_right_binned(
-            bv, tree.split_bin[idx], tree.default_left[idx],
-            None if cat_mask is None else cat_mask[f], missing_bin,
+            column(bins, f_of_row), split_bin, default_left,
+            is_cat[0] if is_cat else None, missing_bin,
         )
 
     root = jnp.zeros((bins.shape[0],), jnp.int32)
-    return tree.value[walk_to_leaf(tree, root, max_depth, go_right_at)]
+    leaf = walk_to_leaf(tree, root, max_depth, go_right_at, read, route)
+    return read(leaf, tree.value[:slots_reached(tree, max_depth or None)])[0]
