@@ -309,6 +309,68 @@ def test_the_eval_walk_reader_on_hand_made_inputs(bench):
         assert _read(bench, "eval_walk.time_pct", {"trace": trace}) is None
 
 
+def _host_timeline():
+    """A window of 10-20 s and 4 rounds: a dispatch before it (its wait
+    ends at 10.0) and two in it (enqueues end at 10.5 and 13.7), a save
+    between them whose readback holds a trace and a backend compile, and a
+    cache load inside a ``cache_hit`` backend compile. Per round: the two
+    gaps with nothing enqueued 500 ms, readback 150, serialise 75, args
+    (10.2 and 13.5) 45, compiles (0.02 + 0.4 + 0.2 s) 155."""
+    def span(seq, name, t0, dur, parent=None, **attrs):
+        rec = {"kind": "span", "name": name, "seq": seq, "t0_s": t0,
+               "dur_s": dur, "parent": parent}
+        return dict(rec, attrs=attrs) if attrs else rec
+
+    return [
+        span(1, "dispatch", 9.0, 1.0), span(2, "dispatch.enqueue", 9.0, 0.1, 1),
+        span(3, "dispatch.args", 9.0, 0.05, 2),
+        span(4, "dispatch.wait", 9.5, 0.5, 1),
+        span(5, "dispatch", 10.2, 2.0), span(6, "dispatch.enqueue", 10.2, 0.3, 5),
+        span(7, "dispatch.args", 10.2, 0.1, 6),
+        span(8, "dispatch.wait", 11.0, 1.2, 5),
+        span(9, "driver.checkpoint", 12.3, 1.0),
+        span(10, "checkpoint.readback", 12.3, 0.6, 9),
+        span(11, "compile.trace", 12.32, 0.02, 10),
+        span(12, "compile.backend", 12.35, 0.4, 10, cache_hit=False),
+        span(13, "checkpoint.serialise", 12.9, 0.3, 9),
+        span(14, "dispatch", 13.5, 1.5), span(15, "dispatch.enqueue", 13.5, 0.2, 14),
+        span(16, "dispatch.args", 13.5, 0.08, 15),
+        span(17, "dispatch.wait", 14.0, 1.0, 14),
+        span(18, "compile.backend", 16.0, 0.2, None, cache_hit=True),
+        span(19, "compile.cache_load", 16.01, 0.15, 18),
+    ]
+
+
+@pytest.mark.parametrize("name, ms, absent", [
+    ("driver.device_gap_ms", 500.0, ("dispatch",)),
+    ("checkpoint.readback_ms", 150.0, ("checkpoint.readback",)),
+    ("checkpoint.serialise_ms", 75.0, ("checkpoint.serialise",)),
+    ("round.args_ms", 45.0, ("dispatch.args",)),
+    ("round.window_compile_ms", 155.0, None),
+])
+def test_the_host_span_readers_on_a_hand_made_timeline(bench, name, ms,
+                                                       absent):
+    """The readers of the host's half of a round: a value on the timeline,
+    ``None`` where the spans they read are not there (a program older than
+    them, for the three whose spans are new) or where there is no
+    timeline at all."""
+    assert name in {m["name"] for m in bench.run.load_cell(
+        "higgs-d6.earlystop")["per_layer"]}
+    clock = types.SimpleNamespace(window_open=10.0, window_close=20.0,
+                                  trace_close=None, trace_stop_s=0.0)
+
+    def ctx(timeline):
+        return {"clock": clock, "window_rounds": 4, "additional_results": {
+            "obs": {"timeline": timeline}}}
+
+    timeline = _host_timeline()
+    assert _read(bench, name, ctx(timeline)) == pytest.approx(ms)
+    assert _read(bench, name, ctx([])) is None
+    if absent:
+        older = [r for r in timeline if r["name"] not in absent]
+        assert _read(bench, name, ctx(older)) is None
+
+
 def _small_wide_forest(rng, x, trees, depth):
     """A forest over ``x``'s columns with thresholds on its values: full
     but for one early leaf in tree 1."""

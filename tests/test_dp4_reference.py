@@ -8,15 +8,14 @@ Two ways of cutting the same rows into shards: (a) i.i.d. shards (the rows
 as generated, in contiguous blocks) and (b) the rows sorted by feature 0
 first (case ``sorted``), so that every shard holds one quarter of feature
 0's range and a split on it sends whole shards to one side: the same checks
-hold, and no sibling build is counted as a fallback.
+hold.
 
 The 4-device forest is held to the benchmark's plain reference
 (``benchmarks/reference.py``: ``follow`` / ``compare``, which imports nothing
 of the program) and to the 1-device forest on the same rows; every actor
 ends with the same model; and the counters the mesh programs thread out
-(``hist_skew_fallback_builds`` of ``hist_sibling_builds``,
-``collectives_per_round``, ``hist_allreduce_bytes_per_round``) are held to an
-independent recount from the forest and to the shapes' own arithmetic.
+(``collectives_per_round``, ``hist_allreduce_bytes_per_round``) are held to
+the shapes' own arithmetic.
 """
 
 import os
@@ -88,39 +87,6 @@ def job(request):
     }
 
 
-def _recount_skew_builds(forest, x, actors):
-    """(skewed builds, sibling builds) by the rule ``build_tree`` states:
-    at every level >= 1, per parent the child with fewer live rows over ALL
-    shards is built (the right one on a tie); a shard is skewed at a level
-    when its own rows of those children (rows parked under a leaf included:
-    they ride down its left edge) exceed half its rows."""
-    shards = np.array_split(np.arange(x.shape[0]), actors)
-    depth = PARAMS["max_depth"]
-    fallback = sibling = 0
-    for t in range(forest["feature"].shape[0]):
-        feature, threshold = forest["feature"][t], forest["threshold"][t]
-        is_leaf = forest["is_leaf"][t]
-        slot = [np.zeros(len(s), np.int64) for s in shards]
-        done = [np.zeros(len(s), bool) for s in shards]
-        for d in range(depth):
-            n_nodes = 1 << d
-            if d >= 1:
-                live = sum(np.bincount(sl[~dn], minlength=n_nodes)
-                           for sl, dn in zip(slot, done))
-                small = 2 * np.arange(n_nodes // 2) + (live[1::2] <= live[0::2])
-                for sl in slot:
-                    picked = np.bincount(sl, minlength=n_nodes)[small].sum()
-                    fallback += picked > len(sl) // 2
-                    sibling += 1
-            for s, rows in enumerate(shards):
-                node = n_nodes - 1 + slot[s]
-                done[s] |= is_leaf[node] | (feature[node] < 0)
-                xv = x[rows, np.maximum(feature[node], 0)]
-                right = (xv >= threshold[node]) & ~done[s]
-                slot[s] = 2 * slot[s] + right
-    return int(fallback), sibling
-
-
 def test_forest_of_four_actors_matches_the_plain_reference(job):
     ref = reference.follow({"train": (job["x"], job["y"])}, job["forest4"],
                            PARAMS, split_trees=range(ROUNDS))
@@ -152,23 +118,7 @@ def test_one_device_world_counts_nothing(job):
     extra = job["extra1"]
     assert extra["hist_allreduce_bytes_per_round"] == 0
     assert extra["collectives_per_round"] == 0
-    assert extra["hist_skew_fallback_builds"] == 0
-    assert extra["hist_sibling_builds"] == 0
     assert extra["device"]["mesh_shape"] == {"actors": 1}
-
-
-def test_skew_fallback_counters_match_a_recount_from_the_forest(job):
-    extra = job["extra4"]
-    skewed, sibling = _recount_skew_builds(job["forest4"], job["x"], ACTORS)
-    # one build a (round, level >= 1, shard)
-    assert sibling == ROUNDS * (PARAMS["max_depth"] - 1) * ACTORS
-    assert extra["hist_sibling_builds"] == sibling
-    if job["case"] == "sorted":
-        # a split on feature 0 sends whole shards to one side ...
-        assert skewed > 0
-    # ... but the dense build has no buffer to overflow: every noted sibling
-    # build held its shard's rows in one pass
-    assert extra["hist_skew_fallback_builds"] == 0
 
 
 def test_wire_counters_are_the_shapes_own_arithmetic(job):
@@ -206,7 +156,9 @@ def test_every_actor_ends_with_the_same_model():
         for other in copies[1:]:
             np.testing.assert_array_equal(copies[0], other)
     stats = eng.mesh_round_stats()
-    assert stats["hist_sibling_builds"] == 2 * (PARAMS["max_depth"] - 1) * ACTORS
+    # the running sum: every shard's collectives of both rounds
+    summed = int(eng._mesh_stats_total()[0])
+    assert summed == stats["collectives_per_round"] * 2 * ACTORS
 
     # the counts are one running sum on the device, and only the first
     # dispatch compiles its fold: a later chunk compiles nothing
@@ -219,7 +171,7 @@ def test_every_actor_ends_with_the_same_model():
     eng.step_many(2, 2)
     assert compiles.value == before
     again = eng.mesh_round_stats()
-    assert again["hist_sibling_builds"] == 2 * stats["hist_sibling_builds"]
+    assert int(eng._mesh_stats_total()[0]) == 2 * summed
     assert again["collectives_per_round"] == stats["collectives_per_round"] == 12
 
 
@@ -253,9 +205,6 @@ def test_events_and_spans_carry_what_the_mesh_adds(job):
     wire = by_name["allreduce.bytes"][-1]["attrs"]
     assert wire == {"bytes_per_round": 2_764_404,
                     "collectives_per_round": 12, "mesh": {"actors": ACTORS}}
-    skew = by_name["hist.skew_builds"][-1]["attrs"]
-    assert skew["sibling_builds"] == job["extra4"]["hist_sibling_builds"]
-    assert skew["fallback_builds"] == job["extra4"]["hist_skew_fallback_builds"]
     load = by_name["data.load"][0]["attrs"]
     assert load["shards"] == ACTORS
     # 28 float32 features and a float32 label a row

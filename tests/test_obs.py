@@ -504,9 +504,10 @@ def test_chaos_shrink_grow_sequence_reconstructible_from_timeline(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _traced(rounds=4, per_round=False, **ray):
+def _traced(rounds=4, per_round=False, valid=False, **ray):
     """One small traced train(); ``per_round`` forces the per-round loop
-    (a TrainingCallback switches the fused scan off)."""
+    (a TrainingCallback switches the fused scan off); ``valid`` adds a
+    validation set beside the training one."""
 
     class Noop:
         def after_iteration(self, model, epoch, evals_log):
@@ -514,8 +515,11 @@ def _traced(rounds=4, per_round=False, **ray):
 
     x, y = _data()
     dtrain = RayDMatrix(x, y)
+    evals = [(dtrain, "train")]
+    if valid:
+        evals.append((RayDMatrix(x[:64], y[:64]), "valid"))
     res = {}
-    train(_PARAMS, dtrain, rounds, evals=[(dtrain, "train")],
+    train(_PARAMS, dtrain, rounds, evals=evals,
           additional_results=res, callbacks=[Noop()] if per_round else None,
           ray_params=RayParams(num_actors=2, checkpoint_frequency=2, **ray))
     return res["obs"]["timeline"]
@@ -620,6 +624,81 @@ def test_every_save_is_one_checkpoint_span_around_its_commit(per_round):
         assert hooks == [None] * 3  # one fan-out a chunk
 
 
+@pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
+def test_a_save_reads_back_and_serialises_in_spans_of_its_own(per_round):
+    """Every ``driver.checkpoint`` holds one ``checkpoint.readback`` (what
+    the flush moved) and then one ``checkpoint.serialise`` (the pickle):
+    over the saves the readbacks bring every round to the host once, and
+    each pickle holds the forest so far. A compile inside a save lands
+    under the readback that caused it."""
+    timeline = _traced(per_round=per_round, valid=True)
+    assert validate_trace_records(timeline, known_names=obs.TRACE_NAMES) == []
+    saves = _spans(timeline, "driver.checkpoint")
+    assert len(saves) == 2
+    flushed = 0
+    for save in saves:
+        kids = [r for r in timeline if r.get("parent") == save["seq"]
+                and r["kind"] == "span"]
+        assert [k["name"] for k in kids] == ["checkpoint.readback",
+                                             "checkpoint.serialise"]
+        readback, serialise = kids
+        assert readback["t0_s"] + readback["dur_s"] <= serialise["t0_s"]
+        moved = readback["attrs"]
+        assert moved["trees"] == 2 and moved["bytes"] > 0
+        assert moved["entries"] == (2 if per_round else 1)
+        flushed += moved["trees"]
+        assert serialise["attrs"]["trees"] == flushed
+        assert serialise["attrs"]["bytes"] > moved["bytes"] // 2
+    assert flushed == 4
+    by_seq = {r["seq"]: r for r in timeline}
+    for r in timeline:
+        chain = [r.get("parent")]
+        while chain[-1] is not None:
+            chain.append(by_seq[chain[-1]].get("parent"))
+        if r["name"].startswith("compile.") and any(
+                by_seq[p]["name"] == "driver.checkpoint" for p in chain[:-1]):
+            assert by_seq[r["parent"]]["name"] == "checkpoint.readback", r
+
+
+@pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
+def test_every_enqueue_assembles_its_arguments_in_a_span(per_round):
+    """Each ``dispatch.enqueue`` holds exactly one ``dispatch.args``, named
+    by the dispatch's program and closed before the launch, validation set
+    included."""
+    timeline = _traced(per_round=per_round, valid=True)
+    dispatches = _spans(timeline, "dispatch")
+    assert len(dispatches) == (4 if per_round else 2)
+    for d in dispatches:
+        (enqueue,) = [r for r in timeline if r.get("parent") == d["seq"]
+                      and r["name"] == "dispatch.enqueue"]
+        (args,) = [r for r in timeline if r.get("parent") == enqueue["seq"]
+                   and r["name"] == "dispatch.args"]
+        assert args["attrs"] == {"program": d["attrs"]["program"]}
+        assert enqueue["t0_s"] <= args["t0_s"]
+        assert (args["t0_s"] + args["dur_s"]
+                <= enqueue["t0_s"] + enqueue["dur_s"])
+
+
+def test_with_tracing_off_no_span_is_recorded(monkeypatch, tmp_path):
+    """``RXGB_TRACE=0``: the per-round loop with a validation set and saves
+    streams nothing, the new spans included."""
+    monkeypatch.setenv("RXGB_TRACE", "0")
+    monkeypatch.setenv("RXGB_TRACE_DIR", str(tmp_path))
+    x, y = _data()
+    res = {}
+
+    class Noop:
+        def after_iteration(self, model, epoch, evals_log):
+            return False
+
+    train(_PARAMS, RayDMatrix(x, y), 3,
+          evals=[(RayDMatrix(x[:64], y[:64]), "valid")],
+          additional_results=res, callbacks=[Noop()],
+          ray_params=RayParams(num_actors=2, checkpoint_frequency=2))
+    assert "obs" not in res
+    assert os.listdir(tmp_path) == []
+
+
 def test_compiles_land_under_the_span_that_caused_them():
     import jax
     import jax.numpy as jnp
@@ -712,30 +791,38 @@ def test_the_binning_program_names_sketch_and_bin():
     assert 'loc("sketch/' in text and 'loc("bin/' in text
 
 
+# a hand-written XSpace (the profiler's ``.xplane.pb``): the protobuf wire
+# format's varint and length-delimited fields, enough of it for the planes
+
+
+def _varint(v):
+    out = bytearray()
+    while True:
+        out.append((v & 0x7F) | (0x80 if v > 0x7F else 0))
+        v >>= 7
+        if not v:
+            return bytes(out)
+
+
+def field(num, val):
+    if isinstance(val, int):
+        return _varint(num << 3) + _varint(val)
+    return _varint(num << 3 | 2) + _varint(len(val)) + bytes(val)
+
+
+def entry(key, msg):
+    return field(1, key) + field(2, msg)
+
+
+def event(md, offset_ps, dur_ps):
+    return field(1, md) + field(2, offset_ps) + field(3, dur_ps)
+
+
 def test_scope_times_on_a_recorded_trace(tmp_path):
     """``obs.device.scope_times`` over a hand-written XSpace: self times by
     scope path, nested operations not counted twice, other lines and host
     planes left out."""
     from xgboost_ray_tpu.obs import device
-
-    def varint(v):
-        out = bytearray()
-        while True:
-            out.append((v & 0x7F) | (0x80 if v > 0x7F else 0))
-            v >>= 7
-            if not v:
-                return bytes(out)
-
-    def field(num, val):
-        if isinstance(val, int):
-            return varint(num << 3) + varint(val)
-        return varint(num << 3 | 2) + varint(len(val)) + bytes(val)
-
-    def entry(key, msg):
-        return field(1, key) + field(2, msg)
-
-    def event(md, offset_ps, dur_ps):
-        return field(1, md) + field(2, offset_ps) + field(3, dur_ps)
 
     def op(md_id, name, tf_op):
         stat = field(1, 7) + field(5, tf_op.encode())
@@ -788,3 +875,97 @@ def test_scope_times_on_a_recorded_trace(tmp_path):
     both = device.scope_times(str(tmp_path))
     assert sum(both.values()) == pytest.approx(2200 * ps)
     assert both["tree/level1/allreduce"] == pytest.approx(700 * ps)
+
+
+# ---------------------------------------------------------------------------
+# the timeline on the profiler's clock: a device's idle seconds by span
+# ---------------------------------------------------------------------------
+
+_MS = 10**9  # picoseconds
+_T0 = 100.0  # where the timeline's perf_counter clock stood at the trace's 0
+
+
+def _idle_trace(tmp_path):
+    """A host plane whose line holds the ``TraceAnnotation`` of three
+    ``span()`` records (and a stray ``dispatch`` of another length), a
+    device plane busy at 0-8 ms and 30-40 ms, both lines at 5 s on the
+    trace's clock; and the timeline: ``dispatch`` 0-10 ms with its ``round``,
+    ``driver.checkpoint`` 20-30 ms, ``checkpoint.readback`` 21-27 ms inside
+    it and a ``compile.backend`` 22-26 ms inside that, which has no
+    annotation of its own and is placed by the others' offset."""
+    names = {1: "dispatch", 2: "driver.checkpoint", 3: "checkpoint.readback",
+             4: "%fusion.1", 5: "%fusion.2"}
+    mds = b"".join(field(4, entry(i, field(1, i) + field(2, n.encode())))
+                   for i, n in names.items())
+    line = field(2, b"python") + field(3, 5 * 10**9) + b"".join(
+        field(4, event(md, start * _MS, dur * _MS))
+        for md, start, dur in ((1, 0, 10), (2, 20, 10), (3, 21, 6),
+                               (1, 45, 3)))
+    host = field(2, b"/host:CPU") + mds + field(3, line)
+    ops = field(2, b"XLA Ops") + field(3, 5 * 10**9) + field(
+        4, event(4, 0, 8 * _MS)) + field(4, event(5, 30 * _MS, 10 * _MS))
+    chip = field(2, b"/device:TPU:0") + mds + field(3, ops)
+    run_dir = tmp_path / "plugins" / "profile" / "run"
+    run_dir.mkdir(parents=True)
+    (run_dir / "host.xplane.pb").write_bytes(field(1, host) + field(1, chip))
+
+    def rec(seq, name, start_ms, dur_ms, parent=None):
+        return {"kind": "span", "name": name, "ts": 0.0, "seq": seq,
+                "t0_s": _T0 + start_ms / 1e3, "dur_s": dur_ms / 1e3,
+                "parent": parent}
+
+    return [rec(1, "dispatch", 0, 10), rec(2, "round", 0, 10, parent=1),
+            rec(3, "driver.checkpoint", 20, 10),
+            rec(4, "checkpoint.readback", 21, 6, parent=3),
+            rec(5, "compile.backend", 22, 4, parent=4)]
+
+
+@pytest.mark.parametrize("path, ms", [
+    ("driver.checkpoint/checkpoint.readback/compile.backend", 4),
+    ("dispatch", 2),
+    ("(no span)", 10),
+], ids=["compile_placed_by_offset", "span_record", "no_span"])
+def test_idle_by_span_on_a_recorded_trace(tmp_path, path, ms):
+    """The device's one gap, 8-30 ms, cut at the timeline's records: 8-10
+    under ``dispatch`` (its ``round`` left out), 10-20 under no record,
+    20-30 under the save: 4 ms of it under the compile inside the readback,
+    2 under the readback itself, 4 under the save's own time."""
+    from xgboost_ray_tpu.obs import device
+
+    records = _idle_trace(tmp_path)
+    idle = device.idle_by_span(str(tmp_path), records)
+    assert set(idle) == {"/device:TPU:0"}
+    table = idle["/device:TPU:0"]
+    assert table[path] == pytest.approx(ms / 1e3)
+    assert table == pytest.approx({
+        "dispatch": 2e-3, "(no span)": 10e-3, "driver.checkpoint": 4e-3,
+        "driver.checkpoint/checkpoint.readback": 2e-3,
+        "driver.checkpoint/checkpoint.readback/compile.backend": 4e-3})
+    # bounds on the timeline's clock cut the gap before it is put down
+    cut = device.idle_by_span(str(tmp_path), records,
+                              bounds=(_T0 + 0.012, _T0 + 0.023))
+    assert cut["/device:TPU:0"] == pytest.approx({
+        "(no span)": 8e-3, "driver.checkpoint": 1e-3,
+        "driver.checkpoint/checkpoint.readback": 1e-3,
+        "driver.checkpoint/checkpoint.readback/compile.backend": 1e-3})
+    # records no annotation pairs with are not placed: all is (no span)
+    lost = device.idle_by_span(str(tmp_path), records[4:])
+    assert lost == {"/device:TPU:0": pytest.approx({"(no span)": 22e-3})}
+    assert device.idle_by_span(str(tmp_path / "nothing_here"), records) == {}
+
+
+def test_the_clock_offset_is_not_fooled_by_repeating_rounds():
+    """Forty rounds of one rhythm (a 10 ms dispatch every 20 ms, a host
+    that drifts 7 us a round and a device 3 us): the three annotated
+    rounds pair as many marks with the rounds one or two later, within the
+    tolerance, and only their own records pair exactly."""
+    from xgboost_ray_tpu.obs import device
+
+    spans = [{"name": "dispatch", "t0_s": _T0 + k * (0.02 + 7e-6),
+              "dur_s": 0.01 + k * 3e-6} for k in range(40)]
+    trace_zero = 5.0  # the trace's clock where the timeline reads _T0
+    marks = [("dispatch", trace_zero + spans[k]["t0_s"] - _T0,
+              spans[k]["dur_s"]) for k in (11, 12, 13)]
+    assert device._clock_offset(spans, marks) == pytest.approx(
+        trace_zero - _T0, abs=1e-9)
+    assert device._clock_offset(spans, [("driver.checkpoint", 1.0, 0.1)]) is None

@@ -1712,7 +1712,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes (identical on every shard)
-                P(AXIS_ACTORS),  # mesh_stats, a [3] a shard (None: no output)
+                P(AXIS_ACTORS),  # mesh_stats, MESH_STATS a shard (None: no output)
             ),
             check_vma=False,
         )
@@ -1783,7 +1783,7 @@ class TpuEngine:
                 P(),
                 tuple(tuple((P(), P()) for _ in self._device_metrics) for _ in self.evals),
                 P(),  # per-round allreduce payload bytes [n_rounds]
-                P(None, AXIS_ACTORS),  # mesh_stats [n_rounds, 3 a shard]
+                P(None, AXIS_ACTORS),  # mesh_stats [n_rounds, MESH_STATS a shard]
             ),
             check_vma=False,
         )
@@ -1867,19 +1867,21 @@ class TpuEngine:
             with tracer.span("dispatch.enqueue"):
                 if self._scan_fn is None:
                     self._scan_fn = self._make_scan_step()
-                # placed from the host: jnp.arange(a, b) compiles an add for
-                # the first a > 0, i.e. inside the second chunk's dispatch
-                iterations = jax.device_put(
-                    np.arange(
-                        self.iteration_offset + iteration0,
-                        self.iteration_offset + iteration0 + n_rounds,
-                        dtype=np.int32,
-                    ),
-                    NamedSharding(self.mesh, P()),
-                )
-                eval_data = self._eval_arrs()
-                group_rows = self._default_group_rows()
-                bounds = self._default_bounds()
+                with tracer.span("dispatch.args", program="scan"):
+                    # placed from the host: jnp.arange(a, b) compiles an add
+                    # for the first a > 0, i.e. inside the second chunk's
+                    # dispatch
+                    iterations = jax.device_put(
+                        np.arange(
+                            self.iteration_offset + iteration0,
+                            self.iteration_offset + iteration0 + n_rounds,
+                            dtype=np.int32,
+                        ),
+                        NamedSharding(self.mesh, P()),
+                    )
+                    eval_data = self._eval_arrs()
+                    group_rows = self._default_group_rows()
+                    bounds = self._default_bounds()
                 # the scan program compiles once per distinct chunk length; the
                 # strict guard arms only for chunk lengths already dispatched
                 with strict_transfer_guard(active=prog in self._warm_programs):
@@ -1962,30 +1964,33 @@ class TpuEngine:
                     if self._step_fn is None:
                         self._step_fn = self._make_step(custom=False)
                     fn = self._step_fn
-                rng = jax.random.fold_in(
-                    jax.random.PRNGKey(self.params.seed), self.iteration_offset + iteration
-                )
-                eval_data = self._eval_arrs()
-                group_rows = self._default_group_rows()
-                if custom:
-                    # g/h hold THIS process's rows (the driver computes the custom
-                    # objective from get_margins_local + process-local labels — the
-                    # reference's per-actor local computation, ``main.py:745-752``);
-                    # _put_rows assembles them into the global sharded layout.
-                    g, h = gh_custom
-                    gh_in = (
-                        self._put_rows(
-                            np.asarray(g, np.float32).reshape(self._local_rows, -1),
-                            np.float32,
-                        ),
-                        self._put_rows(
-                            np.asarray(h, np.float32).reshape(self._local_rows, -1),
-                            np.float32,
-                        ),
+                with tracer.span("dispatch.args", program=prog):
+                    rng = jax.random.fold_in(
+                        jax.random.PRNGKey(self.params.seed),
+                        self.iteration_offset + iteration,
                     )
-                else:
-                    gh_in = jnp.zeros((), jnp.float32)
-                bounds = self._default_bounds()
+                    eval_data = self._eval_arrs()
+                    group_rows = self._default_group_rows()
+                    if custom:
+                        # g/h hold THIS process's rows (the driver computes the
+                        # custom objective from get_margins_local +
+                        # process-local labels — the reference's per-actor
+                        # local computation, ``main.py:745-752``); _put_rows
+                        # assembles them into the global sharded layout.
+                        g, h = gh_custom
+                        gh_in = (
+                            self._put_rows(
+                                np.asarray(g, np.float32).reshape(self._local_rows, -1),
+                                np.float32,
+                            ),
+                            self._put_rows(
+                                np.asarray(h, np.float32).reshape(self._local_rows, -1),
+                                np.float32,
+                            ),
+                        )
+                    else:
+                        gh_in = jnp.zeros((), jnp.float32)
+                    bounds = self._default_bounds()
                 with strict_transfer_guard(active=prog in self._warm_programs):
                     new_margins, new_eval_margins, forest, contribs, ar_bytes, mesh_stats = fn(
                         self.bins,
@@ -2143,7 +2148,7 @@ class TpuEngine:
         self._stack_entries = len(all_trees)
         return map_tree(lambda f: f[: self._stack_rows], self._stack_buf)
 
-    def _flush_trees(self) -> None:
+    def _flush_trees(self) -> Dict[str, int]:
         """Transfer pending device forests to host with batched reads.
 
         Entries are ``(tree, None)`` for one round (per-round step paths) or
@@ -2151,25 +2156,29 @@ class TpuEngine:
         entries are concatenated on device first (per-round trees expand to a
         length-1 leading axis; forest shapes are constant within a run), so a
         flush costs exactly one host read per Tree field no matter how many
-        rounds or chunks are pending."""
+        rounds or chunks are pending. Returns what it moved: ``entries``
+        flushed, ``trees`` (rounds) brought to the host, host ``bytes``."""
         entries = self._trees_dev
-        if not entries:
-            return
         total = sum(1 if n is None else n for _, n in entries)
+        moved = {"entries": len(entries), "trees": total, "bytes": 0}
+        if not entries:
+            return moved
         if len(entries) == 1 and entries[0][1] is None:
-            self.trees.append(jax.tree.map(np.asarray, entries[0][0]))
-            self._trees_dev.clear()
-            return
-        expanded = [
-            jax.tree.map(lambda a: a[None], t) if n is None else t
-            for t, n in entries
-        ]
-        stacked = jax.tree.map(
-            lambda *xs: np.asarray(jnp.concatenate(xs, axis=0)), *expanded
-        )
-        for r in range(total):
-            self.trees.append(jax.tree.map(lambda a, _r=r: a[_r], stacked))
+            host = jax.tree.map(np.asarray, entries[0][0])
+            self.trees.append(host)
+        else:
+            expanded = [
+                jax.tree.map(lambda a: a[None], t) if n is None else t
+                for t, n in entries
+            ]
+            host = jax.tree.map(
+                lambda *xs: np.asarray(jnp.concatenate(xs, axis=0)), *expanded
+            )
+            for r in range(total):
+                self.trees.append(jax.tree.map(lambda a, _r=r: a[_r], host))
         self._trees_dev.clear()
+        moved["bytes"] = sum(a.nbytes for a in jax.tree.leaves(host))
+        return moved
 
     def hist_allreduce_bytes_per_round(self) -> Optional[int]:
         """Measured collective payload bytes of one boosting round's tree
@@ -2188,7 +2197,7 @@ class TpuEngine:
         return int(np.asarray(self._ar_bytes_dev))
 
     def _keep_mesh_stats(self, mesh_stats) -> None:
-        """Add a dispatch's ``mesh_stats`` output (``[..., 3 a shard]``:
+        """Add a dispatch's ``mesh_stats`` output (``[..., MESH_STATS a shard]``:
         rounds or lanes by shards) to the running sum on the device: an
         enqueue, no host read on the round path. A one-device program has
         no such output."""
@@ -2214,29 +2223,19 @@ class TpuEngine:
     def mesh_round_stats(self) -> Dict[str, int]:
         """What only a mesh has, from ``AllreduceBytes.mesh_stats`` as the
         round programs returned it: ``collectives_per_round`` (the tree
-        path's collectives, counted where the bytes are), and over every
-        round dispatched since the last reset and every shard,
-        ``hist_sibling_builds`` (a shard's sibling-subtraction builds, one
-        a level >= 1) and ``hist_skew_fallback_builds`` (those among them
-        that did not hold the shard's rows of the chosen children in one
-        pass: 0, since every build streams every row).
-        All 0 on a one-device world, whose programs have no wire and whose
-        shard cannot skew.
+        path's collectives, counted where the bytes are, over every round
+        dispatched since the last reset and every shard). 0 on a one-device
+        world, whose programs have no wire.
         Reads this process's shards of the one running sum (a small
         device->host read each, after training only) and, on a
         multi-process world, allgathers the processes' sums: every process
         must call it, and every process gets the world's numbers."""
-        out = {"collectives_per_round": 0, "hist_skew_fallback_builds": 0,
-               "hist_sibling_builds": 0}
         total = self._mesh_stats_total()
         if total is None:
-            return out
-        calls, fallback, sibling = (int(v) for v in total[:len(MESH_STATS)])
-        out["collectives_per_round"] = calls // (
-            self._mesh_stats_rounds * int(self.mesh.shape[AXIS_ACTORS]))
-        out["hist_skew_fallback_builds"] = fallback
-        out["hist_sibling_builds"] = sibling
-        return out
+            return {"collectives_per_round": 0}
+        calls = int(total[MESH_STATS.index("collectives")])
+        return {"collectives_per_round": calls // (
+            self._mesh_stats_rounds * int(self.mesh.shape[AXIS_ACTORS]))}
 
     def lossguide_round_stats(self) -> Optional[Dict[str, int]]:
         """``LOSSGUIDE_STATS`` summed over every round dispatched since the
@@ -2336,11 +2335,19 @@ class TpuEngine:
             1 if n is None else n for _, n in self._trees_dev
         )
 
-    def get_booster(self) -> RayXGBoostBooster:
+    def get_booster(
+        self, readback: Optional[Dict[str, int]] = None
+    ) -> RayXGBoostBooster:
+        """The forest so far as a host booster; ``readback``, where given, is
+        filled with what the pending forests' flush moved (``entries``,
+        ``trees``, ``bytes``: the ``checkpoint.readback`` span's attrs)."""
         if self._vk:
             raise RuntimeError(
                 "engine is in vmapped-K mode; use get_booster_lane(lane)"
             )
+        moved = self._flush_trees()
+        if readback is not None:
+            readback.update(moved)
         forest = self._stacked_forest()
         tree_weights = None
         if self.dart:
@@ -2640,7 +2647,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes [K]
-                P(None, AXIS_ACTORS),  # mesh_stats [K, 3 a shard]
+                P(None, AXIS_ACTORS),  # mesh_stats [K, MESH_STATS a shard]
             ),
             check_vma=False,
         )
@@ -2687,10 +2694,11 @@ class TpuEngine:
                 fn = self._vk_fns.get(k)
                 if fn is None:
                     fn = self._vk_fns[k] = self._make_vmapped_step(k)
-                eval_data = self._eval_arrs()
-                group_rows = self._default_group_rows()
-                bounds = self._default_bounds()
-                rngs = self._vk_rngs(iteration)
+                with tracer.span("dispatch.args", program="vmapped"):
+                    eval_data = self._eval_arrs()
+                    group_rows = self._default_group_rows()
+                    bounds = self._default_bounds()
+                    rngs = self._vk_rngs(iteration)
                 with strict_transfer_guard(active=prog in self._warm_programs):
                     new_margins, new_eval_margins, forests, contribs, ar_bytes, mesh_stats = fn(
                         self.bins,
@@ -3132,7 +3140,7 @@ class TpuEngine:
                     for _ in self.evals
                 ),
                 P(),  # allreduce payload bytes
-                P(AXIS_ACTORS),  # mesh_stats, a [3] a shard
+                P(AXIS_ACTORS),  # mesh_stats, MESH_STATS a shard
             ),
             check_vma=False,
         )
@@ -3189,37 +3197,40 @@ class TpuEngine:
                     )
                 if self._dart_fn is None:
                     self._dart_fn = self._make_dart_step()
-                lr = params.learning_rate
-                drop = self._dart_sample_drops(iteration)
-                k_dropped = int(drop.sum())
-                if k_dropped:
-                    if params.normalize_type == "forest":
-                        new_w, drop_scale = 1.0 / (1.0 + lr), 1.0 / (1.0 + lr)
-                    else:  # "tree"
-                        new_w = 1.0 / (k_dropped + lr)
-                        drop_scale = k_dropped / (k_dropped + lr)
-                else:
-                    new_w, drop_scale = 1.0, 1.0
-                w_eff = self.dart_weights.copy()
-                w_eff[drop] = 0.0
-                w_post = self.dart_weights.copy()
-                w_post[drop] *= drop_scale
+                with tracer.span("dispatch.args", program="dart"):
+                    lr = params.learning_rate
+                    drop = self._dart_sample_drops(iteration)
+                    k_dropped = int(drop.sum())
+                    if k_dropped:
+                        if params.normalize_type == "forest":
+                            new_w, drop_scale = 1.0 / (1.0 + lr), 1.0 / (1.0 + lr)
+                        else:  # "tree"
+                            new_w = 1.0 / (k_dropped + lr)
+                            drop_scale = k_dropped / (k_dropped + lr)
+                    else:
+                        new_w, drop_scale = 1.0, 1.0
+                    w_eff = self.dart_weights.copy()
+                    w_eff[drop] = 0.0
+                    w_post = self.dart_weights.copy()
+                    w_post[drop] *= drop_scale
 
-                rng = jax.random.fold_in(
-                    jax.random.PRNGKey(params.seed), self.iteration_offset + iteration
-                )
-                eval_data = self._eval_arrs()
-                group_rows = self._default_group_rows()
-                bounds = self._default_bounds()
-                # the per-round drop weights / tree index are legitimate host
-                # inputs of the dart program: place them explicitly (replicated)
-                # BEFORE entering the strict guard, which rejects the implicit
-                # upload-and-reshard the bare jnp conversions would trigger
-                repl = NamedSharding(self.mesh, P())
-                w_eff_dev = jax.device_put(np.asarray(w_eff), repl)
-                w_post_dev = jax.device_put(np.asarray(w_post), repl)
-                new_w_dev = jax.device_put(np.float32(new_w), repl)
-                dart_t_dev = jax.device_put(np.int32(self.dart_t), repl)
+                    rng = jax.random.fold_in(
+                        jax.random.PRNGKey(params.seed),
+                        self.iteration_offset + iteration,
+                    )
+                    eval_data = self._eval_arrs()
+                    group_rows = self._default_group_rows()
+                    bounds = self._default_bounds()
+                    # the per-round drop weights / tree index are legitimate
+                    # host inputs of the dart program: place them explicitly
+                    # (replicated) BEFORE entering the strict guard, which
+                    # rejects the implicit upload-and-reshard the bare jnp
+                    # conversions would trigger
+                    repl = NamedSharding(self.mesh, P())
+                    w_eff_dev = jax.device_put(np.asarray(w_eff), repl)
+                    w_post_dev = jax.device_put(np.asarray(w_post), repl)
+                    new_w_dev = jax.device_put(np.float32(new_w), repl)
+                    dart_t_dev = jax.device_put(np.int32(self.dart_t), repl)
                 with strict_transfer_guard(active="dart" in self._warm_programs):
                     m_full, new_eval_margins, forest, round_forest, contribs, ar_bytes, mesh_stats = self._dart_fn(
                         self.bins,

@@ -588,9 +588,13 @@ class LinearEngine:
     def combine_host_scalar(self, value, es=None, metric=None) -> float:
         return float(value)  # single-process (enforced in __init__)
 
-    def get_booster(self) -> RayLinearBooster:
+    def get_booster(self, readback=None) -> RayLinearBooster:
+        """``readback`` as ``TpuEngine.get_booster``'s: the weights' bytes."""
+        w, b = np.asarray(self._w), np.asarray(self._b)
+        if readback is not None:
+            readback.update(bytes=w.nbytes + b.nbytes)
         return RayLinearBooster(
-            np.asarray(self._w), np.asarray(self._b), self.params,
+            w, b, self.params,
             self.base_score, feature_names=self.feature_names,
             rounds=self._rounds_done,
         )

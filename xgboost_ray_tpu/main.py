@@ -528,13 +528,6 @@ def _record_engine_readouts(state, engine, booster) -> None:
             "mesh": {k: int(v) for k, v in engine.mesh.shape.items()},
         },
     )
-    tracer.event(
-        "hist.skew_builds",
-        attrs={
-            "fallback_builds": mesh["hist_skew_fallback_builds"],
-            "sibling_builds": mesh["hist_sibling_builds"],
-        },
-    )
     # what ops.histogram.onehot_radix and onehot_ftiles chose where this
     # run's round programs traced a dense build (empty under
     # hist_impl=scatter): the counters rxgb_hist_builds_total{radix=...} and
@@ -551,11 +544,6 @@ def _record_engine_readouts(state, engine, booster) -> None:
             attrs["tile_steps_per_round"] = builds["tile_steps_per_tree"] * (
                 engine.n_outputs * max(1, engine.params.num_parallel_tree))
         tracer.event("hist.builds", attrs=attrs)
-    registry = obs.get_registry()
-    registry.counter("rxgb_hist_skew_fallback_builds_total").inc(
-        mesh["hist_skew_fallback_builds"])
-    registry.counter("rxgb_hist_sibling_builds_total").inc(
-        mesh["hist_sibling_builds"])
     grown = engine.lossguide_round_stats()
     if grown is None:
         return
@@ -573,6 +561,7 @@ def _record_engine_readouts(state, engine, booster) -> None:
         "rounds": grown["rounds"],
     }
     tracer.event("lossguide.grow", attrs=attrs)
+    registry = obs.get_registry()
     registry.counter("rxgb_lossguide_passes_total").inc(
         grown["lossguide_passes"])
     registry.counter("rxgb_lossguide_nodes_evaluated_total").inc(
@@ -1004,17 +993,21 @@ def _train(
     def _checkpoint_and_drain(iteration, save):
         """Drain the callback queue; on a checkpoint boundary (``save``)
         first read the booster back, serialise it and queue it. The
-        ``driver.checkpoint`` span is the stall a save costs the loop; the
-        ``checkpoint.commit`` event lands inside it."""
+        ``driver.checkpoint`` span is the stall a save costs the loop, with
+        ``checkpoint.readback`` and ``checkpoint.serialise`` inside it; its
+        self time is the queue and the commit (the ``checkpoint.commit``
+        event lands inside it)."""
         if not save:
             _handle_queue(state.queue, state.checkpoint, callback_returns)
             return
         with tracer.span("driver.checkpoint", round=iteration):
-            state.queue.put(
-                (0, _Checkpoint(
-                    iteration, _serialize_booster(engine.get_booster())
-                ))
-            )
+            with tracer.span("checkpoint.readback") as readback:
+                booster = engine.get_booster(readback=readback)
+            with tracer.span("checkpoint.serialise") as serialise:
+                raw = _serialize_booster(booster)
+                serialise.update(bytes=len(raw),
+                                 trees=booster.num_boosted_rounds())
+            state.queue.put((0, _Checkpoint(iteration, raw)))
             _handle_queue(state.queue, state.checkpoint, callback_returns)
 
     def _schedule_replacements(force=False):

@@ -25,16 +25,23 @@ lock-guarded metrics island). This package is the one plane they now share:
 * :mod:`xgboost_ray_tpu.obs.device` — ``scope_times(trace_dir)``: device
   seconds per ``DEVICE_SCOPES`` path from a profiler trace, and
   ``scope_times_by_device`` the same for each device of a mesh
-  (``python -m xgboost_ray_tpu.obs.device <dir>``); stdlib only.
+  (``python -m xgboost_ray_tpu.obs.device <dir>``); ``idle_by_span(
+  trace_dir, records)``: each device's idle seconds by the span path of
+  the timeline record that covers them, the records put on the profiler's
+  clock by their ``TraceAnnotation``; stdlib only.
 
 ``train()`` scopes a fresh tracer per run and returns its timeline under
 ``additional_results["obs"]``: one ``attempt`` span, under it ``data.load``,
 ``engine.init`` (``data.h2d``, ``data.sketch_bin``), one ``dispatch``
-(``dispatch.enqueue``, ``dispatch.wait``, ``round`` records, ``compile.*``
-on a first call) per compiled dispatch, and ``driver.checkpoint`` /
-``driver.callbacks`` between dispatches. Environment knobs: ``RXGB_TRACE``
-(0 disables), ``RXGB_TRACE_CAPACITY`` (ring size), ``RXGB_TRACE_DIR``
-(per-rank JSONL streaming).
+(``dispatch.enqueue`` › ``dispatch.args``, ``dispatch.wait``, ``round``
+records, ``compile.*`` on a first call) per compiled dispatch, and
+``driver.checkpoint`` (› ``checkpoint.readback``, ``checkpoint.serialise``)
+/ ``driver.callbacks`` between dispatches. Environment knobs:
+``RXGB_TRACE`` (0 disables), ``RXGB_TRACE_CAPACITY`` (ring size),
+``RXGB_TRACE_DIR`` (per-rank JSONL streaming). Why the chip was idle::
+
+    RXGB_PROFILE_DIR=/tmp/prof RXGB_TRACE_DIR=/tmp/tl python job.py
+    python -m xgboost_ray_tpu.obs.device /tmp/prof --timeline /tmp/tl/trace-rank0.jsonl
 
 Stdlib-only imports: safe to touch before jax comes up.
 """

@@ -79,8 +79,12 @@ TRACE_NAMES = frozenset({
     # ``dispatch.enqueue`` (argument assembly, trace / lower / compile or
     # cache load on a first call, launch: entry until the jitted call
     # returns) and ``dispatch.wait`` (the host blocked on the metric
-    # readback) — and one ``round`` record per boosting round inside it
+    # readback) — and one ``round`` record per boosting round inside it;
+    # ``dispatch.args`` (``program``) inside the enqueue is the argument
+    # assembly up to the jitted call (PRNG key, eval sets, group rows,
+    # bounds, a custom objective's gh upload, the scan's iterations)
     "dispatch", "dispatch.enqueue", "dispatch.wait", "round",
+    "dispatch.args",
     # every compile of the process (obs/compiles.py, one jax.monitoring
     # listener), parented to whatever span is open on the compiling thread
     "compile.trace", "compile.lower", "compile.backend",
@@ -89,8 +93,11 @@ TRACE_NAMES = frozenset({
     # host shards, engine construction, and inside it the row uploads
     # (data.h2d, below) and the sketch + bin program
     "data.load", "engine.init", "data.sketch_bin",
-    # host time between dispatches (main.py round loops)
+    # host time between dispatches (main.py round loops); inside a save,
+    # the booster's readback (``entries``, ``trees``, ``bytes`` moved) and
+    # its serialisation (``bytes`` of the pickle, ``trees`` in the forest)
     "driver.checkpoint", "driver.callbacks",
+    "checkpoint.readback", "checkpoint.serialise",
     # streamed ingestion (stream/ingest.py + stream/upload.py): one fenced
     # span per sketch/bin chunk and per H2D transfer, one per cuts merge —
     # a streamed load is reconstructible from the timeline alone
@@ -105,9 +112,6 @@ TRACE_NAMES = frozenset({
     "attempt", "failure.detected", "recovered", "backoff",
     "world.shrink", "world.grow", "world.resume", "world.restart",
     "checkpoint.commit", "allreduce.bytes",
-    # after training, beside allreduce.bytes: the sibling builds that sat
-    # in the skew fallback's window loop, and how many needed a second window
-    "hist.skew_builds",
     # after training: the radix the dense build factored its bin index by
     # and the feature tiles a row chunk took at every width (2 * node slots)
     # its round programs traced, and a level-wise round's tile steps

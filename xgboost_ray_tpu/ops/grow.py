@@ -654,8 +654,6 @@ def build_tree(
                 # every row streams through the build once, the bigger child's
                 # with zeroed gh: nothing is compacted, so no shard's rows of
                 # the chosen children can overflow a buffer
-                if ar_counter is not None:
-                    ar_counter.note_sibling_build(True)
                 parent_pos = pos >> 1
                 is_right = (pos & 1).astype(bool)
                 sel = (

@@ -443,8 +443,6 @@ def build_tree_lossguide(
         for i, w in enumerate(widths):
             for c, one in zip(counters, per_pass[w]):
                 c.add_trips(one, state["passes"][i])
-        if ar_counter is not None and cfg.sibling_subtract:
-            ar_counter.note_sibling_build(True, times=jnp.sum(state["passes"]))
     passes = 1 + jnp.sum(state["passes"])  # the root's build is one
     node_id, table, order = state["node_id"], state["table"], state["order"]
 

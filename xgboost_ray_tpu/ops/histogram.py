@@ -101,7 +101,7 @@ HIST_QUANT_MIN_BYTES = 32768
 
 
 #: what ``AllreduceBytes.mesh_stats`` holds, in order
-MESH_STATS = ("collectives", "skew_fallback_builds", "sibling_builds")
+MESH_STATS = ("collectives",)
 #: what follows them where the round grew leaf-wise trees
 #: (``note_lossguide``): full-row passes, nodes whose split was evaluated,
 #: splits kept, and wanted nodes the node table had no room to expand (0
@@ -133,11 +133,9 @@ class AllreduceBytes:
 
     Beside the bytes it counts what else only a mesh has, at the same call
     sites: ``calls``, the collectives a round (one per recorded op, a
-    multi-hop ring once per hop), and a shard's sibling-subtraction builds
-    with those among them that did not fit (``note_sibling_build``). Both are
-    0 on a 1-device axis, where there is no wire and no second shard to skew
-    against. They leave the round program through ``mesh_stats``, which is
-    ``None`` -- no output at all -- where the round traced neither, so a
+    multi-hop ring once per hop), 0 on a 1-device axis, where there is no
+    wire. They leave the round program through ``mesh_stats``, which is
+    ``None`` -- no output at all -- where the round traced none, so a
     one-device program is the program it was.
 
     A loop of traced length (the leaf-wise grower's passes) counts through
@@ -149,12 +147,9 @@ class AllreduceBytes:
         self.n = max(1, int(n_actors))
         self.total = 0  # python int: operand shapes are trace-time constants
         self.calls = 0
-        self.sibling_builds = 0
-        self.fallback_builds = 0  # a traced int32 once a build was noted
         # traced int32 terms of loops whose trip count the device decides
         self.dyn_total = 0
         self.dyn_calls = 0
-        self.dyn_sibling_builds = 0
         self.lossguide = None  # traced int32 [len(LOSSGUIDE_STATS)]
 
     @staticmethod
@@ -182,24 +177,6 @@ class AllreduceBytes:
         Without this the counter would have no model for the block-scale
         ring and would silently charge it as an allreduce."""
         self._add(self._nbytes(arr) * int(hops), calls=int(hops))
-
-    def note_sibling_build(self, fits, times=None) -> None:
-        """One sibling build of a shard of a mesh, or ``times`` of them (a
-        traced count: the builds of a loop of traced length). ``fits`` says
-        whether the build held the shard's rows of the chosen children in
-        one pass: the dense build streams every row and always does
-        (``True``), so ``fallback_builds`` stays 0; the count is what the
-        benchmark's ``hist.skew_fallback_pct`` reads."""
-        if self.n == 1:
-            return
-        if times is None:
-            self.sibling_builds += 1
-        else:
-            self.dyn_sibling_builds = self.dyn_sibling_builds + times
-        self.fallback_builds = self.fallback_builds + (
-            jnp.logical_not(fits).astype(jnp.int32)
-            * (1 if times is None else times)
-        )
 
     def mark(self):
         """The static totals now, for ``rewind`` and ``since``."""
@@ -246,17 +223,12 @@ class AllreduceBytes:
         return _plus(jnp.int32(min(self.total, 2**31 - 1)), self.dyn_total)
 
     def mesh_stats(self) -> Optional[jnp.ndarray]:
-        """``MESH_STATS`` of this shard's round as int32 ``[3]``, followed by
+        """``MESH_STATS`` of this shard's round as int32 ``[1]``, followed by
         its ``LOSSGUIDE_STATS`` where it grew leaf-wise trees, or ``None``
         where there is nothing to say (see the class's text)."""
-        if (not self.calls and not self.sibling_builds
-                and self.lossguide is None):
+        if not self.calls and self.lossguide is None:
             return None
-        stats = jnp.stack([
-            _plus(jnp.int32(self.calls), self.dyn_calls),
-            jnp.asarray(self.fallback_builds, jnp.int32),
-            _plus(jnp.int32(self.sibling_builds), self.dyn_sibling_builds),
-        ])
+        stats = jnp.stack([_plus(jnp.int32(self.calls), self.dyn_calls)])
         if self.lossguide is None:
             return stats
         return jnp.concatenate([stats, self.lossguide])
