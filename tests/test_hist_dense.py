@@ -1,8 +1,10 @@
 """The dense, order-free histogram build (``hist_onehot``: the node rides the
-matmul's right-hand side, and under 64 columns the low bits of the bin index
-beside it) against ``hist_scatter`` at every fan-out a tree of
-``max_depth <= 14`` asks for and at every radix the rule can return, the rule
-itself, the structural test that keeps a row order, a compaction and block
+matmul's right-hand side, and where the rule factors the bin index its low
+bits beside it, ``lo`` major under 64 columns and minor from there on)
+against ``hist_scatter`` at every fan-out a tree of ``max_depth <= 14`` asks
+for and at every radix the rule can return, the rule itself, the lowering
+tests that keep radix 1 and every build of a depth-6 tree the programs they
+were, the structural test that keeps a row order, a compaction and block
 copies out of ``build_tree``, and ``onehot`` forests against ``scatter``'s on
 one device and on the 4-device CPU mesh."""
 
@@ -107,21 +109,22 @@ def test_dense_build_is_exact_for_int8_gh(n_nodes):
         n_nodes)
 
 
-#: node slots under 64 matmul columns, where the rule may factor the bin
-#: index, and the regular bins: a multiple of every radix and one of none
-NARROW_FAN_OUTS = [1, 2, 4, 8, 16]
+#: node slots of a factored build: under 64 matmul columns (``lo`` major)
+#: and at 64 and 128 (``lo`` minor); and the regular bins: a multiple of
+#: every radix and one of none
+RADIX_FAN_OUTS = [1, 2, 4, 8, 16, 32, 64]
 RADIX_BINS = [N_BINS, 37]
 
 
 @pytest.mark.parametrize("n_bins", RADIX_BINS)
-@pytest.mark.parametrize("n_nodes", NARROW_FAN_OUTS)
+@pytest.mark.parametrize("n_nodes", RADIX_FAN_OUTS)
 @pytest.mark.parametrize("radix", ONEHOT_RADICES)
 @pytest.mark.parametrize("precision", ["highest", "fast"])
 def test_dense_build_matches_scatter_at_every_radix(precision, radix, n_nodes,
                                                     n_bins):
-    """The radix forced through the private build the rule calls: the
-    missing bucket's ``hi`` lies outside the one-hot (32 bins) or in a padded
-    ``lo`` slot that is cut off (37)."""
+    """The radix forced through the private build the rule calls, in both
+    column orders: the missing bucket's ``hi`` lies outside the one-hot (32
+    bins) or in a padded ``lo`` slot that is cut off (37)."""
     _assert_matches_scatter(
         lambda b, g, p: _hist_onehot(b, g, p, n_nodes, n_bins + 1, 2048,
                                      precision, radix),
@@ -129,7 +132,7 @@ def test_dense_build_matches_scatter_at_every_radix(precision, radix, n_nodes,
 
 
 @pytest.mark.parametrize("n_bins", RADIX_BINS)
-@pytest.mark.parametrize("n_nodes", NARROW_FAN_OUTS)
+@pytest.mark.parametrize("n_nodes", RADIX_FAN_OUTS)
 @pytest.mark.parametrize("radix", ONEHOT_RADICES)
 def test_dense_build_is_exact_for_int8_gh_at_every_radix(radix, n_nodes,
                                                          n_bins):
@@ -139,29 +142,38 @@ def test_dense_build_is_exact_for_int8_gh_at_every_radix(radix, n_nodes,
         n_nodes, n_bins=n_bins)
 
 
-@pytest.mark.parametrize("radix", [r for r in ONEHOT_RADICES if r > 1])
-def test_factored_build_tiles_wide_data(radix):
+@pytest.mark.parametrize("radix,n_nodes", [
+    pytest.param(r, n, id=f"{r}" if n == 2 else f"{r}-lo_minor")
+    for n in (2, 32) for r in ONEHOT_RADICES if r > 1])
+def test_factored_build_tiles_wide_data(radix, n_nodes):
     """More features than one batched step takes (no multiple of the tile):
-    the inner loop over feature tiles and its padded features."""
+    the inner loop over feature tiles and its padded features, with ``lo``
+    major (2 node slots) and minor (32: 64 columns, tiles of 7, each step's
+    product turned to ``lo`` major)."""
     _assert_matches_scatter(
-        lambda b, g, p: _hist_onehot(b, g, p, 2, NBT, 2048, "highest", radix),
-        "highest", 2, features=histogram_ops._RADIX_FTILE_MAX + 5)
+        lambda b, g, p: _hist_onehot(b, g, p, n_nodes, NBT, 2048, "highest",
+                                     radix),
+        "highest", n_nodes, features=histogram_ops._RADIX_FTILE_MAX + 5)
 
 
 def test_radix_rule_is_a_function_of_the_builds_shape():
     """``onehot_radix`` reads (node slots, regular bins) and nothing else:
-    a power of two the build knows, 1 from 64 matmul columns on and under 64
-    bins, never past 64 columns with ``lo`` beside the node, 1 at the widths
+    a power of two the build knows; under 64 matmul columns 1 under 64 bins,
+    never past 64 columns with ``lo`` beside the node, 1 at the widths
     nobody measured (neither 2, 4 nor a multiple of 8), and never wider with
-    more columns among the others."""
+    more columns among the others; from 64 columns on the probe's table
+    alone: radix 2 at 64 columns and 256 bins, 1 everywhere else."""
     for nb_reg in (16, 32, 37, 64, 100, 128, 255, 256, 512, 1024):
         last = max(ONEHOT_RADICES)
-        for n_nodes in (1, 2, 3, 4, 8, 9, 12, 16, 31, 32, 64, 128, 4096):
+        for n_nodes in (1, 2, 3, 4, 8, 9, 12, 16, 31, 32, 48, 64, 128, 4096):
             radix = onehot_radix(n_nodes, nb_reg)
             assert radix == onehot_radix(n_nodes, nb_reg)
             assert radix in ONEHOT_RADICES
+            if 2 * n_nodes >= 64:
+                assert radix == (2 if (n_nodes, nb_reg) == (32, 256) else 1)
+                continue
             assert radix * 2 * n_nodes <= 64 or radix == 1
-            if 2 * n_nodes >= 64 or nb_reg < 64:
+            if nb_reg < 64:
                 assert radix == 1
             if n_nodes > 2 and n_nodes % 4:
                 assert radix == 1
@@ -169,9 +181,9 @@ def test_radix_rule_is_a_function_of_the_builds_shape():
             assert radix <= last
             last = radix
     # the benchmark's shape: 256 bins, a depth-6 tree's builds and wider
-    assert [onehot_radix(n, 256) for n in (1, 2, 4, 8, 16, 32, 64)] == [
-        8, 8, 8, 4, 2, 1, 1]
-
+    # (depth 8's levels 6 and 7, the leaf-wise passes of 48-128 slots)
+    assert [onehot_radix(n, 256) for n in (1, 2, 4, 8, 16, 32, 48, 64, 128)
+            ] == [8, 8, 8, 4, 2, 2, 1, 1, 1]
 
 def _hist_onehot_pr35(bins, gh, pos, n_nodes, n_bins_total, chunk=8192,
                       precision="highest"):
@@ -244,8 +256,8 @@ def _hist_onehot_pr35(bins, gh, pos, n_nodes, n_bins_total, chunk=8192,
 @pytest.mark.parametrize("gh_dtype,precision", [
     ("float32", "fast"), ("float32", "highest"), ("int8", "highest")])
 def test_radix_one_lowers_to_the_unfactored_build(gh_dtype, precision):
-    """A wide build (64 node slots: radix 1 by the rule) is the program it
-    was: the same StableHLO text, through the public entry too."""
+    """A wide build (64 node slots, 128 columns: radix 1 by the rule) is the
+    program it was: the same StableHLO text, through the public entry too."""
     n_nodes, nbt = 64, 257
     shapes = (jax.ShapeDtypeStruct((20_000, 28), jnp.int16),
               jax.ShapeDtypeStruct((20_000, 2), jnp.dtype(gh_dtype)),
@@ -264,6 +276,179 @@ def test_radix_one_lowers_to_the_unfactored_build(gh_dtype, precision):
         b, g, p, 4, nbt, precision=precision)).lower(*shapes).as_text()
     assert narrow != jax.jit(lambda b, g, p: _hist_onehot_pr35(
         b, g, p, 4, nbt, precision=precision)).lower(*shapes).as_text()
+
+
+def _onehot_radix_lo_major(n_nodes, nb_reg):
+    """``onehot_radix`` as it stood before ``lo`` went minor from 64 columns
+    on, kept verbatim: the radix every build under 64 columns still has to
+    take."""
+    width = 2 * n_nodes
+    best, least = 1, float(nb_reg)
+    if nb_reg < 64 or not (width in (2, 4) or width % 8 == 0):
+        return best
+    for radix in ONEHOT_RADICES[1:]:
+        made = nb_reg / radix + 0.8 * radix * width
+        if radix * width <= 64 and made < least:
+            best, least = radix, made
+    return best
+
+
+def _hist_onehot_lo_major(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
+                      radix):
+    """``_hist_onehot`` as it stood before ``lo`` went minor from 64 columns
+    on (``lo`` major at every radix), kept verbatim: what every build under
+    64 columns has to lower to."""
+    from xgboost_ray_tpu.ops.histogram import (
+        _append_missing, _chunk_node_sums, _einsum_precision,
+        _for_row_chunks, onehot_ftiles)
+
+    n, num_features = bins.shape
+    nb_reg = n_bins_total - 1  # regular bins; bucket nb_reg == missing
+    width = 2 * n_nodes
+    prec = _einsum_precision(precision)
+
+    # quantized gradients (gh_precision): both one-hots and gh ride the matmul
+    # in the narrow integer dtype accumulating int32 — exact, and the
+    # int8 x int8 -> int32 MXU path on modern hardware. The bf16 "fast" knob
+    # is meaningless here (integer accumulation is already the cheap mode).
+    int_gh = jnp.issubdtype(gh.dtype, jnp.integer)
+    acc_dt = jnp.int32 if int_gh else jnp.float32
+    # fast mode: the one-hot (the big operand) in bf16 — exact for 0/1
+    # values, halves the traffic; gh rounds to bf16 (~0.2%) once, and its
+    # product with the node's 0/1 is exact
+    if int_gh:
+        oh_dtype = gh.dtype
+    else:
+        oh_dtype = jnp.bfloat16 if precision == "fast" else jnp.float32
+
+    shift = radix.bit_length() - 1
+    n_hi = -(-nb_reg // radix)  # one-hot rows a feature; nb_reg at radix 1
+    n_ftiles = onehot_ftiles(num_features, radix)
+    ftile = -(-num_features // n_ftiles)
+    f_pad = n_ftiles * ftile - num_features
+    bin_ids = jnp.arange(n_hi, dtype=jnp.int32)
+    node_of_col = jnp.arange(width, dtype=jnp.int32) // 2
+    col_is_hess = (jnp.arange(radix * width, dtype=jnp.int32) % 2).astype(bool)
+    if radix > 1:
+        # column lo * C + 2 * node + c of a feature's right-hand side
+        key_of_col = (
+            jnp.arange(radix, dtype=jnp.int32)[:, None] * n_nodes
+            + node_of_col[None, :]
+        ).reshape(-1)
+
+    def chunk_step(carry, pk, bc, ghk):
+        acc, tot = carry  # pk [rows], bc [rows, F] in the storage dtype, ghk [rows, 2]
+        rows = pk.shape[0]
+        bct = bc.T.astype(jnp.int32)  # [F, rows]: per-chunk transient upcast
+        if f_pad:
+            # pad with missing-valued features -> all-zero one-hot rows
+            bct = jnp.pad(bct, ((0, f_pad), (0, 0)), constant_values=nb_reg)
+        # the node rides the right-hand side: column 2 * node + c holds a
+        # row's g (c = 0) or h (c = 1) where the row sits in that node
+        ghc = ghk.astype(oh_dtype)
+        of_col = jnp.where(col_is_hess[None, :], ghc[:, 1:2], ghc[:, 0:1])
+        if radix == 1:
+            rhs = jnp.where(
+                pk[:, None] == node_of_col[None, :], of_col,
+                jnp.zeros((), oh_dtype),
+            )
+        else:
+            # a row in no node slot gets a key no column has
+            node_key = jnp.where(
+                (pk >= 0) & (pk < n_nodes), pk, -radix * n_nodes)
+
+        def ftile_step(t, acc):
+            cols = jax.lax.dynamic_slice_in_dim(bct, t * ftile, ftile, axis=0)
+            # bins == nb_reg (missing) match no regular bin -> zero one-hot
+            oh = (cols[:, None, :] == bin_ids[None, :, None]).astype(oh_dtype)
+            contrib = jax.lax.dot_general(
+                oh.reshape(ftile * nb_reg, rows), rhs, (((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=acc_dt,
+            )  # [ftile*nb_reg, 2*n_nodes] (MXU, f32 — or exact int32 — accumulate)
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc,
+                jax.lax.dynamic_slice_in_dim(acc, t * ftile, ftile, axis=0)
+                + contrib.reshape(ftile, nb_reg, width),
+                t * ftile,
+                axis=0,
+            )
+
+        def radix_step(t, acc):
+            cols = bct if n_ftiles == 1 else jax.lax.dynamic_slice_in_dim(
+                bct, t * ftile, ftile, axis=0)
+            oh = ((cols >> shift)[:, None, :] == bin_ids[None, :, None]
+                  ).astype(oh_dtype)  # [ftile, n_hi, rows]
+            # a feature's right-hand side: column lo * C + 2 * node + c holds
+            # a row's g or h where the row's own (lo, node) is the column's
+            key = (cols & (radix - 1)) * n_nodes + node_key[None, :]
+            of_f = jnp.where(
+                key[:, :, None] == key_of_col[None, None, :], of_col[None],
+                jnp.zeros((), oh_dtype),
+            )  # [ftile, rows, L * C]
+            contrib = jax.lax.dot_general(
+                oh, of_f, (((2,), (1,)), ((0,), (0,))),
+                precision=prec, preferred_element_type=acc_dt,
+            )  # [ftile, n_hi, L * C]
+            if n_ftiles == 1:  # all features in one step: no slice of acc
+                return acc + contrib
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc,
+                jax.lax.dynamic_slice_in_dim(acc, t * ftile, ftile, axis=0)
+                + contrib,
+                t * ftile,
+                axis=0,
+            )
+
+        if radix > 1 and n_ftiles == 1:
+            acc = radix_step(0, acc)
+        else:
+            acc = jax.lax.fori_loop(
+                0, n_ftiles, ftile_step if radix == 1 else radix_step, acc)
+        # node totals ride the loop as one extra tiny matmul per chunk
+        tot = tot + _chunk_node_sums(ghk, pk, n_nodes)
+        return acc, tot
+
+    acc0 = (
+        jnp.zeros((n_ftiles * ftile, n_hi, radix * width), acc_dt),
+        jnp.zeros((n_nodes, 2), acc_dt),
+    )
+    # bins stay in the storage dtype (uint8/int16) until a chunk is read
+    acc, node_tot = _for_row_chunks(chunk_step, acc0, chunk, pos, bins, gh)
+    acc = acc[:num_features]
+    if radix > 1:  # [F, hi, lo * C + col] -> [F, hi * L + lo, col]
+        acc = acc.reshape(num_features, n_hi * radix, width)[:, :nb_reg]
+    # [F, nb_reg, n_nodes * 2] -> [n_nodes, F, nb_reg, 2]
+    hist_reg = acc.reshape(
+        num_features, nb_reg, n_nodes, 2
+    ).transpose(2, 0, 1, 3)
+    return _append_missing(hist_reg, node_tot)
+
+
+@pytest.mark.parametrize("precision", ["fast", "highest"])
+def test_a_depth_6_tree_lowers_to_the_parents_builds(precision, monkeypatch):
+    """Every build of a depth-6 tree (2 to 32 columns: the whole tree of the
+    ``higgs-d6`` cells, on one chip and as a mesh's shard) is the program it
+    was before ``lo`` went minor past 64 columns: the tree's StableHLO text
+    with the parent's rule and build in place of today's is the same text."""
+    shapes = (jax.ShapeDtypeStruct((20_000, 28), jnp.int16),
+              jax.ShapeDtypeStruct((20_000, 2), jnp.float32),
+              jax.ShapeDtypeStruct((28, 255), jnp.float32))
+    cfg = GrowConfig(max_depth=6, max_bin=256, split=SplitParams(),
+                     hist_impl="onehot", hist_precision=precision)
+
+    def lowered():
+        histogram_ops.pop_traced_builds()
+        text = jax.jit(lambda b, g, c: build_tree(b, g, c, cfg)).lower(
+            *shapes).as_text()
+        return text, histogram_ops.pop_traced_builds()["radix_by_width"]
+
+    now, radices = lowered()
+    assert radices == {2: 8, 4: 8, 8: 8, 16: 4, 32: 2}
+    monkeypatch.setattr(histogram_ops, "onehot_radix", _onehot_radix_lo_major)
+    monkeypatch.setattr(histogram_ops, "_hist_onehot", _hist_onehot_lo_major)
+    parent, parent_radices = lowered()
+    assert parent_radices == radices
+    assert now == parent
 
 
 def _tree_inputs(n=40_000, features=6, seed=3):
@@ -363,8 +548,8 @@ def test_a_traced_tree_counts_its_builds_by_radix():
 
 def test_train_reports_the_radix_of_every_width():
     """After ``train()`` the timeline's ``hist.builds`` event carries the
-    radix of every width its round programs built; a deep tree's wide levels
-    took radix 1."""
+    radix of every width its round programs built; a deep tree's level 6
+    (64 columns) took radix 2 with ``lo`` minor, its level 7 radix 1."""
     rng = np.random.RandomState(0)
     x = rng.randn(800, 5).astype(np.float32)
     y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
@@ -377,13 +562,13 @@ def test_train_reports_the_radix_of_every_width():
     events = [r for r in res["obs"]["timeline"] if r["name"] == "hist.builds"]
     assert len(events) == 1
     assert events[0]["attrs"]["radix_by_width"] == {
-        "2": 8, "4": 8, "8": 8, "16": 4, "32": 2, "64": 1, "128": 1}
+        "2": 8, "4": 8, "8": 8, "16": 4, "32": 2, "64": 2, "128": 1}
     after = _builds_by_radix()
     programs = sum(1 for r in res["obs"]["timeline"]
                    if r["name"] == "dispatch" and r["attrs"]["first"])
     assert programs >= 1
     assert [after[r] - before[r] for r in (1, 2, 4, 8)] == [
-        2 * programs, programs, programs, 4 * programs]
+        programs, 2 * programs, programs, 4 * programs]
     assert obs.validate_trace_records(
         res["obs"]["timeline"], known_names=obs.TRACE_NAMES) == []
 

@@ -38,20 +38,22 @@ def _rows(n, features, n_nodes, gh_dtype, seed=0):
     return jnp.asarray(bins), jnp.asarray(gh), jnp.asarray(pos)
 
 
-# n_nodes 1 / 8 / 32 are 2 / 16 / 64 right-hand-side columns: radix 8, 4, 1
+# n_nodes 1 / 8 / 32 / 64 are 2 / 16 / 64 / 128 columns: radix 8, 4, 2 (``lo``
+# minor), 1
 @pytest.mark.parametrize("gh_dtype", [jnp.float32, jnp.int8])
-@pytest.mark.parametrize("n_nodes", [1, 8, 32])
+@pytest.mark.parametrize("n_nodes", [1, 8, 32, 64])
 @pytest.mark.parametrize("features", [70, 72, 300])
 def test_the_tiled_dense_build_is_the_scatter_add(features, n_nodes,
                                                   gh_dtype):
     """More than one feature tile a row chunk, with padded feature columns
-    (70; 300 at radix 1) and without (72; 300 at a radix over 1), at radix 1
-    and over it, float and int8 gh: ``hist_onehot`` against ``hist_scatter``
-    (row chunks of 1,024, the last one clamped)."""
+    (70 at radix 1 and 8; 72 and 300 with ``lo`` minor, tiles of 7; 300 at
+    radix 1) and without (the others), at radix 1 and over it, in both
+    column orders, float and int8 gh: ``hist_onehot`` against
+    ``hist_scatter`` (row chunks of 1,024, the last one clamped)."""
     bins, gh, pos = _rows(2500, features, n_nodes, gh_dtype)
     radix = onehot_radix(n_nodes, 256)
-    assert radix == {1: 8, 8: 4, 32: 1}[n_nodes]
-    assert onehot_ftiles(features, radix) > 1
+    assert radix == {1: 8, 8: 4, 32: 2, 64: 1}[n_nodes]
+    assert onehot_ftiles(features, radix, n_nodes) > 1
     live = jnp.where(pos >= 0, pos, 0)
     gh_live = gh * (pos >= 0)[:, None].astype(gh.dtype)
     want = np.asarray(hist_scatter(bins, gh_live, live, n_nodes, 257))
@@ -72,7 +74,7 @@ def test_one_tile_and_many_tiles_build_the_same_sums(radix, monkeypatch):
     own = np.asarray(_hist_onehot(bins, gh, pos, 4, 257, 512, "highest",
                                   radix))
     monkeypatch.setattr(histogram_ops, "onehot_ftiles",
-                        lambda features, radix: features)
+                        lambda features, radix, n_nodes: features)
     single = np.asarray(_hist_onehot(bins, gh, pos, 4, 257, 512, "highest",
                                      radix))
     np.testing.assert_allclose(single, own, rtol=1e-6, atol=1e-6)
@@ -190,3 +192,41 @@ def test_train_reports_the_tiles_of_every_width():
     assert programs >= 1 and counter.value - before == 9 * programs
     assert obs.validate_trace_records(
         res["obs"]["timeline"], known_names=obs.TRACE_NAMES) == []
+
+
+def test_train_reports_the_tiles_of_the_wide_levels():
+    """A depth-8 tree over the same 70 features builds 2, 2, 4, 8, 16, 32,
+    64 and 128 columns: radix 8 / 8 / 8 / 4 / 2 in tiles of at most 32
+    features (3 a row chunk) under 64 columns, radix 2 with ``lo`` minor in
+    tiles of at most 7 (10) at 64, radix 1 in tiles of at most 8 (9) at 128:
+    6 x 3 + 10 + 9 = 37 tile steps a round of one row chunk. The same
+    arithmetic at ``epsilon-d8``'s 400,000 x 2,000 with 8,192-row chunks is
+    49 x (6 x 63 + 286 + 250) = 44,786 tile steps a round, where radix 1 at
+    64 columns made 49 x (6 x 63 + 2 x 250) = 43,022."""
+    from xgboost_ray_tpu import obs
+
+    rng = np.random.RandomState(0)
+    x = rng.randn(600, 70).astype(np.float32)
+    y = (x[:, 0] + x[:, 40] > 0).astype(np.float32)
+    counter = obs.get_registry().counter("rxgb_hist_tile_steps_total")
+    before = counter.value
+    res = {}
+    train({"objective": "binary:logistic", "max_depth": 8, "max_bin": 256,
+           "hist_impl": "onehot"},
+          RayDMatrix(x, y), num_boost_round=2, additional_results=res,
+          ray_params=RayParams(num_actors=1))
+    events = [r for r in res["obs"]["timeline"] if r["name"] == "hist.builds"]
+    assert len(events) == 1
+    assert events[0]["attrs"] == {
+        "radix_by_width": {"2": 8, "4": 8, "8": 8, "16": 4, "32": 2,
+                           "64": 2, "128": 1},
+        "ftiles_by_width": {"2": 3, "4": 3, "8": 3, "16": 3, "32": 3,
+                            "64": 10, "128": 9},
+        "tile_steps_per_round": 37}
+    programs = sum(1 for r in res["obs"]["timeline"]
+                   if r["name"] == "dispatch" and r["attrs"]["first"])
+    assert programs >= 1 and counter.value - before == 37 * programs
+    assert (6 * 63 + 286 + 250) * -(-400_000 // 8192) == 44_786
+    assert [onehot_ftiles(2000, radix, n_nodes) for radix, n_nodes in (
+        (2, 16), (2, 32), (1, 64))] == [63, 286, 250]
+

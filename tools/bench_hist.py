@@ -14,13 +14,21 @@ was read from: the bin index factored as ``b = hi * L + lo``, the one-hot of
 ``hi`` on the matmul's left and ``lo`` beside the node on its right, timed
 per (L, columns, layout, tile) and printed as the table PERF.md quotes. The
 rows ``shipped`` are the package's build with the radix forced (``--stages``
-with nothing after it runs those alone: 31 builds, a minute of one chip);
-the rows ``probe`` are this file's own forms of it (``probe_build``), stage
-``layouts`` (which way the right-hand side lies, features batched or
-grouped to full MXU tiles) and stage ``forms`` (the order of a feature's
-columns, and how ``lo`` masks them), each followed by the tiles around its
-fastest configurations; ``--widths``, ``--radices``, ``--masks`` and
-``--max-bin`` narrow a run, ``--out`` keeps one JSON line a configuration.
+with nothing after it runs those alone: 31 builds, a minute of one chip),
+``lo`` major under 64 columns and minor from there on, as the package lays
+them out; the rows ``probe`` are this file's own forms of it
+(``probe_build``), stage ``layouts`` (which way the right-hand side lies,
+features batched or grouped to full MXU tiles) and stage ``forms`` (the
+order of a feature's columns, and how ``lo`` masks them: both orders at
+every width), each followed by the tiles around its fastest configurations
+(``--no-tiles`` leaves those out); ``--widths``, ``--radices``, ``--masks``
+and ``--max-bin`` narrow a run, ``--out`` keeps one JSON line a
+configuration. The wide builds and the wide feature axis:
+
+    python tools/bench_hist.py --radix-sweep --rows 11000000 \
+        --widths 64 96 128 256 --radices 1 2 4 --masks key --stages forms
+    python tools/bench_hist.py --radix-sweep --rows 400000 --features 2000 \
+        --widths 64 96 128 256 --radices 1 2 4 --masks key --stages forms
 
 Timing: each kernel is one jitted call timed on the host clock around
 ``block_until_ready`` (the iteration index perturbs the input so no call is
@@ -233,7 +241,7 @@ def _sweep_grid(num_features, nb_reg, widths, radices, masks, stage):
             else:
                 for order in ("lc", "cl"):
                     for mask in masks:
-                        for ftile in (7, num_features):
+                        for ftile in (7, min(num_features, 32)):
                             add(ftile, layout="rk", group=1, order=order,
                                 mask=mask)
     return grid
@@ -301,8 +309,6 @@ def radix_sweep(args, jax, jnp, bins, gh, rng):
     radices = tuple(args.radices)
     for cols in widths:
         for radix in radices:
-            if radix > 1 and cols > 64:
-                continue
             record({"build": "shipped", "radix": radix, "cols": cols,
                     "rule": histogram.onehot_radix(cols // 2, nb_reg)},
                    timed(lambda b, g, p, cols=cols, radix=radix:
@@ -320,23 +326,26 @@ def radix_sweep(args, jax, jnp, bins, gh, rng):
                    cols)
 
     for stage in args.stages:
-        run(_sweep_grid(args.features, nb_reg, [c for c in widths if c <= 64],
-                        radices, args.masks, stage))
-        run(_tile_grid(rows, args.features))
+        run(_sweep_grid(args.features, nb_reg, widths, radices, args.masks,
+                        stage))
+        if args.tiles:
+            run(_tile_grid(rows, args.features))
 
     print("\nbest ms a build, by radix (rows) and right-hand-side columns:")
     table_cols = sorted({r["cols"] for r in rows})
-    print("build    L " + "".join(f"{c:>9d}" for c in table_cols))
-    for build in ("shipped", "probe"):
+    print("build       L " + "".join(f"{c:>9d}" for c in table_cols))
+    for build, order in (("shipped", None), ("probe", "lc"), ("probe", "cl")):
         for radix in radices:
             cells = []
             for cols in table_cols:
                 ms = [r["ms"] for r in rows if r["build"] == build
                       and r["radix"] == radix and r["cols"] == cols
+                      and order in (None, r.get("order", "lc"))
                       and "ms" in r]
                 cells.append(f"{min(ms):9.2f}" if ms else "        -")
             if any(c.strip() != "-" for c in cells):
-                print(f"{build:8s}{radix:2d} " + "".join(cells))
+                label = build if order is None else f"{build} {order}"
+                print(f"{label:11s}{radix:2d} " + "".join(cells))
 
 
 def main():
@@ -353,9 +362,8 @@ def main():
                              "build's factored bin index")
     parser.add_argument("--widths", nargs="*", type=int,
                         default=[2, 4, 8, 16, 32, 64, 128],
-                        help="radix sweep: right-hand-side columns "
-                             "(2 x node slots) to probe; past 64 the "
-                             "package's build at radix 1 alone")
+                        help="radix sweep: a build's columns (2 x node "
+                             "slots) to probe")
     parser.add_argument("--radices", nargs="*", type=int,
                         default=[1, 2, 4, 8, 16],
                         help="radix sweep: radices to probe")
@@ -368,6 +376,9 @@ def main():
                         choices=["layouts", "forms"],
                         help="radix sweep: which of the probe's grids to run "
                              "beside the package's build (none: that alone)")
+    parser.add_argument("--no-tiles", dest="tiles", action="store_false",
+                        help="radix sweep: leave out the tiles around each "
+                             "stage's fastest configurations")
     parser.add_argument("--out", default="",
                         help="radix sweep: append one JSON line a "
                              "configuration to this file")

@@ -16,12 +16,13 @@ name (params ``hist_impl``) in ``build_histogram``:
 * ``hist_onehot`` — the dense MXU build: row-chunked ``onehot(bins)ᵀ @
   (gh ⊗ onehot(node))`` matmuls, one pass over all rows at every fan-out, no
   row order; the loop over row chunks and feature tiles bounds the one-hot
-  transient. Under 64 right-hand-side columns the bin index is factored,
-  ``b = hi * L + lo``: the one-hot is of ``hi`` alone (``n_bins / L`` wide)
-  and ``lo`` rides the right-hand side beside the node, so a narrow level
-  makes 48-192 elements a (row, feature) where it made 256; ``L`` comes
-  from the build's shape (``onehot_radix``), and from 64 columns on the
-  build is the unfactored one. The default on an accelerator.
+  transient. The bin index is factored, ``b = hi * L + lo``: the one-hot
+  is of ``hi`` alone (``n_bins / L`` wide) and ``lo`` rides the right-hand
+  side beside the node, so a narrow level makes 48-192 elements a (row,
+  feature) where it made 256; ``L`` comes from the build's shape
+  (``onehot_radix``): over 1 under 64 columns, and from 64 on only where a
+  probe read it faster, with ``lo`` minor in a feature's columns. The
+  default on an accelerator.
 """
 
 import functools
@@ -560,8 +561,21 @@ _ONEHOT_FTILE_MAX = 8
 # widths the rule takes, and no tile up to 44M elements fell off a cliff
 # (PERF.md §6, PR 36)
 _RADIX_FTILE_MAX = 32
+# a build's columns (C = 2 * n_nodes, before ``lo`` widens them L-fold) from
+# which a factored build lays a feature's columns out with ``lo`` minor: the
+# form that kept 128 right-hand-side columns off the cliff ``lo`` major fell
+# from; narrower builds keep ``lo`` major (``_hist_onehot``)
+_LO_MINOR_COLS = 64
+# most feature columns per step of a build with ``lo`` minor: in steps of 7
+# features of 8,192 rows 28 / 2,000 features' 64-column build read 69.3 /
+# 197.7 ms against 81.9 / 229.9 at radix 1, in one step of 28 / 32 features
+# 189.3 / 497.0 (PERF.md §6)
+_LO_MINOR_FTILE_MAX = 7
 #: the radices ``onehot_radix`` can return
 ONEHOT_RADICES = (1, 2, 4, 8)
+# (columns, regular bins) -> radix from ``_LO_MINOR_COLS`` columns on, where
+# the probe read it faster than radix 1 at both shapes (``onehot_radix``)
+_WIDE_RADIX = {(64, 256): 2}
 
 # ``builds``: width (2 * n_nodes) -> (radix, feature tiles a row chunk) of
 # every ``hist_onehot`` build traced since the last ``pop_traced_builds``;
@@ -575,34 +589,55 @@ _traced = {"builds": {}, "tree_steps": 0}
 def onehot_radix(n_nodes: int, nb_reg: int) -> int:
     """The radix L ``hist_onehot`` factors the bin index by (``b = hi * L +
     lo``) at a build of ``n_nodes`` node slots over ``nb_reg`` regular bins,
-    from the build's shape alone: the L of 1, 2, 4, 8 with at most 64
-    right-hand-side columns (``L * C``, C = 2 * n_nodes) that makes the
-    fewest elements a (row, feature), ``nb_reg / L`` one-hot rows and
-    ``L * C`` columns at 0.8 of a row's cost each; 1 from 64 columns on,
-    under 64 bins, and at widths other than 2, 4 and the multiples of 8
-    (a leaf-wise pass of ``max_leaves - 1`` slots): none was measured, and
-    the forms whose column blocks were no whole sublane tiles were the slow
-    ones.
+    from the build's shape alone. Under 64 columns (C = 2 * n_nodes): the L
+    of 1, 2, 4, 8 with at most 64 right-hand-side columns (``L * C``) that
+    makes the fewest elements a (row, feature), ``nb_reg / L`` one-hot rows
+    and ``L * C`` columns at 0.8 of a row's cost each; 1 under 64 bins and at
+    widths other than 2, 4 and the multiples of 8 (a leaf-wise pass of
+    ``max_leaves - 1`` slots): none was measured, and the forms whose column
+    blocks were no whole sublane tiles were the slow ones. From 64 columns
+    on, with ``lo`` minor (``_hist_onehot``): the radix ``_WIDE_RADIX``
+    holds for the (C, ``nb_reg``) where a probe read it at least 5% faster
+    than radix 1 at both 11M x 28 and 400,000 x 2,000, and 1 everywhere
+    else, past the widest width probed (256) too.
 
     Measured on a v5e at 11M x 28 x 256 bins, bf16, 8192-row chunks, ms a
     build by L (rows) and C (``tools/bench_hist.py --radix-sweep --stages``,
     PERF.md §6, PR 36; the rule's choice starred):
 
         L / C      2      4      8     16     32     64    128
-        1       67.5   67.6   67.6   69.1   73.2  *81.8 *143.1
+        1       67.5   67.6   67.6   69.1   73.2   81.8 *143.1
         2       34.4   33.9   35.7   38.2  *42.4  189.6
         4       21.4   21.9   24.1  *35.7  180.8  184.6
         8      *15.8  *20.5  *22.5  181.0  184.0  189.0
 
     which is about 4 + 0.25 ms a one-hot row + 0.15-0.27 ms a column: the
     VPU makes ``nb_reg / L + L * C`` elements a (row, feature) where radix 1
-    makes ``nb_reg``. Past 64 columns this form falls off a cliff (181-190 ms
-    at 128), and the matmul's own cost would take over anyway. Under 64 bins
-    nothing was measured. With an earlier form of the right-hand side (a
+    makes ``nb_reg``. Past 64 right-hand-side columns this form (``lo``
+    major) falls off a cliff (181-190 ms at 128). From 64 columns on, ms a
+    build at 11M x 28 / 400,000 x 2,000 (the same probe, ``--widths 64 96
+    128 256 --radices 1 2 4 --masks key --stages forms``, and the package's
+    build; PERF.md §6), the best form of each row:
+
+        L / C               64            96            128            256
+        1              81.9/229.7  *99.1/*282.8  *142.7/*387.8  *260.1/*769.3
+        2, lo major   195.8/506.5   106.8/333.2   211.2/607.4    271.8/818.5
+        2, lo minor  *69.3/*197.7    96.2/275.4   191.4/556.3    257.3/771.3
+        4, either     184.5/498.9   190.9/535.3   191.4/571.4    263.8/924.1
+
+    Only 64 columns gain at least 5% at both shapes (15% / 14%), in steps of
+    ``_LO_MINOR_FTILE_MAX`` features whose products are turned to ``lo``
+    major before they join the accumulator (``_hist_onehot``): 2 x 128
+    elements a (row, feature) where radix 1 makes 256, and a 128-column
+    matmul output where radix 1's half-fills the MXU. 96 columns gain under
+    3%, 128 and 256 none. Under 64 bins nothing was measured, and from 64
+    columns on only 256 bins. With an earlier form of the right-hand side (a
     product with the 0/1 ``lo`` compare) 64 / 1,024 bins read 12.8 / 39.9 ms
     at 2 columns and L = 8 against 20.3 / 250.5 at L = 1.
     """
     width = 2 * n_nodes
+    if width >= _LO_MINOR_COLS:
+        return _WIDE_RADIX.get((width, nb_reg), 1)
     best, least = 1, float(nb_reg)
     if nb_reg < 64 or not (width in (2, 4) or width % 8 == 0):
         return best
@@ -613,17 +648,25 @@ def onehot_radix(n_nodes: int, nb_reg: int) -> int:
     return best
 
 
-def onehot_ftiles(num_features: int, radix: int) -> int:
-    """Feature tiles a row chunk of ``hist_onehot`` takes at a radix: the
-    fewest of at most ``_ONEHOT_FTILE_MAX`` (radix 1) or ``_RADIX_FTILE_MAX``
-    features, sized evenly. 28 features make four tiles of 7 at radix 1 and
-    one of 28 over it; 2,000 make 250 of 8 and 63 of 32, and a v5e builds
+def onehot_ftiles(num_features: int, radix: int, n_nodes: int = 1) -> int:
+    """Feature tiles a row chunk of ``hist_onehot`` takes at a radix and a
+    build's node slots: the fewest of at most ``_ONEHOT_FTILE_MAX`` (radix
+    1), ``_LO_MINOR_FTILE_MAX`` (a radix over 1 with ``lo`` minor, from
+    ``_LO_MINOR_COLS`` columns on) or ``_RADIX_FTILE_MAX`` features, sized
+    evenly. 28 features make four tiles of 7 at radix 1, one of 28 over it
+    and four of 7 with ``lo`` minor; 2,000 make 250 of 8, 63 of 32 and 286
+    of 7 (the last padded by 2), and a v5e builds
     them in the time 28 features take, feature for feature (31-143 ms a
     level at 400,000 x 2,000 against 14-43 at 11M x 28 through 32 columns,
     231 / 392 against 82 / 144 at 64 / 128: 0.75-1.28 of the same work;
     PERF.md section 6, PR 38), so the maxima stand as read at 28."""
-    return -(-num_features // (
-        _ONEHOT_FTILE_MAX if radix == 1 else _RADIX_FTILE_MAX))
+    if radix == 1:
+        most = _ONEHOT_FTILE_MAX
+    elif 2 * n_nodes >= _LO_MINOR_COLS:
+        most = _LO_MINOR_FTILE_MAX
+    else:
+        most = _RADIX_FTILE_MAX
+    return -(-num_features // most)
 
 
 def begin_traced_tree() -> None:
@@ -669,7 +712,7 @@ def hist_onehot(
     tiles) under ``rxgb_hist_tile_steps_total`` as it is traced."""
     radix = onehot_radix(n_nodes, n_bins_total - 1)
     n, num_features = bins.shape
-    ftiles = onehot_ftiles(num_features, radix)
+    ftiles = onehot_ftiles(num_features, radix, n_nodes)
     steps = ftiles * -(-n // max(1, min(chunk, n)))
     get_registry().counter(
         f'rxgb_hist_builds_total{{radix="{radix}"}}',
@@ -701,7 +744,7 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
     faster on a v5e than the ``[chunk, ftile * n_bins]`` orientation,
     PERF.md §6, PR 30).
 
-    **L = 1** (64 columns and more): each inner step builds the
+    **L = 1**: each inner step builds the
     ``[ftile * n_bins, chunk]`` one-hot of the REGULAR bins -- the same at
     every level -- and contracts it over the rows against the right-hand
     side. **L > 1**: with ``b = hi * L + lo`` the low bits ride the
@@ -719,6 +762,16 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
     before, every sum over the same rows in the same dtype. The VPU then
     makes ``n_bins / L + L * C`` elements a (row, feature) for ``n_bins``,
     and the MXU takes L times fewer one-hot tiles.
+
+    That order (``lo`` major) is kept under ``_LO_MINOR_COLS`` columns; there
+    128 right-hand-side columns fell off a cliff (181-190 ms a build where
+    radix 1 took 82). From ``_LO_MINOR_COLS`` on, a feature's column
+    ``(2 * node + c) * L + lo`` holds what column ``lo * C + 2 * node + c``
+    held (``lo`` minor), a step takes at most ``_LO_MINOR_FTILE_MAX``
+    features, and each step's product is turned to ``lo`` major before it
+    joins the accumulator: 69 ms. Kept ``lo`` minor through the row loop and
+    turned once after it, the same build fell off the cliff again (208 ms;
+    PERF.md §6).
 
     Missing rows (bin ``n_bins``) match no regular bin -- their ``hi`` lies
     outside the one-hot, or, where L does not divide ``n_bins``, in the last
@@ -748,18 +801,26 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
 
     shift = radix.bit_length() - 1
     n_hi = -(-nb_reg // radix)  # one-hot rows a feature; nb_reg at radix 1
-    n_ftiles = onehot_ftiles(num_features, radix)
+    n_ftiles = onehot_ftiles(num_features, radix, n_nodes)
     ftile = -(-num_features // n_ftiles)
     f_pad = n_ftiles * ftile - num_features
+    lo_minor = radix > 1 and width >= _LO_MINOR_COLS
     bin_ids = jnp.arange(n_hi, dtype=jnp.int32)
     node_of_col = jnp.arange(width, dtype=jnp.int32) // 2
-    col_is_hess = (jnp.arange(radix * width, dtype=jnp.int32) % 2).astype(bool)
-    if radix > 1:
-        # column lo * C + 2 * node + c of a feature's right-hand side
-        key_of_col = (
-            jnp.arange(radix, dtype=jnp.int32)[:, None] * n_nodes
-            + node_of_col[None, :]
-        ).reshape(-1)
+    if lo_minor:
+        # column (2 * node + c) * L + lo of a feature's right-hand side
+        col = jnp.arange(radix * width, dtype=jnp.int32)
+        col_is_hess = (col // radix % 2).astype(bool)
+        key_of_col = col % radix * n_nodes + col // (2 * radix)
+    else:
+        col_is_hess = (
+            jnp.arange(radix * width, dtype=jnp.int32) % 2).astype(bool)
+        if radix > 1:
+            # column lo * C + 2 * node + c of a feature's right-hand side
+            key_of_col = (
+                jnp.arange(radix, dtype=jnp.int32)[:, None] * n_nodes
+                + node_of_col[None, :]
+            ).reshape(-1)
 
     def chunk_step(carry, pk, bc, ghk):
         acc, tot = carry  # pk [rows], bc [rows, F] in the storage dtype, ghk [rows, 2]
@@ -803,8 +864,8 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
                 bct, t * ftile, ftile, axis=0)
             oh = ((cols >> shift)[:, None, :] == bin_ids[None, :, None]
                   ).astype(oh_dtype)  # [ftile, n_hi, rows]
-            # a feature's right-hand side: column lo * C + 2 * node + c holds
-            # a row's g or h where the row's own (lo, node) is the column's
+            # a feature's right-hand side: the column of key_of_col's (lo,
+            # node) holds a row's g or h where the row's own are the column's
             key = (cols & (radix - 1)) * n_nodes + node_key[None, :]
             of_f = jnp.where(
                 key[:, :, None] == key_of_col[None, None, :], of_col[None],
@@ -814,6 +875,9 @@ def _hist_onehot(bins, gh, pos, n_nodes, n_bins_total, chunk, precision,
                 oh, of_f, (((2,), (1,)), ((0,), (0,))),
                 precision=prec, preferred_element_type=acc_dt,
             )  # [ftile, n_hi, L * C]
+            if lo_minor:  # [.., col * L + lo] -> [.., lo * C + col]
+                contrib = contrib.reshape(ftile, n_hi, width, radix).transpose(
+                    0, 1, 3, 2).reshape(ftile, n_hi, radix * width)
             if n_ftiles == 1:  # all features in one step: no slice of acc
                 return acc + contrib
             return jax.lax.dynamic_update_slice_in_dim(
