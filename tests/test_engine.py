@@ -427,11 +427,103 @@ def test_scan_path_transfer_count_regression(monkeypatch):
     )
 
     counts["d2h"] = 0
+    pending = len(eng._trees_dev)  # the warm-up chunk and this one
+    n_fields = len(eng._trees_dev[0][0])
+    started = {"copies": 0, "at_first_read": None}
+    orig_async = _jarr.ArrayImpl.copy_to_host_async
+
+    def counting_async(self):
+        started["copies"] += 1
+        return orig_async(self)
+
+    def first_read_value(self):
+        if started["at_first_read"] is None:
+            started["at_first_read"] = started["copies"]
+        return counting_value(self)
+
+    monkeypatch.setattr(_jarr.ArrayImpl, "copy_to_host_async", counting_async)
+    monkeypatch.setattr(_jarr.ArrayImpl, "_value", property(first_read_value))
     eng.get_booster()
     flush_reads = counts["d2h"]
-    # one batched read per Tree field (9) + cuts + small constant slack;
+    # one batched read: every field of every pending chunk starts its copy
+    # before the first one is waited on, plus the cut points' one read;
     # NOT proportional to the 8 trained rounds
-    assert flush_reads <= 14, (
+    assert started["at_first_read"] == pending * n_fields
+    assert flush_reads <= pending * n_fields + 1, (
         f"{flush_reads} device->host reads in get_booster(); forest "
-        f"flush must stay one batched read per field"
+        f"flush must stay one read per field of each pending chunk"
     )
+    # nothing pending: no forest read, and the cut points are not read again
+    counts["d2h"] = 0
+    eng.get_booster()
+    assert counts["d2h"] == 0
+
+
+def _pending_on_host(eng):
+    """The pending device entries as host trees in round order, read field
+    by field with ``np.asarray``: what a flush has to reproduce."""
+    out = []
+    for tree, n in eng._trees_dev:
+        fields = [np.asarray(a) for a in tree]
+        if n is None:
+            out.append(fields)
+        else:
+            out.extend([f[r] for f in fields] for r in range(n))
+    return out
+
+
+# each case: the steps between flushes, ("step", i) or ("many", first, n)
+_FLUSH_CASES = {
+    "per_round_3_then_2": ({}, [[("step", 0), ("step", 1), ("step", 2)],
+                                [("step", 3), ("step", 4)]]),
+    "fused": ({}, [[("many", 0, 4)]]),
+    "fused_then_per_round": ({}, [[("many", 0, 3), ("step", 3),
+                                   ("step", 4)]]),
+    "dart": ({"booster": "dart", "rate_drop": 0.3, "one_drop": 1},
+             [[("step", 0), ("step", 1), ("step", 2)], [("step", 3)]]),
+}
+
+
+@pytest.mark.parametrize("num_actors", [1, 2], ids=["one_device", "mesh2"])
+@pytest.mark.parametrize("case", sorted(_FLUSH_CASES))
+def test_flush_brings_every_pending_tree_to_the_host_bit_for_bit(case,
+                                                                 num_actors):
+    """A flush moves every pending entry, per-round or fused, DART's too,
+    to the host in one batched read: each field of each host tree equals,
+    byte for byte and in round order, a per-field read of the entries taken
+    before it; ``transfers`` is 1 with entries pending and 0 without."""
+    extra, saves = _FLUSH_CASES[case]
+    rng = np.random.RandomState(5)
+    x = rng.randn(300, 5).astype(np.float32)
+    x[::11, 3] = np.nan
+    y = (np.nan_to_num(x[:, 0]) + 0.3 * x[:, 1] > 0).astype(np.float32)
+    p = parse_params(dict({"objective": "binary:logistic", "max_depth": 3,
+                           "eta": 0.4}, **extra))
+    eng = TpuEngine([{"data": x, "label": y}], p, num_actors=num_actors,
+                    total_rounds=5)
+    for steps in saves:
+        for kind, first, *n in steps:
+            if kind == "step":
+                eng.step(first)
+            else:
+                eng.step_many(first, n[0])
+        want = _pending_on_host(eng)
+        entries = len(eng._trees_dev)
+        before = len(eng.trees)
+        moved = eng._flush_trees()
+        got = eng.trees[before:]
+        assert len(got) == len(want)
+        for host_tree, fields in zip(got, want):
+            assert len(host_tree) == len(fields)
+            for a, b in zip(host_tree, fields):
+                assert isinstance(a, np.ndarray)
+                assert (a.dtype, a.shape) == (b.dtype, b.shape)
+                assert a.tobytes() == b.tobytes()
+        assert moved == {
+            "entries": entries, "trees": len(want), "transfers": 1,
+            "bytes": sum(b.nbytes for fields in want for b in fields),
+        }
+        assert eng._trees_dev == []
+    assert eng._flush_trees() == {"entries": 0, "trees": 0, "bytes": 0,
+                                  "transfers": 0}
+    assert eng.get_booster().num_boosted_rounds() == len(eng.trees)

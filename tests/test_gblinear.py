@@ -263,3 +263,18 @@ def test_gblinear_iteration_range_noop_forms_supported():
         np.testing.assert_allclose(got, want, atol=0)
     with pytest.raises(NotImplementedError, match="iteration_range"):
         bst.predict(x, iteration_range=(0, 2))
+
+
+def test_gblinear_save_reads_its_weights_back_in_one_transfer():
+    """A save's ``checkpoint.readback`` brings the weights and the bias to
+    the host in one batched read and names its bytes."""
+    x, y, _ = _lin_data()
+    res = {}
+    train({"objective": "reg:squarederror", "booster": "gblinear"},
+          RayDMatrix(x, y), 4, additional_results=res,
+          ray_params=RayParams(num_actors=1, checkpoint_frequency=2))
+    readbacks = [r for r in res["obs"]["timeline"]
+                 if r["name"] == "checkpoint.readback"]
+    assert len(readbacks) == 2
+    for r in readbacks:
+        assert r["attrs"] == {"bytes": (6 + 1) * 4, "transfers": 1}

@@ -660,6 +660,36 @@ def test_a_save_reads_back_and_serialises_in_spans_of_its_own(per_round):
             assert by_seq[r["parent"]]["name"] == "checkpoint.readback", r
 
 
+def test_a_save_compiles_nothing_and_reads_its_trees_back_at_once():
+    """Per-round saves with three and then two rounds pending: each save's
+    readback issues one batched host read (``transfers`` 1) and no save
+    compiles anything, whatever the number of pending rounds."""
+
+    class Noop:
+        def after_iteration(self, model, epoch, evals_log):
+            return False
+
+    x, y = _data()
+    res = {}
+    # a depth no other test here trains: a compile inside a save cannot
+    # hide behind a cache another test filled
+    train(dict(_PARAMS, max_depth=5), RayDMatrix(x, y), 5,
+          additional_results=res, callbacks=[Noop()],
+          ray_params=RayParams(num_actors=2, checkpoint_frequency=3))
+    timeline = res["obs"]["timeline"]
+    saves = _spans(timeline, "driver.checkpoint")
+    assert len(saves) == 2
+    pending = []
+    for save in saves:
+        below = _below(timeline, save["seq"])
+        assert [r["name"] for r in below
+                if r["name"].startswith("compile.")] == []
+        (readback,) = [r for r in below if r["name"] == "checkpoint.readback"]
+        assert readback["attrs"]["transfers"] == 1
+        pending.append(readback["attrs"]["trees"])
+    assert pending == [3, 2]
+
+
 @pytest.mark.parametrize("per_round", [False, True], ids=["fused", "per_round"])
 def test_every_enqueue_assembles_its_arguments_in_a_span(per_round):
     """Each ``dispatch.enqueue`` holds exactly one ``dispatch.args``, named

@@ -349,6 +349,7 @@ class TpuEngine:
         self._streamed = streams is not None
         self._stream_stats: Optional[Dict[str, Any]] = None
         self._stream_cuts_np: Optional[np.ndarray] = None
+        self._cuts_np: Optional[np.ndarray] = None  # ``_host_cuts``' copy
         if self._streamed:
             from xgboost_ray_tpu.params import validate_streaming_params
 
@@ -1904,9 +1905,9 @@ class TpuEngine:
             self.margins = new_margins
             self._adopt_eval_margins(new_eval_margins)
             # defer forest transfer: keep the whole stacked chunk on device
-            # (order-safe alongside per-round step()s) and materialize it in ONE
-            # batched read per Tree field at the next checkpoint/get_booster
-            # instead of an eager 9-field read per chunk
+            # (order-safe alongside per-round step()s) and bring it to the host
+            # with every other pending entry in ONE batched read at the next
+            # checkpoint/get_booster instead of an eager 9-field read per chunk
             self._trees_dev.append((forests, n_rounds))
 
             # metrics: one stacked transfer for ALL (num, den) scalars of the
@@ -2149,35 +2150,35 @@ class TpuEngine:
         return map_tree(lambda f: f[: self._stack_rows], self._stack_buf)
 
     def _flush_trees(self) -> Dict[str, int]:
-        """Transfer pending device forests to host with batched reads.
+        """Bring the pending device forests to the host in one batched read.
 
         Entries are ``(tree, None)`` for one round (per-round step paths) or
-        ``(stacked_tree, n_rounds)`` for a whole scan chunk. ALL pending
-        entries are concatenated on device first (per-round trees expand to a
-        length-1 leading axis; forest shapes are constant within a run), so a
-        flush costs exactly one host read per Tree field no matter how many
-        rounds or chunks are pending. Returns what it moved: ``entries``
-        flushed, ``trees`` (rounds) brought to the host, host ``bytes``."""
+        ``(stacked_tree, n_rounds)`` for a whole scan chunk. One
+        ``jax.device_get`` of all of them starts every field's copy at once
+        and runs no device op, so a flush compiles nothing whatever the mix
+        of entries; a chunk is cut into its rounds on the host. Returns what
+        it moved: ``entries`` flushed, ``trees`` (rounds) brought to the
+        host, host ``bytes``, and ``transfers``, the batched host reads it
+        issued (1, or 0 with nothing pending)."""
         entries = self._trees_dev
-        total = sum(1 if n is None else n for _, n in entries)
-        moved = {"entries": len(entries), "trees": total, "bytes": 0}
         if not entries:
-            return moved
-        if len(entries) == 1 and entries[0][1] is None:
-            host = jax.tree.map(np.asarray, entries[0][0])
-            self.trees.append(host)
-        else:
-            expanded = [
-                jax.tree.map(lambda a: a[None], t) if n is None else t
-                for t, n in entries
-            ]
-            host = jax.tree.map(
-                lambda *xs: np.asarray(jnp.concatenate(xs, axis=0)), *expanded
-            )
-            for r in range(total):
-                self.trees.append(jax.tree.map(lambda a, _r=r: a[_r], host))
+            return {"entries": 0, "trees": 0, "bytes": 0, "transfers": 0}
+        host = jax.device_get([t for t, _ in entries])
+        for tree, (_, n) in zip(host, entries):
+            if n is None:
+                self.trees.append(tree)
+            else:
+                self.trees.extend(
+                    jax.tree.map(lambda a, _r=r: a[_r], tree)
+                    for r in range(n)
+                )
+        moved = {
+            "entries": len(entries),
+            "trees": sum(1 if n is None else n for _, n in entries),
+            "bytes": sum(a.nbytes for a in jax.tree.leaves(host)),
+            "transfers": 1,
+        }
         self._trees_dev.clear()
-        moved["bytes"] = sum(a.nbytes for a in jax.tree.leaves(host))
         return moved
 
     def hist_allreduce_bytes_per_round(self) -> Optional[int]:
@@ -2335,12 +2336,26 @@ class TpuEngine:
             1 if n is None else n for _, n in self._trees_dev
         )
 
+    def _host_cuts(self) -> np.ndarray:
+        """The cut points on the host, read once: they are fixed for the
+        engine's life, so every booster shares this read-only copy (the
+        streamed ingest's own host copy where it kept one)."""
+        if self._cuts_np is None:
+            if self._stream_cuts_np is not None:
+                cuts = self._stream_cuts_np.view()
+                cuts.flags.writeable = False
+            else:
+                cuts = np.asarray(self.cuts)
+            self._cuts_np = cuts
+        return self._cuts_np
+
     def get_booster(
         self, readback: Optional[Dict[str, int]] = None
     ) -> RayXGBoostBooster:
         """The forest so far as a host booster; ``readback``, where given, is
         filled with what the pending forests' flush moved (``entries``,
-        ``trees``, ``bytes``: the ``checkpoint.readback`` span's attrs)."""
+        ``trees``, ``bytes``, ``transfers``: the ``checkpoint.readback``
+        span's attrs)."""
         if self._vk:
             raise RuntimeError(
                 "engine is in vmapped-K mode; use get_booster_lane(lane)"
@@ -2354,7 +2369,7 @@ class TpuEngine:
             tree_weights = self.dart_weights[: self.dart_t].copy()
         booster = RayXGBoostBooster(
             forest,
-            np.asarray(self.cuts),
+            self._host_cuts(),
             self.params,
             self.base_score,
             feature_names=self.feature_names,
@@ -2807,7 +2822,7 @@ class TpuEngine:
         forest = stack_trees(self._vk_trees[lane])
         booster = RayXGBoostBooster(
             forest,
-            np.asarray(self.cuts),
+            self._host_cuts(),
             self._vk_lane_params[lane],
             self.base_score,
             feature_names=self.feature_names,

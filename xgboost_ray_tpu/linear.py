@@ -589,10 +589,11 @@ class LinearEngine:
         return float(value)  # single-process (enforced in __init__)
 
     def get_booster(self, readback=None) -> RayLinearBooster:
-        """``readback`` as ``TpuEngine.get_booster``'s: the weights' bytes."""
-        w, b = np.asarray(self._w), np.asarray(self._b)
+        """``readback`` as ``TpuEngine.get_booster``'s: the weights' bytes,
+        read in one batched transfer."""
+        w, b = jax.device_get((self._w, self._b))
         if readback is not None:
-            readback.update(bytes=w.nbytes + b.nbytes)
+            readback.update(bytes=w.nbytes + b.nbytes, transfers=1)
         return RayLinearBooster(
             w, b, self.params,
             self.base_score, feature_names=self.feature_names,

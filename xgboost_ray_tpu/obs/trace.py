@@ -94,7 +94,8 @@ TRACE_NAMES = frozenset({
     # (data.h2d, below) and the sketch + bin program
     "data.load", "engine.init", "data.sketch_bin",
     # host time between dispatches (main.py round loops); inside a save,
-    # the booster's readback (``entries``, ``trees``, ``bytes`` moved) and
+    # the booster's readback (``entries``, ``trees``, ``bytes`` moved in
+    # ``transfers`` batched host reads: 1, or 0 with nothing pending) and
     # its serialisation (``bytes`` of the pickle, ``trees`` in the forest)
     "driver.checkpoint", "driver.callbacks",
     "checkpoint.readback", "checkpoint.serialise",
